@@ -23,15 +23,15 @@ def _load() -> Optional[ctypes.CDLL]:
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_SO_PATH):
-            try:  # build on demand; fine to fail (pure-python fallback)
-                subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
-                               capture_output=True, timeout=120)
-            except Exception:
-                return None
+        # the binary is git-ignored, so it is always built from the source
+        # git commits: make decides (a no-op while the .so is newer than the
+        # .cpp), and a stale binary in one tree can never be what runs.
+        # Fine to fail — no compiler, no make — (pure-python fallback).
         try:
+            subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
+                           capture_output=True, timeout=120)
             lib = ctypes.CDLL(_SO_PATH)
-        except OSError:
+        except (OSError, subprocess.SubprocessError):
             return None
         lib.dl4j_idx_info.argtypes = [ctypes.c_char_p,
                                       ctypes.POINTER(ctypes.c_int64),

@@ -22,6 +22,7 @@ the decoder matmuls stay large on the MXU instead of looping.
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -38,7 +39,7 @@ from deeplearning4j_tpu.nn.losses import compute_loss
 
 DIST_REGISTRY: dict[str, type] = {}
 
-_HALF_LOG_2PI = 0.5 * float(jnp.log(2 * jnp.pi))
+_HALF_LOG_2PI = 0.5 * math.log(2 * math.pi)
 
 
 def register_dist(cls):

@@ -583,18 +583,23 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
             self._device_loop_cache[cache_key] = run
         return run
 
-    def train_step_flops(self, x, y) -> Optional[float]:
-        """XLA cost-analysis FLOPs of ONE fit_on_device training step (see
-        MultiLayerNetwork.train_step_flops)."""
+    def lower_train_step(self, x, y):
+        """AOT-lower ONE fit_on_device training step (see
+        MultiLayerNetwork.lower_train_step)."""
         self._check_init()
         x = tuple(jnp.asarray(v, self.dtype) for v in _as_list(x))
         y = tuple(jnp.asarray(v, self.dtype) for v in _as_list(y))
-        from deeplearning4j_tpu.util.costs import lowered_flops
         run = self._get_device_loop()
-        return lowered_flops(
-            run, self.params_tree, self._opt_state, self.state_tree,
+        return run.lower(
+            self.params_tree, self._opt_state, self.state_tree,
             jnp.asarray(self._step, jnp.int32), self._rng, x, y, None, None,
             self._health_nf_in(), n=1)
+
+    def train_step_flops(self, x, y) -> Optional[float]:
+        """XLA cost-analysis FLOPs of ONE fit_on_device training step (see
+        MultiLayerNetwork.train_step_flops)."""
+        from deeplearning4j_tpu.util.costs import costs_of
+        return costs_of(self.lower_train_step(x, y))["flops"] or None
 
     def fit(self, data, labels=None, epochs: int = 1):
         """fit(x(s), y(s)) | fit(DataSet/MultiDataSet) | fit(iterator[, epochs])

@@ -12,7 +12,7 @@ from deeplearning4j_tpu.ops import pallas_kernels  # registers kernels on import
 from deeplearning4j_tpu.ops import conv_fused  # registers conv1x1_bn_act
 from deeplearning4j_tpu.ops import lstm_scan_fused  # registers graves_lstm_scan
 from deeplearning4j_tpu.ops import flash_attention  # registers flash_attention
-from deeplearning4j_tpu.ops import decode_attention  # registers decode_attention
+from deeplearning4j_tpu.ops import decode_attention  # registers the paged decode kernels
 
 __all__ = ["enable_helpers", "helpers_enabled", "helper_for", "register_helper",
            "registered_helpers", "pallas_kernels", "conv_fused",

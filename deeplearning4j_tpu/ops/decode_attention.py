@@ -1,55 +1,51 @@
-"""Split-K flash-decode kernel: single-query cached attention for serving.
+"""Split-K flash-decode kernel: single-query paged attention for serving.
 
 Beyond-reference (Flash-Decoding, Dao et al. 2023; SURVEY §5 serving). The
 serving engine's decode step attends ONE query per slot against that slot's
 KV-cache prefix (serving/kv_cache.py). The dense path
-(`decode_attention_dense`, the fp64 oracle and universal fallback) builds the
-full (S, H, L) score tensor and softmaxes over the whole max_len axis no
+(`decode_attention_dense`, the fp64 oracle the paged oracles call) builds
+the full (S, H, L) score tensor and softmaxes over the whole max_len axis no
 matter how short the actual sequences are. At decode there is no query-axis
 parallelism to tile over (q is a single position), so the flash trick that
-matters is SPLIT-K: partition the cache LENGTH axis into nk chunks of bkv
-positions, compute each partition's softmax-weighted partial sum and row
-logsumexp independently (one grid cell per (slot, kv-head, partition)), and
-merge the partials outside the kernel with the SAME logaddexp algebra that
-ring attention and `flash_attention_lse` use:
+matters is SPLIT-K: partition the cache LENGTH axis, compute each
+partition's softmax-weighted partial sum and row logsumexp independently
+(one grid cell per (slot, partition)), and merge the partials outside the
+kernel with the SAME logaddexp algebra that ring attention and
+`flash_attention_lse` use:
 
     out = sum_p exp(L_p - L_tot) * o_p,   L_tot = logsumexp_p L_p.
 
 Partitions entirely beyond a slot's visible length — or entirely behind its
 sliding window — are skipped inside the kernel (zero output block, L_p =
-NEG_INF, which the merge weighs to zero), so per-slot cost follows the
-slot's TRUE length, not max_len: a freshly admitted request in a mostly
-empty cache does bkv worth of score math, not max_len worth.
+NEG_INF, which the merge weighs to zero), so per-slot score math follows
+the slot's TRUE length, not max_len.
 
-GQA-aware without materializing the head repeat: q arrives reshaped
-(S, Hk, G, D) and each grid cell contracts its (G, D) query group against
-the (bkv, D) k/v tile of its kv head — the same grouping as
-ops/flash_attention._kv_row and serving/decode.decode_attention. Score and
-softmax math run in fp32 (fp64 under x64); k/v stream in the cache dtype
-(bf16 on TPU).
-
-Registered as helper "decode_attention" (default-on for TPU);
-serving/decode.py dispatches here through the helper seam with the dense
-path as oracle and fallback. Falls back to dense automatically when the
-cache length cannot be partitioned (L not divisible down to a >= 8 block).
-Inference-only: no custom VJP (the dense fallback is differentiable if
-anyone ever needs gradients through decode).
-
-PAGED variant (ISSUE 7): the serving cache is now block-paged
-(serving/kv_cache.py) — k/v live as (num_blocks + 1, block_size, Hk, D)
-physical blocks and each slot maps logical blocks through a
-(max_seqs, blocks_per_seq) int32 block table. The split-K partition
-structure aligns PERFECTLY with paging: one length partition = one
-physical block, so `flash_decode_attention_paged` keeps the gather
-INSIDE the kernel by feeding the block table through
+The cache is block-paged (ISSUE 7, serving/kv_cache.py): k/v live as
+(num_blocks + 1, block_size, Hk, D) physical blocks and each slot maps
+logical blocks through a (max_seqs, blocks_per_seq) int32 block table. The
+split-K partition structure aligns with paging: one length partition = one
+physical block, so `flash_decode_attention_paged` keeps the gather INSIDE
+the kernel by feeding the block table through
 `pltpu.PrefetchScalarGridSpec` (scalar-prefetch operand) and letting each
 grid cell's k/v index_map resolve (slot, logical block j) ->
 `bt_ref[s, j]` — no (S, L, Hk, D) contiguous copy of the cache is ever
-materialized. The kernel body is the SAME `_decode_kernel` (same math,
-same skip logic, bkv = block_size); `decode_attention_dense_paged`
-extends the fp64 oracle to resolve block tables (gather + reshape, then
-the unchanged dense math) so the parity harness covers the paged path
-end to end. Falls back to the dense-paged path when block_size < 8.
+materialized. `decode_attention_dense_paged` extends the fp64 oracle to
+resolve block tables (gather + reshape, then the unchanged dense math) so
+the parity harness covers the paged path end to end.
+
+GQA-aware without materializing the head repeat: q arrives reshaped
+(S, Hk, G, D), each grid cell holds one whole physical block (bs, Hk, D)
+and contracts every kv head's (G, D) query group against that head's
+(bs, D) k/v slice — the same grouping as ops/flash_attention._kv_row.
+Score and softmax math run in fp32 (fp64 under x64); k/v stream in the
+cache dtype (bf16 on TPU, int8 for a quantized pool).
+
+Registered as helpers "decode_attention_paged" and
+"decode_attention_spec_paged" (default-on for TPU); serving/decode.py
+dispatches here through the helper seam with the dense paged paths as
+oracle and fallback. Falls back to the dense-paged path when
+block_size < 8. Inference-only: no custom VJP (the dense fallback is
+differentiable if anyone ever needs gradients through decode).
 
 Chunked prefill (ISSUE 9) adds no kernel variant: a prefill chunk
 attends its predecessor blocks through the SAME block-table gather
@@ -64,16 +60,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from deeplearning4j_tpu.ops.helpers import register_helper
 
 NEG_INF = -1e30
-
-# 0 = auto: 256-position partitions (A/B-able; at serving shapes the kernel
-# is HBM-bound on the k/v stream, so the block size mostly sets how much
-# work the visible-length skip can drop).
-DEFAULT_BKV = 0
 
 
 def _interpret() -> bool:
@@ -82,8 +72,8 @@ def _interpret() -> bool:
 
 
 def decode_attention_dense(q, kc, vc, visible, scale, window: int = 0):
-    """Dense single-query attention against the cache — the fp64 oracle and
-    universal fallback (bit-identical to the pre-split-K serving decode).
+    """Dense single-query attention against a contiguous per-slot cache —
+    the fp64 oracle the paged oracles reduce to.
 
     q: (S, H, D) current-position queries; kc/vc: (S, L, Hk, D) cache
     (current position already appended); visible: (S,) number of visible
@@ -109,140 +99,141 @@ def decode_attention_dense(q, kc, vc, visible, scale, window: int = 0):
     return out.reshape(S, H, D).astype(q.dtype)
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, m_ref, vis_ref, o_ref, l_ref, *,
-                   bkv, window, scale, acc_dt, ks_ref=None, vs_ref=None):
-    """One grid cell = (slot, kv head, length partition): partial
-    softmax-weighted sum o_p (G, D) and row logsumexp L_p (G,) over this
-    partition's bkv cache positions. Partitions with no visible position
-    (fully beyond the slot's length, or fully behind its sliding window)
-    skip the score math and emit (0, NEG_INF) — the merge weighs them to
-    zero.
+def _decode_kernel(bt_ref, vis_ref, q_ref, k_ref, v_ref, *rest, nq, bs,
+                   window, scale, acc_dt, quantized):
+    """One grid cell = (slot, logical block): for every kv head, the partial
+    softmax-weighted sum o_p (R, D) and row logsumexp L_p (R, 1) of the
+    head's R = nq * G query rows over this block's bs cache positions. Row
+    r belongs to query position r // G, which sits at logical position
+    vis - 1 + r // G and so sees cache positions below vis + r // G (nq = 1
+    is plain decode; nq > 1 is speculative verification). Blocks no query
+    can see (beyond the last query's horizon, or behind the first query's
+    sliding window) skip the score math and emit (0, NEG_INF) — the merge
+    weighs them to zero.
 
-    Quantized pool (ISSUE 15): ks_ref/vs_ref, when given, are this cell's
-    per-head-per-block scales ((1, 1) SMEM tiles, routed through the same
-    block-table index_map as the k/v tiles), and the k/v streams are int8.
-    Dequantization is ONE scalar broadcast multiply per tile, applied to
-    the (bkv, D) tile right after the dtype widen — structurally the same
-    `payload * scale` the dense oracle applies per gathered block, so
-    kernel-vs-oracle parity carries over to the int8 path unchanged. The
-    pool bytes crossing HBM stay int8; nothing dequantized ever persists
-    beyond this cell's registers."""
+    The k/v tile is the whole physical block (bs, Hk, D): its last two
+    dims are the pool's own, which is the only shape of a one-block tile
+    the TPU lowering takes from a (.., bs, Hk, D) pool; heads are unrolled
+    here. Validity is built from an iota against the scalar-prefetched
+    visible length — no mask operand.
+
+    Quantized pool (ISSUE 15): ks_ref/vs_ref are this block's per-head
+    scales, a (1, 1, Hk) tile routed through the same block-table
+    index_map as the k/v tiles, and the k/v streams are int8.
+    Dequantization is ONE broadcast multiply per head tile right after the
+    dtype widen — structurally the `payload * scale` the dense
+    oracle applies per gathered block. The pool bytes crossing HBM stay
+    int8; nothing dequantized outlives this cell."""
     from jax.experimental import pallas as pl
-    j = pl.program_id(2)
-    vis = vis_ref[0, 0]                              # slot's visible length
-    lo = j * bkv
-    run = lo < vis                                   # any position visible?
+    if quantized:
+        ks_ref, vs_ref, o_ref, l_ref = rest
+    else:
+        o_ref, l_ref = rest
+    n_kv, R = q_ref.shape[1], q_ref.shape[2]
+    G = R // nq
+    vis = vis_ref[pl.program_id(0)]                  # query 0's visible length
+    lo = pl.program_id(1) * bs
+    run = lo < vis + (nq - 1)                        # any query sees any pos?
     if window:
-        run = run & (lo + bkv > vis - window)        # any inside the window?
+        run = run & (lo + bs > vis - window)         # union over queries
 
     @pl.when(run)
     def _():
-        q = q_ref[0, 0].astype(acc_dt)               # (G, D)
-        k = k_ref[0, :, 0, :].astype(acc_dt)         # (bkv, D)
-        if ks_ref is not None:
-            k = k * ks_ref[0, 0].astype(acc_dt)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=acc_dt) * scale
-        valid = m_ref[0, :] > 0                      # (bkv,) per-position
-        s = jnp.where(valid[None, :], s, NEG_INF)
-        m = jnp.max(s, axis=1)                       # (G,)
-        p = jnp.exp(s - m[:, None])
-        p = jnp.where(valid[None, :], p, 0.0)
-        l = jnp.sum(p, axis=1)                       # (G,)
-        v = v_ref[0, :, 0, :].astype(acc_dt)         # (bkv, D)
-        if vs_ref is not None:
-            v = v * vs_ref[0, 0].astype(acc_dt)
-        o = jax.lax.dot_general(p, v,
-                                (((1,), (0,)), ((), ())),
-                                preferred_element_type=acc_dt)
-        o_ref[0, 0, 0] = (o / jnp.maximum(l, 1e-30)[:, None]).astype(
-            o_ref.dtype)
-        l_ref[0, 0, 0] = jnp.where(
-            l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF)
+        pos = lo + jax.lax.broadcasted_iota(jnp.int32, (R, bs), 1)
+        horizon = vis
+        if nq > 1:
+            horizon = vis + jax.lax.broadcasted_iota(
+                jnp.int32, (R, bs), 0) // G
+        valid = pos < horizon
+        if window:
+            valid = valid & (horizon - 1 - pos < window)
+        for h in range(n_kv):
+            q = q_ref[0, h].astype(acc_dt)           # (R, D)
+            k = k_ref[0, :, h, :].astype(acc_dt)     # (bs, D)
+            v = v_ref[0, :, h, :].astype(acc_dt)
+            if quantized:
+                k = k * ks_ref[0, :, h:h + 1].astype(acc_dt)
+                v = v * vs_ref[0, :, h:h + 1].astype(acc_dt)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=acc_dt) * scale
+            s = jnp.where(valid, s, NEG_INF)
+            m = jnp.max(s, axis=1, keepdims=True)    # (R, 1)
+            p = jnp.where(valid, jnp.exp(s - m), 0.0)
+            l = jnp.sum(p, axis=1, keepdims=True)    # (R, 1)
+            o = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                    preferred_element_type=acc_dt)
+            o_ref[0, 0, h] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+            l_ref[0, 0, h] = jnp.where(
+                l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF)
 
     @pl.when(jnp.logical_not(run))
     def _():
-        o_ref[0, 0, 0] = jnp.zeros_like(o_ref[0, 0, 0])
-        l_ref[0, 0, 0] = jnp.full_like(l_ref[0, 0, 0], NEG_INF)
+        o_ref[...] = jnp.zeros_like(o_ref)
+        l_ref[...] = jnp.full_like(l_ref, NEG_INF)
 
 
-def _resolve_bkv(bkv: int, L: int) -> int:
-    """Largest feasible partition size <= the request that divides L (the
-    cache is never copied/padded — partitions must tile max_len exactly)."""
-    if not bkv:
-        bkv = 256
-    bkv = min(bkv, L)
-    while bkv > 1 and L % bkv:
-        bkv //= 2
-    return bkv
+def _paged_split_k(q4, kp, vp, block_tables, visible, scale, window,
+                   k_scale, v_scale, nq):
+    """The pallas_call + logaddexp merge shared by the single-query and the
+    speculative entry points. q4: (S, Hk, R, D) with R = nq * G query rows
+    per kv head (query-major); returns (S, Hk, R, D) in the accumulator
+    dtype.
 
-
-def flash_decode_attention(q, kc, vc, visible, scale, window: int = 0,
-                           bkv: int = DEFAULT_BKV):
-    """Split-K flash-decode: same contract as `decode_attention_dense`
-    (q (S, H, D), kc/vc (S, L, Hk, D), visible (S,)), computed as nk
-    independent length partitions merged via logaddexp. Falls back to the
-    dense path when L cannot be split into >= 8-position partitions."""
+    `block_tables` and `visible` ride as `pltpu.PrefetchScalarGridSpec`
+    scalar-prefetch operands: every index_map takes them as trailing refs,
+    the k/v (and scale) maps do the paging gather — logical block j of slot
+    s lives at physical block bt_ref[s, j] — and the kernel reads its
+    slot's visible length from SMEM."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    S, H, D = q.shape
-    L, Hk = kc.shape[1], kc.shape[2]
-    if H % Hk != 0:
-        raise ValueError(f"n_heads {H} % n_kv_heads {Hk} != 0")
-    bkv = _resolve_bkv(bkv, L)
-    if bkv < 8 or L % bkv:
-        return decode_attention_dense(q, kc, vc, visible, scale, window)
-    nk = L // bkv
-    G = H // Hk
-    acc_dt = jnp.promote_types(q.dtype, jnp.float32)
-    q4 = q.reshape(S, Hk, G, D)
-    visible = jnp.asarray(visible, jnp.int32)
-    # per-position visibility (the same mask algebra as the dense path);
-    # the kernel reads one (bkv,) stripe per grid cell
-    j = jnp.arange(L)[None, :]
-    valid = j < visible[:, None]
-    if window:
-        valid = valid & (visible[:, None] - 1 - j < window)
-    valid = valid.astype(jnp.int32)                  # (S, L)
-    vis2 = visible[:, None]                          # (S, 1) SMEM scalar feed
-
-    kern = functools.partial(_decode_kernel, bkv=bkv, window=window,
-                             scale=float(scale), acc_dt=acc_dt)
-    o_p, l_p = pl.pallas_call(
-        kern,
-        grid=(S, Hk, nk),
+    S, Hk, R, D = q4.shape
+    bs = kp.shape[1]
+    bps = block_tables.shape[1]
+    quantized = k_scale is not None
+    acc_dt = jnp.promote_types(q4.dtype, jnp.float32)
+    page = pl.BlockSpec((1, bs, Hk, D),
+                        lambda s, j, bt_ref, vis_ref: (bt_ref[s, j], 0, 0, 0))
+    page_scale = pl.BlockSpec(
+        (1, 1, Hk), lambda s, j, bt_ref, vis_ref: (bt_ref[s, j], 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(S, bps),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda s, h, j: (s, h, 0, 0)),
-            pl.BlockSpec((1, bkv, 1, D), lambda s, h, j: (s, j, h, 0)),
-            pl.BlockSpec((1, bkv, 1, D), lambda s, h, j: (s, j, h, 0)),
-            pl.BlockSpec((1, bkv), lambda s, h, j: (s, j)),
-            pl.BlockSpec((1, 1), lambda s, h, j: (s, 0),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, Hk, R, D),
+                         lambda s, j, bt_ref, vis_ref: (s, 0, 0, 0)),
+            page, page,
+            *([page_scale, page_scale] if quantized else []),
         ],
         out_specs=(
-            pl.BlockSpec((1, 1, 1, G, D), lambda s, h, j: (s, h, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda s, h, j: (s, h, j, 0)),
+            pl.BlockSpec((1, 1, Hk, R, D),
+                         lambda s, j, bt_ref, vis_ref: (s, j, 0, 0, 0)),
+            pl.BlockSpec((1, 1, Hk, R, 1),
+                         lambda s, j, bt_ref, vis_ref: (s, j, 0, 0, 0)),
         ),
+    )
+    o_p, l_p = pl.pallas_call(
+        functools.partial(_decode_kernel, nq=nq, bs=bs, window=window,
+                          scale=float(scale), acc_dt=acc_dt,
+                          quantized=quantized),
+        grid_spec=grid_spec,
         out_shape=(
-            jax.ShapeDtypeStruct((S, Hk, nk, G, D), acc_dt),
-            jax.ShapeDtypeStruct((S, Hk, nk, G), acc_dt),
+            jax.ShapeDtypeStruct((S, bps, Hk, R, D), acc_dt),
+            jax.ShapeDtypeStruct((S, bps, Hk, R, 1), acc_dt),
         ),
         interpret=_interpret(),
-    )(q4, kc, vc, valid, vis2)
+    )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(visible, jnp.int32),
+      q4, kp, vp,
+      *((k_scale[:, None], v_scale[:, None]) if quantized else ()))
 
-    # logaddexp merge across partitions (the flash_attention_lse algebra):
-    # out = sum_p exp(L_p - L_tot) * o_p. Skipped partitions carry
+    # logaddexp merge across blocks (the flash_attention_lse algebra):
+    # out = sum_p exp(L_p - L_tot) * o_p. Skipped blocks carry
     # L_p = NEG_INF -> weight 0; a fully-masked row (cannot happen for
     # visible >= 1, but kept safe) gets denom >= 1 and o_p = 0 -> output 0,
     # matching the dense path's zeroed fully-masked rows.
-    m = jnp.max(l_p, axis=2, keepdims=True)          # (S, Hk, 1, G)
-    w = jnp.exp(l_p - jnp.maximum(m, NEG_INF))       # (S, Hk, nk, G)
-    denom = jnp.maximum(jnp.sum(w, axis=2), 1e-30)   # (S, Hk, G)
-    out = jnp.einsum("shkg,shkgd->shgd", w, o_p) / denom[..., None]
-    return out.reshape(S, H, D).astype(q.dtype)
-
-
-register_helper("decode_attention", default_on=True)(flash_decode_attention)
+    m = jnp.max(l_p, axis=1, keepdims=True)          # (S, 1, Hk, R, 1)
+    w = jnp.exp(l_p - jnp.maximum(m, NEG_INF))       # (S, bps, Hk, R, 1)
+    denom = jnp.maximum(jnp.sum(w, axis=1), 1e-30)   # (S, Hk, R, 1)
+    return jnp.sum(w * o_p, axis=1) / denom
 
 
 # --------------------------------------------------------------- paged path
@@ -283,27 +274,20 @@ def flash_decode_attention_paged(q, kp, vp, block_tables, visible, scale,
                                  v_scale=None):
     """Block-table-aware split-K flash-decode: same contract as
     `decode_attention_dense_paged`, computed with one grid cell per
-    (slot, kv head, LOGICAL block) and the logical -> physical lookup done
-    by the k/v index_maps through the scalar-prefetched block table. A
-    partition IS a physical block (bkv = block_size — physical blocks are
-    not contiguous in HBM, so larger partitions cannot be one tile); the
-    kernel body and the logaddexp merge are shared with the slot-path
-    kernel. Falls back to the dense paged path when block_size < 8 (tile
-    too small for the TPU layout) — fallback and kernel are value-identical
+    (slot, LOGICAL block) and the logical -> physical lookup done by the
+    k/v index_maps through the scalar-prefetched block table. A partition
+    IS a physical block (physical blocks are not contiguous in HBM, so
+    larger partitions cannot be one tile). Falls back to the dense paged
+    path when block_size < 8 — fallback and kernel are value-identical
     either way.
 
     Quantized pool (ISSUE 15): pass k_scale/v_scale (num_blocks + 1, Hk)
-    with int8 kp/vp. The scales ride as two extra (1, 1) SMEM operands
-    whose index_map is the SAME block-table lookup as the k/v tiles — each
-    grid cell receives exactly its block's per-head scale and dequantizes
-    its own int8 tile in-register (`_decode_kernel`). The pool streams at
-    the int8 byte count and is never materialized dequantized anywhere."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    with int8 kp/vp; each cell receives its block's per-head scales and
+    dequantizes its own int8 tile in-register (`_decode_kernel`). The pool
+    streams at the int8 byte count and is never materialized dequantized
+    anywhere."""
     S, H, D = q.shape
     bs, Hk = kp.shape[1], kp.shape[2]
-    bps = block_tables.shape[1]
-    quantized = k_scale is not None
     if H % Hk != 0:
         raise ValueError(f"n_heads {H} % n_kv_heads {Hk} != 0")
     if bs < 8:
@@ -311,86 +295,8 @@ def flash_decode_attention_paged(q, kp, vp, block_tables, visible, scale,
                                             visible, scale, window,
                                             k_scale=k_scale,
                                             v_scale=v_scale)
-    G = H // Hk
-    L = bps * bs
-    acc_dt = jnp.promote_types(q.dtype, jnp.float32)
-    q4 = q.reshape(S, Hk, G, D)
-    visible = jnp.asarray(visible, jnp.int32)
-    # per-position visibility over the LOGICAL length axis (identical mask
-    # algebra to the slot path — the kernel reads one (bs,) stripe per cell)
-    j = jnp.arange(L)[None, :]
-    valid = j < visible[:, None]
-    if window:
-        valid = valid & (visible[:, None] - 1 - j < window)
-    valid = valid.astype(jnp.int32)                  # (S, L)
-    vis2 = visible[:, None]                          # (S, 1) SMEM scalar feed
-
-    def kern(bt_ref, *refs):
-        # the scalar-prefetch operand arrives as the leading kernel ref; the
-        # body only needs it in the index_maps — drop it and run the SAME
-        # math as the slot-path kernel (with this cell's block scales when
-        # the pool is quantized)
-        if quantized:
-            (q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, vis_ref,
-             o_ref, l_ref) = refs
-            _decode_kernel(q_ref, k_ref, v_ref, m_ref, vis_ref, o_ref,
-                           l_ref, bkv=bs, window=window,
-                           scale=float(scale), acc_dt=acc_dt,
-                           ks_ref=ks_ref, vs_ref=vs_ref)
-        else:
-            _decode_kernel(*refs, bkv=bs, window=window,
-                           scale=float(scale), acc_dt=acc_dt)
-    # PrefetchScalarGridSpec: block_tables rides as the scalar-prefetch
-    # operand and every index_map takes it as a trailing ref — the k/v maps
-    # do the paging gather (logical block j of slot s lives at physical
-    # block bt_ref[s, j]); q/mask/visible index on logical coordinates.
-    # The scale operands (quantized pool) use the same physical lookup so
-    # each cell's SMEM scalar is its own block's per-head scale.
-    scale_specs = [
-        pl.BlockSpec((1, 1), lambda s, h, j, bt_ref: (bt_ref[s, j], h),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, 1), lambda s, h, j, bt_ref: (bt_ref[s, j], h),
-                     memory_space=pltpu.SMEM),
-    ] if quantized else []
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(S, Hk, bps),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D),
-                         lambda s, h, j, bt_ref: (s, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda s, h, j, bt_ref: (bt_ref[s, j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda s, h, j, bt_ref: (bt_ref[s, j], 0, h, 0)),
-            *scale_specs,
-            pl.BlockSpec((1, bs), lambda s, h, j, bt_ref: (s, j)),
-            pl.BlockSpec((1, 1), lambda s, h, j, bt_ref: (s, 0),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, 1, 1, G, D),
-                         lambda s, h, j, bt_ref: (s, h, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, G),
-                         lambda s, h, j, bt_ref: (s, h, j, 0)),
-        ),
-    )
-    scale_ops = (k_scale, v_scale) if quantized else ()
-    o_p, l_p = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((S, Hk, bps, G, D), acc_dt),
-            jax.ShapeDtypeStruct((S, Hk, bps, G), acc_dt),
-        ),
-        interpret=_interpret(),
-    )(jnp.asarray(block_tables, jnp.int32), q4, kp, vp, *scale_ops,
-      valid, vis2)
-
-    # same logaddexp merge as the slot path (see above)
-    m = jnp.max(l_p, axis=2, keepdims=True)          # (S, Hk, 1, G)
-    w = jnp.exp(l_p - jnp.maximum(m, NEG_INF))       # (S, Hk, bps, G)
-    denom = jnp.maximum(jnp.sum(w, axis=2), 1e-30)   # (S, Hk, G)
-    out = jnp.einsum("shkg,shkgd->shgd", w, o_p) / denom[..., None]
+    out = _paged_split_k(q.reshape(S, Hk, H // Hk, D), kp, vp, block_tables,
+                         visible, scale, window, k_scale, v_scale, nq=1)
     return out.reshape(S, H, D).astype(q.dtype)
 
 
@@ -425,82 +331,22 @@ def decode_attention_dense_spec_paged(q, kp, vp, block_tables, visible,
     return jnp.stack(outs, axis=1)                   # (S, Q, H, D)
 
 
-def _spec_decode_kernel(q_ref, k_ref, v_ref, m_ref, vis_ref, o_ref, l_ref, *,
-                        nq, bkv, window, scale, acc_dt, ks_ref=None,
-                        vs_ref=None):
-    """Multi-query generalization of `_decode_kernel`: one grid cell =
-    (slot, kv head, length partition), scoring all Q query positions of the
-    slot against this partition's bkv cache positions. The FlashAttention-2
-    online-softmax algebra is unchanged — the query tile just grows from
-    (G, D) to (Q*G, D), with the per-QUERY visibility mask (query i sees
-    j < vis + i) applied per (query, position) from the precomputed
-    (S, Q, L) mask stripe. Partitions no query can see emit (0, NEG_INF)."""
-    from jax.experimental import pallas as pl
-    j = pl.program_id(2)
-    vis = vis_ref[0, 0]                              # query 0's visible length
-    lo = j * bkv
-    run = lo < vis + nq - 1                          # any query sees any pos?
-    if window:
-        run = run & (lo + bkv > vis - window)        # union over queries
-
-    @pl.when(run)
-    def _():
-        nG, D = q_ref.shape[3], q_ref.shape[4]
-        q = q_ref[0, 0].reshape(nq * nG, D).astype(acc_dt)
-        k = k_ref[0, :, 0, :].astype(acc_dt)         # (bkv, D)
-        if ks_ref is not None:                       # int8 tile dequant
-            k = k * ks_ref[0, 0].astype(acc_dt)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=acc_dt) * scale
-        s = s.reshape(nq, nG, bkv)
-        valid = m_ref[0, :, :] > 0                   # (Q, bkv)
-        s = jnp.where(valid[:, None, :], s, NEG_INF)
-        m = jnp.max(s, axis=2)                       # (Q, G)
-        p = jnp.exp(s - m[:, :, None])
-        p = jnp.where(valid[:, None, :], p, 0.0)
-        l = jnp.sum(p, axis=2)                       # (Q, G)
-        v = v_ref[0, :, 0, :].astype(acc_dt)         # (bkv, D)
-        if vs_ref is not None:
-            v = v * vs_ref[0, 0].astype(acc_dt)
-        o = jax.lax.dot_general(p.reshape(nq * nG, bkv), v,
-                                (((1,), (0,)), ((), ())),
-                                preferred_element_type=acc_dt)
-        o = o.reshape(nq, nG, D)
-        o_ref[0, 0, 0] = (o / jnp.maximum(l, 1e-30)[:, :, None]).astype(
-            o_ref.dtype)
-        l_ref[0, 0, 0] = jnp.where(
-            l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF)
-
-    @pl.when(jnp.logical_not(run))
-    def _():
-        o_ref[0, 0, 0] = jnp.zeros_like(o_ref[0, 0, 0])
-        l_ref[0, 0, 0] = jnp.full_like(l_ref[0, 0, 0], NEG_INF)
-
-
 def flash_decode_attention_spec_paged(q, kp, vp, block_tables, visible,
                                       scale, window: int = 0,
                                       k_scale=None, v_scale=None):
     """Block-table-aware split-K flash-decode over Q query positions per
     slot (speculative verification): same contract as
-    `decode_attention_dense_spec_paged`, same grid as the single-query paged
-    kernel — one cell per (slot, kv head, logical block), block table
-    scalar-prefetched into the k/v index_maps — with the query tile widened
-    to (Q, G, D) so all draft positions are scored in ONE dispatch at
-    unchanged k/v bytes moved (the whole point: decode is HBM-bound on the
-    cache stream, so Q-for-1 amortizes the stream). Falls back to the dense
-    spec oracle when block_size < 8 — value-identical either way.
-
-    Quantized pool: identical scale plumbing to the single-query paged
-    kernel — two extra (1, 1) SMEM operands resolved through the block
-    table, tile dequant inside `_spec_decode_kernel`. Quantization
-    compounds with the Q-for-1 amortization: the int8 stream is the same
-    bytes whether one or Q queries consume it."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    `decode_attention_dense_spec_paged`, same grid and kernel as the
+    single-query paged path with each kv head's query tile widened from
+    (G, D) to (Q*G, D), so all draft positions are scored in ONE dispatch
+    at unchanged k/v bytes moved (the whole point: decode is HBM-bound on
+    the cache stream, so Q-for-1 amortizes the stream). Falls back to the
+    dense spec oracle when block_size < 8 — value-identical either way.
+    A quantized pool takes the same k_scale/v_scale as the single-query
+    kernel: the int8 stream is the same bytes whether one or Q queries
+    consume it."""
     S, Q, H, D = q.shape
     bs, Hk = kp.shape[1], kp.shape[2]
-    bps = block_tables.shape[1]
-    quantized = k_scale is not None
     if H % Hk != 0:
         raise ValueError(f"n_heads {H} % n_kv_heads {Hk} != 0")
     if bs < 8:
@@ -509,80 +355,11 @@ def flash_decode_attention_spec_paged(q, kp, vp, block_tables, visible,
                                                  k_scale=k_scale,
                                                  v_scale=v_scale)
     G = H // Hk
-    L = bps * bs
-    acc_dt = jnp.promote_types(q.dtype, jnp.float32)
-    q5 = q.reshape(S, Q, Hk, G, D).transpose(0, 2, 1, 3, 4)  # (S,Hk,Q,G,D)
-    visible = jnp.asarray(visible, jnp.int32)
-    # per-(query, position) visibility over the logical length axis: query i
-    # sits at position visible - 1 + i, so it sees j < visible + i and (with
-    # a sliding window) j within window of its own position
-    j = jnp.arange(L)[None, None, :]                 # (1, 1, L)
-    i = jnp.arange(Q)[None, :, None]                 # (1, Q, 1)
-    vis3 = visible[:, None, None]                    # (S, 1, 1)
-    valid = j < vis3 + i
-    if window:
-        valid = valid & (vis3 + i - 1 - j < window)
-    valid = valid.astype(jnp.int32)                  # (S, Q, L)
-    vis2 = visible[:, None]                          # (S, 1) SMEM scalar feed
-
-    def kern(bt_ref, *refs):
-        if quantized:
-            (q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, vis_ref,
-             o_ref, l_ref) = refs
-            _spec_decode_kernel(q_ref, k_ref, v_ref, m_ref, vis_ref,
-                                o_ref, l_ref, nq=Q, bkv=bs, window=window,
-                                scale=float(scale), acc_dt=acc_dt,
-                                ks_ref=ks_ref, vs_ref=vs_ref)
-        else:
-            _spec_decode_kernel(*refs, nq=Q, bkv=bs, window=window,
-                                scale=float(scale), acc_dt=acc_dt)
-    scale_specs = [
-        pl.BlockSpec((1, 1), lambda s, h, j, bt_ref: (bt_ref[s, j], h),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, 1), lambda s, h, j, bt_ref: (bt_ref[s, j], h),
-                     memory_space=pltpu.SMEM),
-    ] if quantized else []
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(S, Hk, bps),
-        in_specs=[
-            pl.BlockSpec((1, 1, Q, G, D),
-                         lambda s, h, j, bt_ref: (s, h, 0, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda s, h, j, bt_ref: (bt_ref[s, j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda s, h, j, bt_ref: (bt_ref[s, j], 0, h, 0)),
-            *scale_specs,
-            pl.BlockSpec((1, Q, bs), lambda s, h, j, bt_ref: (s, 0, j)),
-            pl.BlockSpec((1, 1), lambda s, h, j, bt_ref: (s, 0),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, 1, 1, Q, G, D),
-                         lambda s, h, j, bt_ref: (s, h, j, 0, 0, 0)),
-            pl.BlockSpec((1, 1, 1, Q, G),
-                         lambda s, h, j, bt_ref: (s, h, j, 0, 0)),
-        ),
-    )
-    scale_ops = (k_scale, v_scale) if quantized else ()
-    o_p, l_p = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((S, Hk, bps, Q, G, D), acc_dt),
-            jax.ShapeDtypeStruct((S, Hk, bps, Q, G), acc_dt),
-        ),
-        interpret=_interpret(),
-    )(jnp.asarray(block_tables, jnp.int32), q5, kp, vp, *scale_ops,
-      valid, vis2)
-
-    # same logaddexp merge, with the extra Q axis riding along
-    m = jnp.max(l_p, axis=2, keepdims=True)          # (S, Hk, 1, Q, G)
-    w = jnp.exp(l_p - jnp.maximum(m, NEG_INF))       # (S, Hk, bps, Q, G)
-    denom = jnp.maximum(jnp.sum(w, axis=2), 1e-30)   # (S, Hk, Q, G)
-    out = jnp.einsum("shkqg,shkqgd->shqgd", w, o_p) / denom[..., None]
-    out = out.transpose(0, 2, 1, 3, 4).reshape(S, Q, H, D)
-    return out.astype(q.dtype)
+    q4 = q.reshape(S, Q, Hk, G, D).transpose(0, 2, 1, 3, 4)  # (S,Hk,Q,G,D)
+    out = _paged_split_k(q4.reshape(S, Hk, Q * G, D), kp, vp, block_tables,
+                         visible, scale, window, k_scale, v_scale, nq=Q)
+    out = out.reshape(S, Hk, Q, G, D).transpose(0, 2, 1, 3, 4)
+    return out.reshape(S, Q, H, D).astype(q.dtype)
 
 
 register_helper("decode_attention_spec_paged",

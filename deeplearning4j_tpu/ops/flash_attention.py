@@ -58,9 +58,10 @@ def _interpret() -> bool:
     return interpret_mode()
 
 
-# bq/bk = 0 means "auto": 1024 tiles at long T, 512 below (A/B'd on chip,
-# experiments/flash_block_ab.py — 1024/1024 is +13% over 512/512 at the
-# bench shape T=8192 Dh=64; 256 tiles are 15-28% WORSE, so 512 floors it).
+# bq/bk = 0 means "auto": 1024 tiles at long T, 512 below (a plug-in-era
+# chip A/B, not re-measured on today's installation — PERF.md "Tried, no
+# win": 1024/1024 was +13% over 512/512 at the bench shape T=8192 Dh=64;
+# 256 tiles were 15-28% WORSE, so 512 floors it).
 DEFAULT_BQ = 0
 DEFAULT_BK = 0
 

@@ -72,11 +72,12 @@ def _interpret() -> bool:
     return interpret_mode()
 
 
-# Headroom under Mosaic's 16 MB scoped VMEM limit, CALIBRATED against real
-# compiles (r5 A/B, experiments/lstm_grid_ab*.py): at the bench shape
-# (H=256, bf16) the estimate for the largest config that compiles (bwd
-# bt=512) is 14.69 MB and the smallest that fails (bwd bt=1024, fwd
-# bt=2048, tm 1024/512) estimates >= 19 MB — 15 MB splits them.
+# Headroom under Mosaic's 16 MB scoped VMEM limit, calibrated against
+# compiles: at the bench shape (H=256, bf16) the estimate for the largest
+# config that compiles (bwd bt=512) is 14.69 MB and the smallest that fails
+# (bwd bt=1024, fwd bt=2048, tm 1024/512) estimates >= 19 MB — 15 MB splits
+# them. The picked layout (fwd 1024 / bwd 512) compiles under libtpu 0.0.34
+# for a v5e (tests/test_kernels_lower_for_tpu.py; PERF.md, PR 21).
 VMEM_BUDGET = 15 * 1024 * 1024
 
 _TILES = (2048, 1024, 512, 256, 128, 64, 32, 16, 8)
@@ -103,8 +104,7 @@ def configure(**kw):
 
     NOTE: the knobs are read at TRACE time — a function jitted before the
     configure() call keeps its compiled layout (JAX returns the cached
-    executable). A/B harnesses must build a fresh jit per configuration
-    (experiments/lstm_grid_ab.py does)."""
+    executable). An A/B harness must build a fresh jit per configuration."""
     prev = dict(_CONFIG)
     for k, v in kw.items():
         if k not in _CONFIG:

@@ -331,12 +331,21 @@ def threshold_encode_pallas(update: jnp.ndarray, residual: jnp.ndarray,
     from jax.experimental import pallas as pl
     n = update.shape[0]
     lanes = 128
-    rows = max(8, (n + lanes - 1) // lanes)
+    rows = -(-n // lanes)
+    # row tiles of at most 1024 x 128: one input and two output blocks,
+    # double-buffered, stay far under the 16 MiB scoped-VMEM limit at any n
+    # (ungridded, a 1M-element update asked for 20 MB and did not compile)
+    tile = min(1024, -(-rows // 8) * 8)
+    rows = -(-rows // tile) * tile
     acc = update + residual
     acc2d = jnp.zeros((rows * lanes,), update.dtype).at[:n].set(acc) \
         .reshape(rows, lanes)
+    block = pl.BlockSpec((tile, lanes), lambda i: (i, 0))
     msg2d, res2d = pl.pallas_call(
         _make_threshold_kernel(float(threshold)),
+        grid=(rows // tile,),
+        in_specs=[block],
+        out_specs=(block, block),
         out_shape=(jax.ShapeDtypeStruct((rows, lanes), update.dtype),
                    jax.ShapeDtypeStruct((rows, lanes), update.dtype)),
         interpret=_interpret(),

@@ -34,17 +34,11 @@ def make_mesh(num_devices: Optional[int] = None,
 
 
 def compat_shard_map(fn, mesh: Mesh, in_specs, out_specs):
-    """shard_map across jax versions: `jax.shard_map(..., check_vma=False)`
-    where it exists (jax >= 0.6), else `jax.experimental.shard_map` with
-    the older `check_rep=False` spelling of the same knob. Replication
-    checking stays off either way (the repo idiom — the bodies use
-    collectives whose replication the checker can't always prove)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    """`jax.shard_map` with replication checking off (the repo idiom — the
+    bodies use collectives whose replication the checker can't always
+    prove)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def replica_submeshes(mesh: Mesh, inner_axis: Optional[str] = None

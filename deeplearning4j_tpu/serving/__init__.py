@@ -5,7 +5,7 @@ sampling. See serving/engine.py for the design overview;
 engine behind the existing inference API."""
 from deeplearning4j_tpu.serving.block_table import (BlockAllocator,
                                                     PrefixRegistry)
-from deeplearning4j_tpu.serving.decode import (StackDecoder, decode_attention,
+from deeplearning4j_tpu.serving.decode import (StackDecoder,
                                                decode_attention_paged,
                                                decode_attention_spec_paged,
                                                one_hot_embedder)
@@ -41,7 +41,7 @@ __all__ = [
     "KVCache", "init_cache_state", "BlockAllocator", "PrefixRegistry",
     "HostBlockPool", "KVLifecycleManager", "PersistentPrefixStore",
     "resolve_lifecycle", "resolve_prefix_store",
-    "StackDecoder", "decode_attention", "decode_attention_paged",
+    "StackDecoder", "decode_attention_paged",
     "decode_attention_spec_paged",
     "one_hot_embedder", "ServingEngine", "Request", "GenerationResult",
     "Sampler", "sample_tokens", "spec_accept_tokens",

@@ -2,21 +2,22 @@
 
 Beyond-reference: the attention stack recomputes all T x T scores per token
 (O(T^2) per generated token); this module makes generation O(T) per token by
-attending a SINGLE query position against the slot-based cache
+attending a SINGLE query position against the paged cache
 (serving/kv_cache.py).
 
 Two pieces:
 
-- `decode_attention`: the masked single-query dot-product against the cache,
-  GQA-aware without materializing the head repeat (q is reshaped to
-  (S, Hk, G, D) and contracted directly against the (S, L, Hk, D) cache —
+- `decode_attention_paged`: the masked single-query dot-product against the
+  paged cache, GQA-aware without materializing the head repeat (q is
+  reshaped to (S, Hk, G, D) and contracted directly against the cache —
   query head h = hk*G + g reads kv head hk, the SAME grouping as
   ops/flash_attention._kv_row and the layer's jnp.repeat fallback). Scores
   and softmax run in fp32 (fp64 under x64), streams stay in the cache dtype
   (bf16 on TPU). Dispatches through the helper seam to the split-K
   flash-decode Pallas kernel (ops/decode_attention.py, default-on for TPU)
-  which partitions the cache length axis and merges partials via logaddexp;
-  the dense einsum path here is the fp64 oracle and universal fallback.
+  which partitions the cache length axis by block and merges partials via
+  logaddexp; the dense gather + einsum path is the fp64 oracle and
+  universal fallback.
 
 - `StackDecoder`: a stateful prefill-then-decode wrapper over an already
   initialized MultiLayerNetwork / ComputationGraph whose hidden layers are
@@ -65,8 +66,7 @@ from deeplearning4j_tpu.nn.conf.layers.feedforward import (
     ActivationLayer, DropoutLayer, LossLayer)
 from deeplearning4j_tpu.nn.conf.layers.recurrent import RnnOutputLayer
 from deeplearning4j_tpu.ops.decode_attention import (
-    decode_attention_dense, decode_attention_dense_paged,
-    decode_attention_dense_spec_paged)
+    decode_attention_dense_paged, decode_attention_dense_spec_paged)
 from deeplearning4j_tpu.ops.helpers import helper_for
 from deeplearning4j_tpu.serving import kv_cache, quant
 
@@ -78,29 +78,17 @@ NEG_INF = -1e30
 _POSITIONWISE = (RnnOutputLayer, ActivationLayer, DropoutLayer, LossLayer)
 
 
-def decode_attention(q, kc, vc, visible, scale, window: int = 0):
-    """Single-query attention against the cache.
-
-    q: (S, H, D) current-position queries; kc/vc: (S, L, Hk, D) cache
-    (current position already appended); visible: (S,) number of visible
-    positions per slot (= position index + 1); `window` > 0 applies the
-    layer's sliding-window semantics (query at position visible-1 sees keys
-    j with (visible-1) - j < window). Returns (S, H, D) in q.dtype.
-
-    Resolved through the helper seam at trace time: the split-K
-    flash-decode Pallas kernel (ops/decode_attention.flash_decode_attention,
-    default-on for TPU) when enabled, else the dense einsum oracle
-    (ops/decode_attention.decode_attention_dense)."""
-    fn = helper_for("decode_attention", decode_attention_dense)
-    return fn(q, kc, vc, visible, scale, window)
-
-
 def decode_attention_paged(q, kp, vp, block_tables, visible, scale,
                            window: int = 0, k_scale=None, v_scale=None):
-    """Single-query attention against the PAGED cache: same contract as
-    `decode_attention`, but kc/vc are the (num_blocks + 1, block_size, Hk,
-    D) physical blocks and each slot's positions resolve through its
-    (blocks_per_seq,) block-table row. Resolved through the helper seam:
+    """Single-query attention against the PAGED cache.
+
+    q: (S, H, D) current-position queries; kp/vp: (num_blocks + 1,
+    block_size, Hk, D) physical blocks (current position already appended)
+    resolved per slot through its (blocks_per_seq,) block-table row;
+    visible: (S,) number of visible positions per slot (= position index +
+    1); `window` > 0 applies the layer's sliding-window semantics (query at
+    position visible-1 sees keys j with (visible-1) - j < window). Returns
+    (S, H, D) in q.dtype. Resolved through the helper seam:
     the block-table-aware split-K kernel
     (ops/decode_attention.flash_decode_attention_paged, default-on for
     TPU — the gather stays INSIDE the kernel via scalar prefetch) when
@@ -270,8 +258,10 @@ class StackDecoder:
             paged_spec_attention if paged_spec_attention is not None
             else decode_attention_spec_paged)
         self._prefill_jit = jax.jit(self._prefill_fn)
+        # kv_blocks is static and POSITIONAL: jit rejects keyword arguments
+        # once in_shardings are pinned (serving/sharding.py re-jits this)
         self._prefill_shared_jit = jax.jit(self._prefill_shared_fn,
-                                           static_argnames=("kv_blocks",))
+                                           static_argnums=(6,))
         self._decode_jit = jax.jit(self._decode_fn)
         self._profiled_buckets: set = set()   # prefill cost-registry dedup
         self.metrics = None    # engine installs its child registry here so
@@ -333,7 +323,7 @@ class StackDecoder:
         return cache_state, self._head_logprobs(h_last[None])[0]
 
     def _prefill_shared_fn(self, params, cache_state, x, slot, plen,
-                           shared_len, *, kv_blocks):
+                           shared_len, kv_blocks):
         """Shared-prefix prompt pass: x (n_in, Ts_pad) features of the
         SUFFIX only (logical positions [shared_len, plen)) — the prefix KV
         is already resident in blocks admission mapped shared. Scatters the
@@ -559,15 +549,13 @@ class StackDecoder:
                 profiler.register(
                     f"prefill_shared_b{Tsp}k{kvb}", self._prefill_shared_jit,
                     (self.params, self.cache.state, x, slot_a, plen_a,
-                     shared_a),
-                    kwargs={"kv_blocks": kvb},
+                     shared_a, kvb),
                     meta={"bucket": Tsp, "kv_blocks": kvb},
                     registry=self.metrics)
             except Exception:
                 pass
         self.cache.state, logprobs = self._prefill_shared_jit(
-            self.params, self.cache.state, x, slot_a, plen_a, shared_a,
-            kv_blocks=kvb)
+            self.params, self.cache.state, x, slot_a, plen_a, shared_a, kvb)
         return logprobs
 
     def prefill_chunk(self, slot: int, x, start: int,
@@ -604,15 +592,13 @@ class StackDecoder:
                 profiler.register(
                     f"prefill_shared_b{Tsp}k{kvb}", self._prefill_shared_jit,
                     (self.params, self.cache.state, x, slot_a, end_a,
-                     start_a),
-                    kwargs={"kv_blocks": kvb},
+                     start_a, kvb),
                     meta={"bucket": Tsp, "kv_blocks": kvb},
                     registry=self.metrics)
             except Exception:
                 pass
         self.cache.state, logprobs = self._prefill_shared_jit(
-            self.params, self.cache.state, x, slot_a, end_a, start_a,
-            kv_blocks=kvb)
+            self.params, self.cache.state, x, slot_a, end_a, start_a, kvb)
         return logprobs
 
     def decode_step(self, x, active) -> jnp.ndarray:

@@ -400,23 +400,11 @@ class ShardedServingEngine(ServingEngine):
             dec._prefill_fn,
             in_shardings=(ps, cs, rep, rep, rep),
             out_shardings=(cs, rep))
-        # older pjit rejects kwargs alongside in_shardings, and the decoder
-        # calls the shared prefill with kv_blocks=...: route the keyword
-        # through a positional static arg
-        _shared_positional = jax.jit(
-            lambda p, c, x, s, pl, sh, kvb: dec._prefill_shared_fn(
-                p, c, x, s, pl, sh, kv_blocks=kvb),
+        dec._prefill_shared_jit = jax.jit(
+            dec._prefill_shared_fn,
             static_argnums=(6,),
             in_shardings=(ps, cs, rep, rep, rep, rep),
             out_shardings=(cs, rep))
-
-        def _shared_jit(p, c, x, s, pl, sh, *, kv_blocks):
-            return _shared_positional(p, c, x, s, pl, sh, kv_blocks)
-
-        _shared_jit.lower = (  # profiler.register lowers for cost analysis
-            lambda p, c, x, s, pl, sh, *, kv_blocks:
-            _shared_positional.lower(p, c, x, s, pl, sh, kv_blocks))
-        dec._prefill_shared_jit = _shared_jit
         dec._decode_jit = jax.jit(
             dec._decode_fn,
             in_shardings=(ps, cs, rep, rep),

@@ -25,13 +25,12 @@ ISSUE 6 tentpole, part 1+3. Three jobs:
    device trace's clock) so host spans and device ops land in one Perfetto
    view.
 
-Honesty notes (the roofline table in PERF.md is generated from this data):
-- `mxu_floor_ms` is flops / peak-FLOPs. On platforms without a peak entry
-  (CPU test runs) the **reference** peak — TPU v5e bf16, 197 TFLOP/s, the
-  ROADMAP's roofline target — is used so attribution ratios exist
-  everywhere; rows and gauges carry the platform so a CPU-measured ms is
-  never mistaken for a TPU claim (`profiler.platform_has_peak` gauge,
-  `platform` field in `roofline_table()`).
+Honesty notes:
+- `mxu_floor_ms` is flops / peak FLOP/s, with the peak taken from
+  `DEVICE_PEAKS` by the chip's `device_kind`. A TPU kind that is not in the
+  table raises; off a TPU there is no peak, so rows and gauges carry the
+  platform, the measured ms and the cost-model counts, and no floor, no
+  MFU and no roofline fraction.
 - `bytes_accessed` is XLA's per-HLO sum (ignores fusion reuse) — the
   optimistic-roof side of the bracket, same caveat as PERF.md.
 
@@ -55,12 +54,17 @@ from deeplearning4j_tpu.telemetry.registry import (DEFAULT_MS_BUCKETS,
                                                    sanitize_component)
 from deeplearning4j_tpu.util import costs as _costs
 
-# bf16 peak FLOP/s per chip by jax.default_backend() name. TPU v5e (lite)
-# MXU peak — the denominator the ROADMAP roofline item tracks. Extend via
-# configure(peak_flops=...) for other parts.
-PEAK_FLOPS: Dict[str, float] = {"tpu": 197e12}
-HBM_GBS: Dict[str, float] = {"tpu": 819e9}
-REFERENCE_PLATFORM = "tpu"
+# Published per-chip peaks, keyed by `jax.devices()[0].device_kind`. The one
+# table every floor, MFU and roofline share in the repo divides by (bench.py
+# imports it). A device that is not listed is an error, not a default.
+DEVICE_PEAKS: Dict[str, dict] = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "hbm_bytes_per_s": 819e9,
+        "source": 'Google Cloud documentation, "TPU v5e": 197 TFLOP/s '
+                  "bf16, 819 GB/s HBM per chip",
+    },
+}
 
 _FALSEY = ("", "0", "false", "off")
 _TRUTHY_COSTS_ONLY = ("1", "true", "on", "costs", "yes")
@@ -70,6 +74,7 @@ _ENABLED = _env.lower() not in _FALSEY
 _CAPTURE_DIR: Optional[str] = (
     _env if _ENABLED and _env.lower() not in _TRUTHY_COSTS_ONLY else None)
 _PLATFORM: Optional[str] = None          # lazy jax.default_backend()
+_DEVICE_KIND: Optional[str] = None       # lazy jax.devices()[0].device_kind
 
 # host-side per-function aggregates: name -> {count, total_ms, last_ms}
 _OBSERVED: Dict[str, dict] = {}
@@ -90,24 +95,19 @@ def capture_dir() -> Optional[str]:
 def configure(enabled: Optional[bool] = None,
               platform: Optional[str] = None,
               capture_dir: Optional[str] = None,
-              peak_flops: Optional[float] = None,
-              hbm_gbs: Optional[float] = None) -> None:
+              device_kind: Optional[str] = None) -> None:
     """Override env defaults at runtime (tests, bench, embedding apps).
-    `peak_flops`/`hbm_gbs` install an entry for the current (or given)
-    platform."""
-    global _ENABLED, _PLATFORM, _CAPTURE_DIR
+    `platform`/`device_kind` pin what would otherwise be detected from
+    JAX."""
+    global _ENABLED, _PLATFORM, _CAPTURE_DIR, _DEVICE_KIND
     if enabled is not None:
         _ENABLED = bool(enabled)
     if platform is not None:
         _PLATFORM = str(platform)
+    if device_kind is not None:
+        _DEVICE_KIND = str(device_kind)
     if capture_dir is not None:
         _CAPTURE_DIR = capture_dir or None
-    if peak_flops is not None:
-        # sync-ok: configuration scalar from the caller, never a device buffer
-        PEAK_FLOPS[platform or _detect_platform()] = float(peak_flops)
-    if hbm_gbs is not None:
-        # sync-ok: configuration scalar from the caller, never a device buffer
-        HBM_GBS[platform or _detect_platform()] = float(hbm_gbs)
 
 
 def clear_observations() -> None:
@@ -119,13 +119,14 @@ def clear_observations() -> None:
 
 def reset() -> None:
     """Forget observations and restore env-derived config (tests)."""
-    global _ENABLED, _PLATFORM, _CAPTURE_DIR
+    global _ENABLED, _PLATFORM, _CAPTURE_DIR, _DEVICE_KIND
     _OBSERVED.clear()
     env = os.environ.get("DL4J_TPU_PROFILE", "")
     _ENABLED = env.lower() not in _FALSEY
     _CAPTURE_DIR = (env if _ENABLED
                     and env.lower() not in _TRUTHY_COSTS_ONLY else None)
     _PLATFORM = None
+    _DEVICE_KIND = None
 
 
 def _detect_platform() -> str:
@@ -144,23 +145,32 @@ def platform() -> str:
     return _detect_platform()
 
 
-def reference_peak_flops(plat: Optional[str] = None) -> float:
-    """Peak FLOP/s used for floors/MFU: the platform's entry when known,
-    otherwise the v5e REFERENCE peak (attribution aid on CPU, not a
-    hardware claim — `platform_has_peak(plat)` says which case applies)."""
-    plat = plat or _detect_platform()
-    return PEAK_FLOPS.get(plat, PEAK_FLOPS[REFERENCE_PLATFORM])
+def device_peaks(device_kind: Optional[str] = None) -> dict:
+    """The `DEVICE_PEAKS` entry (`bf16_flops`, `hbm_bytes_per_s`, `source`)
+    for `device_kind`, by default the kind of `jax.devices()[0]`. Raises
+    KeyError for a kind the table does not hold — a floor computed from
+    another chip's peak is a wrong number, not an estimate."""
+    global _DEVICE_KIND
+    if device_kind is None:
+        if _DEVICE_KIND is None:
+            import jax
+            _DEVICE_KIND = jax.devices()[0].device_kind
+        device_kind = _DEVICE_KIND
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peak for device_kind {device_kind!r} (known: "
+            f"{sorted(DEVICE_PEAKS)}); add it to telemetry.profiler."
+            "DEVICE_PEAKS with its source") from None
 
 
-def platform_has_peak(plat: Optional[str] = None) -> bool:
-    return (plat or _detect_platform()) in PEAK_FLOPS
-
-
-def mxu_floor_ms(flops: float, plat: Optional[str] = None) -> float:
-    """Compute-roofline floor in ms for `flops` on `plat` (reference peak
-    when the platform has no entry)."""
-    peak = reference_peak_flops(plat)
-    return flops / peak * 1e3 if peak > 0 else 0.0
+def mxu_floor_ms(flops: float, plat: Optional[str] = None) -> Optional[float]:
+    """Compute-roofline floor in ms for `flops`: flops over the chip's bf16
+    peak on a TPU (an unknown kind raises), None on any other platform."""
+    if (plat or _detect_platform()) != "tpu":
+        return None
+    return flops / device_peaks()["bf16_flops"] * 1e3
 
 
 def _default_registry() -> MetricsRegistry:
@@ -169,7 +179,7 @@ def _default_registry() -> MetricsRegistry:
 
 
 # ------------------------------------------------------------- register
-def register(name: str, jitted=None, args=(), kwargs=None, *,
+def register(name: str, jitted=None, args=(), *,
              flops: Optional[float] = None,
              bytes_accessed: Optional[float] = None,
              meta: Optional[dict] = None,
@@ -180,8 +190,8 @@ def register(name: str, jitted=None, args=(), kwargs=None, *,
     AOT `cost_analysis()`, or pass `flops`/`bytes_accessed` directly (bench
     replays already-measured numbers). Registration is explicit — the
     instrumented call sites gate on `enabled()` so default runs never pay
-    the extra lower/compile. Publishes `profiler.fn.<name>.flops/.bytes/
-    .mxu_floor_ms` gauges and returns the cost record.
+    the extra lower/compile. Publishes `profiler.fn.<name>.flops/.bytes`
+    gauges (and `.mxu_floor_ms` on a TPU) and returns the cost record.
 
     Safe to call immediately before dispatching a donated-arg jit (AOT
     lowering does not consume buffers) — and that ordering is REQUIRED for
@@ -190,8 +200,7 @@ def register(name: str, jitted=None, args=(), kwargs=None, *,
     meta = dict(meta or {})
     meta.setdefault("platform", plat)
     if jitted is not None:
-        rec = _costs.analyze_and_record(name, jitted, *args,
-                                        meta=meta, **(kwargs or {}))
+        rec = _costs.analyze_and_record(name, jitted, *args, meta=meta)
     else:
         rec = _costs.record_costs(name, flops or 0.0, bytes_accessed or 0.0,
                                   meta=meta)
@@ -202,13 +211,11 @@ def register(name: str, jitted=None, args=(), kwargs=None, *,
     reg.gauge(f"profiler.fn.{n}.bytes",
               "XLA cost-model bytes accessed per call (per-HLO sum)"
               ).set(rec["bytes_accessed"])
-    reg.gauge(f"profiler.fn.{n}.mxu_floor_ms",
-              "compute-roofline floor ms (reference peak off-TPU)"
-              ).set(mxu_floor_ms(rec["flops"], plat))
-    reg.gauge("profiler.platform_has_peak",
-              "1 when the platform has a real peak-FLOPs entry; 0 means "
-              "floors/MFU use the v5e reference peak (attribution aid)"
-              ).set(1.0 if platform_has_peak(plat) else 0.0)
+    floor = mxu_floor_ms(rec["flops"], plat)
+    if floor is not None:
+        reg.gauge(f"profiler.fn.{n}.mxu_floor_ms",
+                  "compute-roofline floor ms (flops / chip bf16 peak)"
+                  ).set(floor)
     return rec
 
 
@@ -218,8 +225,8 @@ def observe(name: str, ms: float,
     """Feed one measured wall-time (milliseconds, a HOST value the caller
     already holds — never a device read) for a registered function.
     Publishes the ms histogram + measured_ms gauge, and when costs are on
-    file, the mfu / roofline_frac / x_floor gauges. Pure host arithmetic:
-    zero added syncs."""
+    file for a TPU run, the mfu / roofline_frac / x_floor gauges. Pure host
+    arithmetic: zero added syncs."""
     ms = float(ms)  # sync-ok: caller passes a host wall-clock delta
     agg = _OBSERVED.get(name)
     if agg is None:
@@ -238,20 +245,17 @@ def observe(name: str, ms: float,
     rec = _costs.get_costs(name)
     if rec is None or ms <= 0.0:
         return
-    plat = rec.get("meta", {}).get("platform") or _detect_platform()
-    floor = mxu_floor_ms(rec["flops"], plat)
-    if floor > 0.0:
-        reg.gauge(f"profiler.fn.{n}.roofline_frac",
-                  "MXU-floor ms / measured ms (1.0 = at the roofline)"
-                  ).set(floor / ms)
-        reg.gauge(f"profiler.fn.{n}.x_floor",
-                  "measured ms / MXU-floor ms").set(ms / floor)
-    peak = reference_peak_flops(plat)
-    if rec["flops"] > 0.0 and peak > 0.0:
-        reg.gauge(f"profiler.fn.{n}.mfu",
-                  "model FLOPs utilization vs platform peak "
-                  "(reference peak off-TPU)"
-                  ).set(rec["flops"] / (ms * 1e-3) / peak)
+    floor = mxu_floor_ms(rec["flops"], rec.get("meta", {}).get("platform"))
+    if not floor:
+        return
+    reg.gauge(f"profiler.fn.{n}.roofline_frac",
+              "MXU-floor ms / measured ms (1.0 = at the roofline)"
+              ).set(floor / ms)
+    reg.gauge(f"profiler.fn.{n}.x_floor",
+              "measured ms / MXU-floor ms").set(ms / floor)
+    reg.gauge(f"profiler.fn.{n}.mfu",
+              "model FLOPs utilization vs the chip's bf16 peak"
+              ).set(floor / ms)
 
 
 def register_train_loop(owner, key, run, args, steps: int,
@@ -295,10 +299,10 @@ def observed(name: str) -> Optional[dict]:
 
 # ------------------------------------------------------- roofline table
 def roofline_table(registry: Optional[MetricsRegistry] = None) -> List[dict]:
-    """Join registered costs with host aggregates into the rows perf_docs
-    renders: one dict per function with measured vs floor, MFU, bytes.
-    Functions registered but never observed get measured_ms None (compile
-    happened, no timed call yet)."""
+    """Join registered costs with host aggregates: one dict per function
+    with measured vs floor, MFU, bytes. Functions registered but never
+    observed get measured_ms None (compile happened, no timed call yet);
+    rows from a platform other than a TPU carry no floor and no MFU."""
     rows: List[dict] = []
     for name, rec in sorted(_costs.all_costs().items()):
         plat = rec.get("meta", {}).get("platform") or _detect_platform()
@@ -306,27 +310,23 @@ def roofline_table(registry: Optional[MetricsRegistry] = None) -> List[dict]:
         mean_ms = (agg["total_ms"] / agg["count"]
                    if agg and agg["count"] else None)
         floor = mxu_floor_ms(rec["flops"], plat)
-        peak = reference_peak_flops(plat)
         row = {
             "function": name,
             "platform": plat,
             "flops": rec["flops"],
             "bytes_accessed": rec["bytes_accessed"],
-            "mxu_floor_ms": round(floor, 4),
+            "mxu_floor_ms": None if floor is None else round(floor, 4),
             "measured_ms": None if mean_ms is None else round(mean_ms, 4),
             "calls": agg["count"] if agg else 0,
             "mfu": None,
             "x_floor": None,
-            "reference_peak": not platform_has_peak(plat),
         }
-        if mean_ms and mean_ms > 0.0:
-            if rec["flops"] > 0.0 and peak > 0.0:
-                mfu = rec["flops"] / (mean_ms * 1e-3) / peak
-                # keep tiny utilizations exact — rounding a CPU row to 0.0
-                # would read as "no flops ran" (and fail the schema's (0,1))
-                row["mfu"] = round(mfu, 4) if mfu >= 1e-4 else mfu
-            if floor > 0.0:
-                row["x_floor"] = round(mean_ms / floor, 2)
+        if floor and mean_ms and mean_ms > 0.0:
+            mfu = floor / mean_ms
+            # keep tiny utilizations exact — rounding to 0.0 would read as
+            # "no flops ran" (and fail the schema's (0,1))
+            row["mfu"] = round(mfu, 4) if mfu >= 1e-4 else mfu
+            row["x_floor"] = round(mean_ms / floor, 2)
         rows.append(row)
     return rows
 
@@ -358,7 +358,7 @@ def attribute_from_tracer(tracer=None,
             plat = rec.get("meta", {}).get("platform") or _detect_platform()
             floor = mxu_floor_ms(rec["flops"], plat)
             a["mxu_floor_ms"] = floor
-            if floor > 0.0:
+            if floor:
                 a["x_floor"] = a["mean_ms"] / floor
     return agg
 

@@ -1,9 +1,9 @@
-"""Schema gate for the published bench artifact (ISSUE 6 satellite).
+"""Schema gate for the bench artifact (ISSUE 6 satellite).
 
-`BENCH_LATEST.json` is the single source the docs are generated from
-(util/perf_docs.py), so a malformed artifact silently becomes malformed
-published numbers. `validate_artifact` checks the structural contract —
-and the ISSUE 6 additions: every measured entry carries a `platform`
+The JSON line `bench.py` prints is what a reader of a run sees, so a
+malformed artifact silently becomes malformed numbers.
+`validate_artifact` checks the structural contract — and the ISSUE 6
+additions: every measured entry carries a `platform`
 label, `decode_serving`/`decode_serving_k1` are ALWAYS present (skipped
 runs say so via `skipped_reason` instead of vanishing), and the
 auto-generated `roofline_table` rows are well-formed. ISSUE 7 adds
@@ -73,10 +73,9 @@ round-trip on the same forced-overload schedule — CPU-runnable and
 always present; measured entries must prove bit-identical replayed
 tokens, deterministic-alert-count parity, a None divergence localizer,
 and journal overhead under 1% of the recorded wall).
-bench.py calls
-`assert_valid` on the dict it is about to print, and
-tests/test_bench_schema.py re-validates the committed artifact, so the
-contract holds at write time and at review time.
+bench.py calls `assert_valid` on the dict it is about to print;
+tests/test_bench_schema.py validates the validator. `vs_baseline` is null:
+no baseline was measured on today's installation.
 """
 from __future__ import annotations
 
@@ -111,6 +110,9 @@ def validate_artifact(art: dict) -> List[str]:
         return errs
     if not _is_num(art["value"]):
         errs.append("'value' is not a number")
+    if art["vs_baseline"] is not None:
+        errs.append("'vs_baseline' is not null (no baseline has been "
+                    "measured on this installation)")
     if not isinstance(art["unit"], str) or not art["unit"]:
         errs.append("'unit' is not a non-empty string")
     e = art["extra"]
@@ -733,7 +735,7 @@ def validate_artifact(art: dict) -> List[str]:
                 errs.append(f"extra['{name}'] is a measurement dict without "
                             "a 'platform' label")
 
-    # roofline_table rows (auto-generated attribution, rendered into docs)
+    # roofline_table rows (auto-generated attribution)
     table = e.get("roofline_table")
     if table is not None:
         if not isinstance(table, list):

@@ -24,9 +24,14 @@ def lowered_costs(jitted, *args, **kwargs) -> dict:
     per-HLO-instruction sum — an upper-ish estimate of HBM traffic that
     ignores fusion reuse; PERF.md's roofline uses it as the optimistic-roof
     side of the bracket."""
+    return costs_of(jitted.lower(*args, **kwargs))
+
+
+def costs_of(lowered) -> dict:
+    """`lowered_costs` for an already-lowered computation (a
+    `jax.stages.Lowered`, e.g. a net's `lower_train_step`)."""
     try:
-        compiled = jitted.lower(*args, **kwargs).compile()
-        ca = compiled.cost_analysis()
+        ca = lowered.compile().cost_analysis()
         if isinstance(ca, list):
             ca = ca[0] if ca else {}
         return {"flops": float(ca.get("flops", 0.0)),
@@ -35,16 +40,6 @@ def lowered_costs(jitted, *args, **kwargs) -> dict:
         import warnings
         warnings.warn(f"XLA cost analysis unavailable ({type(e).__name__}: {e})")
         return {"flops": 0.0, "bytes_accessed": 0.0}
-
-
-def lowered_flops(jitted, *args, **kwargs) -> Optional[float]:
-    """FLOPs of `jitted(*args, **kwargs)` per XLA's cost model, or None when
-    the backend exposes none (which disables the caller's peak-FLOPS sanity
-    gate — lowered_costs warns in that case). AOT lower/compile — nothing
-    executes and no buffer is donated; callers use it once per bench config,
-    outside timed regions."""
-    flops = lowered_costs(jitted, *args, **kwargs)["flops"]
-    return flops if flops > 0 else None
 
 
 # --------------------------------------------------- named cost registry

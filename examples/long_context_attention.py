@@ -4,21 +4,23 @@ parallelism.
 Demonstrates the two long-context paths of SelfAttentionLayer:
 - single device: T far beyond the dense O(T^2) score tensor's memory, via
   the online-softmax block scan (layer default past `block_size`);
-- 8-device mesh (virtual CPU here; identical code on an ICI slice): the time
-  dimension sharded over a 'seq' axis, with either GSPMD-partitioned dense
-  einsums or the hand-scheduled ring (k/v blocks rotating via ppermute).
+- 8-device mesh: the time dimension sharded over a 'seq' axis, with either
+  GSPMD-partitioned dense einsums or the hand-scheduled ring (k/v blocks
+  rotating via ppermute).
+
+Takes the devices it finds and needs eight of them. On an 8-chip slice:
 
   python examples/long_context_attention.py
+
+For the CPU demo, ask for eight virtual host devices from the environment:
+
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+      python examples/long_context_attention.py
 """
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + \
-    " --xla_force_host_platform_device_count=8"
-import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 

@@ -1,22 +1,20 @@
 """Tensor- and pipeline-parallel training of REAL networks.
 
-Runs anywhere: forces an 8-virtual-device CPU mesh so the sharding logic is
-identical to an 8-chip TPU slice (swap the platform config away on real
-hardware and the same code runs over ICI).
+Takes the devices it finds and needs eight of them: the sharding logic is
+the same on an 8-chip TPU slice (over ICI) and on eight virtual host
+devices. On the slice:
 
   python examples/model_parallel_training.py
+
+For the CPU demo, ask for the virtual devices from the environment:
+
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+      python examples/model_parallel_training.py
 """
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + \
-    " --xla_force_host_platform_device_count=8"
-import jax
-
-# demo runs on the 8-virtual-device CPU mesh; on an 8-chip slice, drop this
-# line and the same code runs over ICI
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 

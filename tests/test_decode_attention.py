@@ -1,12 +1,12 @@
-"""Split-K flash-decode kernel vs the dense single-query oracle.
+"""Paged split-K flash-decode kernel vs the dense paged oracle.
 
 conftest.py forces x64, so `decode_attention_dense` runs in fp64 and the
 kernel (interpret mode off-TPU) must match it to ~1e-12 across the shapes
 the serving engine actually produces: MHA / GQA / MQA head layouts, sliding
 windows, and RAGGED visible lengths (continuous batching means every slot
 sits at a different cache position). Also covers the automatic dense
-fallback (lengths that cannot be partitioned) and the helper-seam wiring
-(an engine built with helpers forced ON stays on the fp64 parity oracle).
+fallback (block_size < 8) and the helper-seam wiring (an engine built with
+helpers forced ON stays on the fp64 parity oracle).
 """
 import numpy as np
 import pytest
@@ -16,94 +16,11 @@ import jax.numpy as jnp
 
 from deeplearning4j_tpu.ops.decode_attention import (
     decode_attention_dense, decode_attention_dense_paged,
-    flash_decode_attention, flash_decode_attention_paged)
+    flash_decode_attention_paged)
 
 
 def _rand(shape, key, dtype=jnp.float64):
     return jax.random.normal(jax.random.PRNGKey(key), shape, dtype)
-
-
-def _case(S, H, Hk, D, L, window, seed=0):
-    q = _rand((S, H, D), seed)
-    kc = _rand((S, L, Hk, D), seed + 1)
-    vc = _rand((S, L, Hk, D), seed + 2)
-    # ragged: every slot at a different position, including the extremes a
-    # serving batch produces (freshly admitted = 1, full prefix = L)
-    vis = jnp.asarray([(7 * (i + 1)) % L + 1 for i in range(S)], jnp.int32)
-    vis = vis.at[0].set(1).at[S - 1].set(L)
-    return q, kc, vc, vis, 1.0 / np.sqrt(D), window
-
-
-SWEEP = [
-    # (S, H, Hk, D, L, window)
-    (3, 4, 4, 16, 64, 0),      # MHA
-    (3, 4, 2, 16, 64, 0),      # GQA group 2
-    (2, 4, 1, 8, 32, 0),       # MQA
-    (3, 4, 2, 16, 64, 5),      # GQA + sliding window
-    (2, 2, 2, 16, 48, 3),      # MHA + window, L with odd partition count
-    (1, 4, 2, 16, 24, 0),      # L forces bkv reduction (24 -> 8)
-]
-
-
-@pytest.mark.parametrize("S,H,Hk,D,L,window", SWEEP)
-def test_split_k_matches_dense_oracle(S, H, Hk, D, L, window):
-    q, kc, vc, vis, scale, w = _case(S, H, Hk, D, L, window)
-    ref = decode_attention_dense(q, kc, vc, vis, scale, w)
-    out = flash_decode_attention(q, kc, vc, vis, scale, w, bkv=16)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=1e-12, rtol=1e-12)
-
-
-def test_split_k_default_block_size():
-    """Auto bkv (256 clamped/halved to fit L) stays on the oracle."""
-    q, kc, vc, vis, scale, w = _case(2, 4, 2, 16, 128, 0, seed=9)
-    ref = decode_attention_dense(q, kc, vc, vis, scale, w)
-    out = flash_decode_attention(q, kc, vc, vis, scale, w)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=1e-12, rtol=1e-12)
-
-
-def test_unpartitionable_length_falls_back_to_dense():
-    """L that cannot form a >= 8-position partition (too short, or an
-    explicit bkv that halves below 8) must take the dense path —
-    bit-identical, not merely close."""
-    q, kc, vc, vis, scale, w = _case(2, 4, 2, 8, 6, 0, seed=4)
-    ref = decode_attention_dense(q, kc, vc, vis, scale, w)
-    out = flash_decode_attention(q, kc, vc, vis, scale, w)
-    assert np.array_equal(np.asarray(out), np.asarray(ref))
-    # bkv=4 against a divisible L: requested block is below the floor
-    q, kc, vc, vis, scale, w = _case(2, 4, 2, 8, 32, 0, seed=5)
-    ref = decode_attention_dense(q, kc, vc, vis, scale, w)
-    out = flash_decode_attention(q, kc, vc, vis, scale, w, bkv=4)
-    assert np.array_equal(np.asarray(out), np.asarray(ref))
-
-
-def test_prime_length_runs_single_partition():
-    """A prime L still runs the kernel (one L-wide partition) and stays on
-    the oracle."""
-    q, kc, vc, vis, scale, w = _case(2, 4, 2, 8, 13, 0, seed=4)
-    ref = decode_attention_dense(q, kc, vc, vis, scale, w)
-    out = flash_decode_attention(q, kc, vc, vis, scale, w)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=1e-12, rtol=1e-12)
-
-
-def test_kernel_engaged_through_serving_engine():
-    """helpers forced ON routes serving decode through the split-K kernel;
-    the engine's captured logprobs must still sit on the full-recompute
-    fp64 oracle (the end-to-end acceptance gate)."""
-    from deeplearning4j_tpu.ops.helpers import helpers_enabled_ctx
-    from deeplearning4j_tpu.serving import Request, ServingEngine
-    from tests.test_serving import _assert_parity, _build_net
-
-    net = _build_net(n_kv=2)
-    prompt = [1, 2, 3, 4, 5]
-    with helpers_enabled_ctx(True):
-        eng = ServingEngine(net, max_seqs=2, max_len=32, seed=0,
-                            capture_logprobs=True)
-        res = eng.generate([Request(prompt, max_new_tokens=6)])[0]
-    assert len(res.tokens) == 6
-    _assert_parity(net, res, prompt)
 
 
 # ------------------------------------------------------------- paged kernel
@@ -145,7 +62,7 @@ def test_paged_kernel_matches_dense_paged_oracle(S, H, Hk, D, bs, bps,
 
 def test_paged_oracle_equals_gathered_dense_oracle():
     """The paged oracle is DEFINED as gather-then-dense: resolving the
-    block table by hand and calling the slot-path oracle must be
+    block table by hand and calling the contiguous oracle must be
     bit-identical."""
     q, kp, vp, bt, vis, scale, w = _paged_case(3, 4, 2, 16, 16, 4, 5)
     S, bps, bs = 3, 4, 16
@@ -163,6 +80,24 @@ def test_paged_small_block_falls_back_to_dense():
     ref = decode_attention_dense_paged(q, kp, vp, bt, vis, scale, w)
     out = flash_decode_attention_paged(q, kp, vp, bt, vis, scale, w)
     assert np.array_equal(np.asarray(out), np.asarray(ref))
+
+
+def test_kernel_engaged_through_serving_engine():
+    """helpers forced ON routes serving decode through the split-K kernel;
+    the engine's captured logprobs must still sit on the full-recompute
+    fp64 oracle (the end-to-end acceptance gate)."""
+    from deeplearning4j_tpu.ops.helpers import helpers_enabled_ctx
+    from deeplearning4j_tpu.serving import Request, ServingEngine
+    from tests.test_serving import _assert_parity, _build_net
+
+    net = _build_net(n_kv=2)
+    prompt = [1, 2, 3, 4, 5]
+    with helpers_enabled_ctx(True):
+        eng = ServingEngine(net, max_seqs=2, max_len=32, seed=0,
+                            capture_logprobs=True)
+        res = eng.generate([Request(prompt, max_new_tokens=6)])[0]
+    assert len(res.tokens) == 6
+    _assert_parity(net, res, prompt)
 
 
 def test_paged_kernel_engaged_through_serving_engine_with_sharing():

@@ -1,0 +1,168 @@
+"""Every registered Pallas helper must lower — and compile — for the chip.
+
+The parity tests run the kernels with `interpret=True`, which never meets
+the TPU lowering rules: three decode kernels shipped in PR 3/7/11 whose
+block shapes the Pallas->Mosaic lowering refuses, and twenty PRs of CPU
+tests never saw it. Here each helper (and the custom-VJP backwards) is
+traced at the shapes chip_smoke.py runs, with `interpret_mode` forced to
+False and x64 off as on the chip, and
+
+1. cross-lowered on the CPU backend with `lowering_platforms=("tpu",)`; the
+   lowered text must hold a `tpu_custom_call`. This is the check that needs
+   nothing but JAX and catches a block-shape refusal in about a second;
+2. where the installed libtpu will describe a v5e without one being
+   attached (`jax.experimental.topologies`), compiled ahead of time for it —
+   the chip's own compiler, so a scoped-VMEM overflow or an op Mosaic cannot
+   lay out fails here and not on the first chip run.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deeplearning4j_tpu.ops import helpers
+from deeplearning4j_tpu.ops.conv_fused import conv1x1_bn_act
+from deeplearning4j_tpu.ops.decode_attention import (
+    flash_decode_attention_paged, flash_decode_attention_spec_paged)
+from deeplearning4j_tpu.ops.flash_attention import flash_attention
+from deeplearning4j_tpu.ops.lstm_scan_fused import graves_lstm_scan_pallas
+from deeplearning4j_tpu.ops.pallas_kernels import (
+    graves_gates_pallas, lstm_gates_pallas, threshold_encode_pallas)
+
+BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+
+
+@pytest.fixture(autouse=True)
+def as_on_the_chip(monkeypatch):
+    monkeypatch.setattr(helpers, "interpret_mode", lambda: False)
+    with jax.enable_x64(False):
+        yield
+
+
+@pytest.fixture(scope="module")
+def v5e_sharding():
+    """A sharding on one described (not attached) v5e chip, or None where
+    this installation cannot describe one."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    skip_mds = os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:1x1",
+            chips_per_host_bounds=(1, 1, 1), num_slices=1)
+    except (RuntimeError, ValueError, NotImplementedError):
+        return None                     # no libtpu, or it is held elsewhere
+    finally:
+        if skip_mds == "1":
+            os.environ.pop("TPU_SKIP_MDS_QUERY", None)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sum(outs):
+    return sum(jnp.sum(o.astype(F32)) for o in jax.tree.leaves(outs))
+
+
+def _grad(fn, n):
+    """fn's custom-VJP backward: grads of a scalar of its outputs w.r.t.
+    the first n arguments."""
+    return jax.grad(lambda *a: _sum(fn(*a)), argnums=tuple(range(n)))
+
+
+# ---- the smoke's shapes
+# flash attention: B4 H4 T8192 D64 bf16 (bench_attention_longcontext)
+QKV = [((4, 4, 8192, 64), BF16)] * 3
+
+
+def _flash(window=0, bwd=None):
+    return lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                           window=window, bwd=bwd)
+
+
+# fused Graves-LSTM scan: T100 B8192 H256 bf16 (zoo TextGenerationLSTM)
+T, B, H = 100, 8192, 256
+SCAN = [((T, B, 4 * H), BF16), ((H, 4 * H), BF16), ((H,), BF16),
+        ((H,), BF16), ((H,), BF16), ((B, H), BF16), ((B, H), BF16)]
+
+# paged decode: 8 slots, 4 heads / 2 kv heads x 64, 1024 positions in
+# blocks of 16 (bench_decode_serving through ServingEngine)
+S, NH, NKV, D, BS, BPS = 8, 4, 2, 64, 16, 64
+NB = S * BPS + 1
+
+
+def _paged(q_shape, pool_dtype):
+    args = [(q_shape, BF16), ((NB, BS, NKV, D), pool_dtype),
+            ((NB, BS, NKV, D), pool_dtype), ((S, BPS), I32), ((S,), I32)]
+    if pool_dtype == I8:
+        args += [((NB, NKV), F32)] * 2
+    return args
+
+
+def _decode(kernel, window=0):
+    def fn(q, kp, vp, bt, vis, ks=None, vs=None):
+        return kernel(q, kp, vp, bt, vis, 0.125, window, k_scale=ks,
+                      v_scale=vs)
+    return fn
+
+
+GATES = [((B, 4 * H), BF16), ((B, H), BF16)]
+PEEPS = [((H,), BF16)] * 3
+
+CASES = {
+    # default-on
+    "flash_attention": (_flash(), QKV),
+    "flash_attention window=1024": (_flash(1024), QKV),
+    "flash_attention bwd fused": (_grad(_flash(), 3), QKV),
+    "flash_attention bwd fused window=1024": (_grad(_flash(1024), 3), QKV),
+    "flash_attention bwd two_pass": (_grad(_flash(bwd="two_pass"), 3), QKV),
+    "flash_attention bwd two_pass window=1024":
+        (_grad(_flash(1024, "two_pass"), 3), QKV),
+    "graves_lstm_scan": (graves_lstm_scan_pallas, SCAN),
+    "graves_lstm_scan bwd": (_grad(graves_lstm_scan_pallas, 7), SCAN),
+    "decode_attention_paged":
+        (_decode(flash_decode_attention_paged), _paged((S, NH, D), BF16)),
+    "decode_attention_paged window=256":
+        (_decode(flash_decode_attention_paged, 256),
+         _paged((S, NH, D), BF16)),
+    "decode_attention_paged int8":
+        (_decode(flash_decode_attention_paged), _paged((S, NH, D), I8)),
+    "decode_attention_spec_paged Q=4":
+        (_decode(flash_decode_attention_spec_paged),
+         _paged((S, 4, NH, D), BF16)),
+    "decode_attention_spec_paged Q=4 int8 window=256":
+        (_decode(flash_decode_attention_spec_paged, 256),
+         _paged((S, 4, NH, D), I8)),
+    # registered, default-off
+    "lstm_gates": (lstm_gates_pallas, GATES),
+    "lstm_gates bwd": (_grad(lstm_gates_pallas, 2), GATES),
+    "graves_lstm_gates": (graves_gates_pallas, GATES + PEEPS),
+    "graves_lstm_gates bwd": (_grad(graves_gates_pallas, 5), GATES + PEEPS),
+    "threshold_encode":
+        (lambda u, r: threshold_encode_pallas(u, r, 1e-3),
+         [((1 << 20,), F32)] * 2),
+    # ResNet50's widest 1x1: 56x56, 64 -> 256 channels
+    "conv1x1_bn_act":
+        (lambda x, w, g, b, c: conv1x1_bn_act(x, w, g, b, c, 1e-5, True, 1),
+         [((32, 64, 56, 56), BF16), ((256, 64), BF16)]
+         + [((256,), F32)] * 3),
+}
+
+
+def test_every_registered_helper_has_a_case():
+    covered = {name.split()[0] for name in CASES}
+    assert covered == set(helpers.registered_helpers())
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_kernel_lowers_and_compiles_for_tpu(name, v5e_sharding):
+    fn, shapes = CASES[name]
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text, f"{name}: no Mosaic call in the " \
+        "lowered text — the kernel gave way to its reference"
+    if v5e_sharding is None:
+        return
+    on_chip = [jax.ShapeDtypeStruct(s, d, sharding=v5e_sharding)
+               for s, d in shapes]
+    jax.jit(fn).lower(*on_chip).compile()

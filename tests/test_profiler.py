@@ -114,25 +114,56 @@ def test_helper_seam_resolution_counters():
 
 
 # ------------------------------------------------- register / observe
+V5E = "TPU v5 lite"
+
+
 def test_register_publishes_roofline_gauges():
     reg = MetricsRegistry()
-    profiler.configure(enabled=True, platform="cpu")
+    profiler.configure(enabled=True, platform="tpu", device_kind=V5E)
     rec = profiler.register("my_fn", flops=197e9, bytes_accessed=1e6,
                             registry=reg)
     assert rec["flops"] == 197e9
     text = reg.prometheus_text()
     assert "profiler_fn_my_fn_flops 197" in text
-    assert "profiler_fn_my_fn_mxu_floor_ms" in text
-    # cpu has no real peak entry: floor uses the v5e REFERENCE peak and the
-    # exposition flags it
-    assert not profiler.platform_has_peak("cpu")
-    assert math.isclose(profiler.mxu_floor_ms(197e9, "cpu"), 1.0)
-    assert "profiler_platform_has_peak 0" in text
+    assert "profiler_fn_my_fn_mxu_floor_ms 1" in text
+    assert math.isclose(profiler.mxu_floor_ms(197e9, "tpu"), 1.0)
+
+
+def test_off_chip_rows_carry_no_floor_and_no_mfu():
+    """A CPU run reports counts and its own wall time, never a share of a
+    chip's peak (the `decode_chunk_k4 | cpu (ref)` row this replaces
+    published a 2,288,064.9x floor)."""
+    reg = MetricsRegistry()
+    profiler.configure(enabled=True, platform="cpu")
+    profiler.register("c", flops=197e9, bytes_accessed=5.0, registry=reg)
+    profiler.observe("c", 2.0, registry=reg)
+    text = reg.prometheus_text()
+    assert "profiler_fn_c_flops 197" in text
+    assert "profiler_fn_c_measured_ms 2" in text
+    for gauge in ("mxu_floor_ms", "mfu", "x_floor", "roofline_frac"):
+        assert f"profiler_fn_c_{gauge}" not in text
+    assert profiler.mxu_floor_ms(197e9, "cpu") is None
+    row = {r["function"]: r for r in profiler.roofline_table()}["c"]
+    assert row["platform"] == "cpu" and row["measured_ms"] == 2.0
+    assert row["mxu_floor_ms"] is None and row["mfu"] is None \
+        and row["x_floor"] is None
+
+
+def test_peak_table_is_keyed_by_device_kind_and_unknown_kind_raises():
+    peaks = profiler.device_peaks(V5E)
+    assert peaks["bf16_flops"] == 197e12
+    assert peaks["hbm_bytes_per_s"] == 819e9 and peaks["source"]
+    with pytest.raises(KeyError, match="TPU v9000"):
+        profiler.device_peaks("TPU v9000")
+    # a TPU the table does not know gets no floor either: it raises
+    profiler.configure(platform="tpu", device_kind="TPU v9000")
+    with pytest.raises(KeyError):
+        profiler.mxu_floor_ms(1e9)
 
 
 def test_observe_publishes_mfu_and_x_floor():
     reg = MetricsRegistry()
-    profiler.configure(enabled=True, platform="cpu")
+    profiler.configure(enabled=True, platform="tpu", device_kind=V5E)
     profiler.register("g", flops=197e9, registry=reg)   # floor = 1.0 ms
     profiler.observe("g", 4.0, registry=reg)
     text = reg.prometheus_text()
@@ -147,13 +178,13 @@ def test_observe_publishes_mfu_and_x_floor():
 
 
 def test_roofline_table_rows():
-    profiler.configure(enabled=True, platform="cpu")
+    profiler.configure(enabled=True, platform="tpu", device_kind=V5E)
     profiler.register("t", flops=197e9, bytes_accessed=5.0,
                       registry=MetricsRegistry())
     profiler.observe("t", 2.0, registry=MetricsRegistry())
     rows = {r["function"]: r for r in profiler.roofline_table()}
     row = rows["t"]
-    assert row["platform"] == "cpu" and row["reference_peak"] is True
+    assert row["platform"] == "tpu" and row["mxu_floor_ms"] == 1.0
     assert row["calls"] == 1 and row["measured_ms"] == 2.0
     assert row["x_floor"] == 2.0 and row["mfu"] == 0.5
     assert 0 < row["mfu"] < 1
@@ -204,8 +235,8 @@ def test_fit_on_device_registers_train_step_costs():
     net.fit_on_device(x, y, steps=3)        # warm call feeds observe
     assert profiler.observed("train_step")["count"] >= 1
     text = telemetry.registry().prometheus_text()
-    assert "profiler_fn_train_step_mfu" in text
-    assert "profiler_fn_train_step_mxu_floor_ms" in text
+    assert "profiler_fn_train_step_flops" in text
+    assert "profiler_fn_train_step_measured_ms" in text
 
 
 # ------------------------------------------------------ serving path
@@ -223,7 +254,6 @@ def test_serving_publishes_prefill_and_decode_chunk_gauges():
     assert f"profiler_fn_prefill_b{b}_measured_ms" in text
     assert "profiler_fn_decode_chunk_k4_flops" in text
     assert "profiler_fn_decode_chunk_k4_measured_ms" in text
-    assert "profiler_fn_decode_chunk_k4_mfu" in text
     names = {r["function"] for r in profiler.roofline_table()}
     assert any(n.startswith("prefill_b") for n in names)
     assert any(n.startswith("decode_chunk_k") for n in names)
