@@ -15,6 +15,7 @@ from typing import Iterable, Iterator, List, Optional
 
 import numpy as np
 
+from deeplearning4j_tpu import telemetry
 from deeplearning4j_tpu.datasets.dataset import DataSet
 
 
@@ -171,6 +172,9 @@ class AsyncDataSetIterator(DataSetIterator):
     (ref AsyncDataSetIterator.java:30, AsyncPrefetchThread :382-406). Stages device_put
     so host→HBM transfer overlaps the previous step's compute."""
     async_supported = False  # don't double-wrap
+    #: the training iteration the first batch feeds: `fit` sets it, so that the
+    #: producer's spans carry the `step` of the training thread's spans
+    first_step = 0
 
     def __init__(self, underlying, queue_size: int = 4, device_prefetch: bool = True):
         self.underlying = underlying
@@ -183,39 +187,54 @@ class AsyncDataSetIterator(DataSetIterator):
         err: List[BaseException] = []
         stop = threading.Event()
 
-        def _put(item) -> bool:
+        def _put(item, step) -> bool:
             # bounded put that aborts if the consumer went away — otherwise a full
             # queue would park this thread forever holding the underlying iterator
-            while not stop.is_set():
-                try:
-                    q.put(item, timeout=0.1)
-                    return True
-                except queue.Full:
-                    continue
+            try:
+                q.put_nowait(item)
+                return True
+            except queue.Full:
+                pass
+            with telemetry.span("dl4j.async.put_wait", step=step):
+                while not stop.is_set():
+                    try:
+                        q.put(item, timeout=0.1)
+                        return True
+                    except queue.Full:
+                        continue
             return False
 
         def producer():
+            step = self.first_step
             try:
-                for ds in self.underlying:
-                    if stop.is_set():
+                it = iter(self.underlying)
+                while True:
+                    with telemetry.span("dl4j.async.produce", step=step):
+                        ds = next(it, _END)
+                    if ds is _END or stop.is_set():
                         return
                     if self.device_prefetch:
-                        try:
+                        try:    # a MultiDataSet goes on as it is
                             import jax
-                            ds = DataSet(jax.device_put(np.asarray(ds.features)),
-                                         jax.device_put(np.asarray(ds.labels)),
-                                         ds.features_mask if ds.features_mask is None
-                                         else jax.device_put(np.asarray(ds.features_mask)),
-                                         ds.labels_mask if ds.labels_mask is None
-                                         else jax.device_put(np.asarray(ds.labels_mask)))
+                            parts = (ds.features, ds.labels, ds.features_mask,
+                                     ds.labels_mask)
+                            nbytes = sum(int(getattr(a, "nbytes", 0))
+                                         for a in parts)
+                            with telemetry.span("dl4j.async.stage", step=step,
+                                                bytes=nbytes):
+                                ds = DataSet(*(
+                                    a if a is None
+                                    else jax.device_put(np.asarray(a))
+                                    for a in parts))
                         except Exception:
                             pass
-                    if not _put(ds):
+                    if not _put(ds, step):
                         return
+                    step += 1
             except BaseException as e:  # propagate into consumer
                 err.append(e)
             finally:
-                _put(_END)
+                _put(_END, step)
 
         t = threading.Thread(target=producer, daemon=True)
         t.start()
@@ -234,3 +253,17 @@ class AsyncDataSetIterator(DataSetIterator):
 
     def reset(self):
         self.underlying.reset()
+
+
+def waited_batches(it, net):
+    """Yields `it`'s batches, each wait for the next under a
+    `dl4j.fit.next_batch` span that carries the iteration `net` is at: the
+    interval `net.last_etl_ms` times, in `fit`'s loop."""
+    it = iter(it)
+    end = object()
+    while True:
+        with telemetry.span("dl4j.fit.next_batch", step=net._step):
+            ds = next(it, end)
+        if ds is end:
+            return
+        yield ds

@@ -10,6 +10,8 @@ params/opt-state.
 """
 from __future__ import annotations
 
+import functools
+import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -21,8 +23,11 @@ from deeplearning4j_tpu.nn.conf.graph_configuration import ComputationGraphConfi
 from deeplearning4j_tpu.nn.conf.layers.base import BaseLayerConf, apply_dropout
 from deeplearning4j_tpu.nn.divergence import DivergenceSentinelMixin
 from deeplearning4j_tpu.nn.multilayer import (
-    _apply_updates, _compute_updates, _normalize_gradients)
+    _abstract, _apply_updates, _cast_params, _compute_updates,
+    _device_loop_args, _layer_scope, _normalize_gradients,
+    _register_fit_batch_costs, _subtract_updates, _train_step_args)
 from deeplearning4j_tpu.nn.updater.updaters import BaseUpdater
+from deeplearning4j_tpu import telemetry as _telemetry
 from deeplearning4j_tpu.telemetry import health as _health
 from deeplearning4j_tpu.util.flat_params import flatten_params, num_params, unflatten_params
 
@@ -179,7 +184,8 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         mixed = cd != self.dtype
         params_full = params_tree  # storage-dtype originals (score + regularization)
         if mixed:
-            params_tree = cast_floats(params_tree, cd)
+            params_tree = _cast_params(self.layers, self.layer_names,
+                                       params_tree, cd)
         nodes = self.conf.nodes
         fmasks = fmasks or [None] * len(self.conf.inputs)
         values: Dict[str, jnp.ndarray] = dict(zip(self.conf.inputs, inputs))
@@ -215,7 +221,8 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                 i = layer_idx[name]
                 cur = values[node.inputs[0]]
                 if mixed:
-                    cur = cur.astype(cd)
+                    with _layer_scope(node.conf, name):
+                        cur = cur.astype(cd)
                 pending_fused[name] = (cur, i, node.conf)
                 values[name] = None  # guarded by the single-consumer check
                 masks[name] = masks.get(node.inputs[0])
@@ -233,9 +240,11 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                 bias = cp.get("b")
                 if bias is None:
                     bias = jnp.zeros((w.shape[0],), w.dtype)
-                out, m_b, v_b = conv1x1_bn_act(
-                    x0, w, bp["gamma_w"], bp["beta"], bias, bn.eps,
-                    bn.activation == _Act.RELU, conv.stride[0])
+                # one kernel for both: it goes under the convolution's name
+                with _layer_scope(conv, node.inputs[0]):
+                    out, m_b, v_b = conv1x1_bn_act(
+                        x0, w, bp["gamma_w"], bp["beta"], bias, bn.eps,
+                        bn.activation == _Act.RELU, conv.stride[0])
                 d = bn.decay
                 st = state_tree[i]
                 # match BatchNormalization.forward's running update exactly
@@ -249,14 +258,16 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
             in_vals = [values[i] for i in node.inputs]
             in_masks = [masks.get(i) for i in node.inputs]
             if node.kind == "vertex":
-                out, m = node.conf.forward(in_vals, in_masks)
+                with _layer_scope(node.conf, name):
+                    out, m = node.conf.forward(in_vals, in_masks)
                 values[name], masks[name] = out, m
                 continue
             layer = node.conf
             i = layer_idx[name]
             cur, mask = in_vals[0], in_masks[0]
             if mixed and not isinstance(layer, EmbeddingLayer):
-                cur = cur.astype(cd)
+                with _layer_scope(layer, name):
+                    cur = cur.astype(cd)
             if node.preprocessor is not None:
                 cur = node.preprocessor.preprocess(cur)
                 mask = node.preprocessor.feed_forward_mask(mask)
@@ -271,12 +282,16 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                 if lm is None and mask is not None and cur.ndim == 3:
                     lm = mask
                 # output-layer matmul + loss in storage dtype for stability
-                total_loss = total_loss + layer.compute_score(
-                    params_full[i], cur.astype(self.dtype), label_map[name], lm)
+                with jax.named_scope("dl4j.loss"):
+                    total_loss = total_loss + layer.compute_score(
+                        params_full[i], cur.astype(self.dtype),
+                        label_map[name], lm)
                 new_states[i] = state_tree[i]
                 # still produce activation in case downstream nodes consume it
-                out, ns, m = layer.forward(params_tree[i], state_tree[i], cur,
-                                           train=train, rng=lrng, mask=mask)
+                with _layer_scope(layer, name):
+                    out, ns, m = layer.forward(
+                        params_tree[i], state_tree[i], cur, train=train,
+                        rng=lrng, mask=mask)
                 values[name], masks[name] = out, m
             else:
                 from deeplearning4j_tpu.nn.conf.layers.recurrent import (
@@ -286,18 +301,20 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                         and rnn_init_states is not None:
                     # tBPTT segment: scan from the carried state, export final
                     init = rnn_init_states[len(final_rnn)]
-                    out, (h, c) = layer._scan(
-                        params_tree[i], cur, mask,
-                        h0=None if init is None else init[0],
-                        c0=None if init is None else init[1])
+                    with _layer_scope(layer, name):
+                        out, (h, c) = layer._scan(
+                            params_tree[i], cur, mask,
+                            h0=None if init is None else init[0],
+                            c0=None if init is None else init[1])
                     final_rnn.append((h, c))
                     ns, m = state_tree[i], mask
                 else:
                     if isinstance(layer, _LSTM):
                         final_rnn.append(None)
-                    out, ns, m = layer.forward(params_tree[i], state_tree[i],
-                                               cur, train=train, rng=lrng,
-                                               mask=mask)
+                    with _layer_scope(layer, name):
+                        out, ns, m = layer.forward(
+                            params_tree[i], state_tree[i], cur, train=train,
+                            rng=lrng, mask=mask)
                 new_states[i] = ns
                 values[name], masks[name] = out, m
         if mixed:
@@ -355,8 +372,10 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                 fmasks=fmasks, stop_at_scores=True, labels=labels,
                 lmasks=lmasks)
             final_rnn = None
-        reg = sum((self.conf.nodes[n].conf.regularization_score(p)
-                   for n, p in zip(self.layer_names, params_tree)), jnp.asarray(0.0))
+        with jax.named_scope("dl4j.regularization"):
+            reg = sum((self.conf.nodes[n].conf.regularization_score(p)
+                       for n, p in zip(self.layer_names, params_tree)),
+                      jnp.asarray(0.0))
         # aux-loss seam (see MultiLayerNetwork._loss_fn): e.g. MoE load balancing
         aux = sum((jnp.sum(ns["__aux_loss__"]) for ns in new_states
                    if isinstance(ns, dict) and "__aux_loss__" in ns),
@@ -371,8 +390,10 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         health_on = hc is not None and hc.enabled
         protect = health_on and hc.protects
 
-        def train_step(params_tree, opt_state, state_tree, step, rng, x, y,
-                       fmask, lmask, rnn_init_states, health_nf_in):
+        # the function's name is the program's in a profile (`XLA Modules`)
+        def dl4j_cg_train_step(params_tree, opt_state, state_tree, step, rng,
+                               x, y, fmask, lmask, rnn_init_states,
+                               health_nf_in):
             (loss, (new_states, final_rnn)), grads = jax.value_and_grad(
                 self._loss_fn, has_aux=True)(params_tree, state_tree, x, y, fmask,
                                              lmask, rng, True, rnn_init_states)
@@ -383,8 +404,7 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
             # health side-output — see MultiLayerNetwork._build_train_step
             upds, new_opt = _compute_updates(layer_confs, updaters, grads,
                                              opt_state, params_tree, step)
-            new_params = [jax.tree_util.tree_map(lambda p, d: p - d, pt, ut)
-                          for pt, ut in zip(params_tree, upds)]
+            new_params = _subtract_updates(params_tree, upds)
             stats, bad = _health.summarize(params_tree, grads, upds, loss)
             if protect:
                 keep = lambda new, old: jax.tree_util.tree_map(
@@ -395,38 +415,34 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
             stash = _health.step_stash(stats, bad, step, health_nf_in)
             return new_params, new_opt, new_states, loss, final_rnn, stash
 
-        self._train_step_fn = jax.jit(train_step, donate_argnums=(0, 1, 2))
+        self._train_step_fn = jax.jit(dl4j_cg_train_step,
+                                      donate_argnums=(0, 1, 2))
         return self._train_step_fn
 
     def fit_batch(self, x, y, fmask=None, lmask=None, rnn_init_states=None):
         self._check_init()
-        x = tuple(jnp.asarray(v, self.dtype) for v in _as_list(x))
-        y = tuple(jnp.asarray(v, self.dtype) for v in _as_list(y))
-        fmask = None if fmask is None else tuple(_as_list(fmask))
-        lmask = None if lmask is None else tuple(_as_list(lmask))
-        if self._train_step_fn is None:
-            self._build_train_step()
-        self._rng, sub = jax.random.split(self._rng)
+        with _telemetry.span("dl4j.fit_batch", step=self._step):
+            return self._fit_batch(x, y, fmask, lmask, rnn_init_states)
 
+    def _fit_batch(self, x, y, fmask, lmask, rnn_init_states):
+        step = self._step
+        with _telemetry.span("dl4j.fit_batch.prepare", step=step):
+            x = tuple(jnp.asarray(v, self.dtype) for v in _as_list(x))
+            y = tuple(jnp.asarray(v, self.dtype) for v in _as_list(y))
+            fmask = None if fmask is None else tuple(_as_list(fmask))
+            lmask = None if lmask is None else tuple(_as_list(lmask))
+            if self._train_step_fn is None:
+                self._build_train_step()
+            self._rng, sub = jax.random.split(self._rng)
+            if self._accumulator is None:
+                step_args = _train_step_args(self, sub, x, y, fmask, lmask,
+                                             rnn_init_states)
+                _register_fit_batch_costs(self, step_args)
         if self._accumulator is not None:
             return self._fit_batch_accumulated(x, y, fmask, lmask, sub)
-
-        step_args = (self.params_tree, self._opt_state, self.state_tree,
-                     jnp.asarray(self._step, jnp.int32), sub, x, y, fmask,
-                     lmask, rnn_init_states, self._health_nf_in())
-        # profiler cost registry (ISSUE 6): register BEFORE the donated
-        # dispatch (see MultiLayerNetwork.fit_batch)
-        from deeplearning4j_tpu.telemetry import profiler as _profiler
-        if _profiler.enabled() \
-                and not getattr(self, "_profiled_fit_batch", False):
-            self._profiled_fit_batch = True
-            try:
-                _profiler.register("train_step", self._train_step_fn,
-                                   step_args, meta={"loop": "fit_batch"})
-            except Exception:
-                pass
-        new_params, new_opt, new_states, loss, final_rnn, health_stash = \
-            self._train_step_fn(*step_args)
+        with _telemetry.span("dl4j.fit_batch.dispatch", step=step):
+            new_params, new_opt, new_states, loss, final_rnn, health_stash = \
+                self._train_step_fn(*step_args)
         self.params_tree = new_params
         self._opt_state = new_opt
         self.state_tree = new_states
@@ -434,8 +450,9 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         self._score = loss
         if health_stash is not None:
             self._stash_health(health_stash, steps=1)  # raises under policy="raise"
-        for lst in self._listeners:
-            lst.iteration_done(self, self._step)
+        with _telemetry.span("dl4j.fit_batch.listeners", step=step):
+            for lst in self._listeners:
+                lst.iteration_done(self, self._step)
         return final_rnn
 
     def _fit_batch_accumulated(self, x, y, fmask, lmask, sub):
@@ -460,45 +477,46 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         semantics). Benchmark mode only here: the same batch is reused `steps`
         times (rotated per step when vary_batch)."""
         self._check_init()
-        x = tuple(jnp.asarray(v, self.dtype) for v in _as_list(x))
-        y = tuple(jnp.asarray(v, self.dtype) for v in _as_list(y))
         if steps is None:
             raise ValueError("steps is required (single-batch device loop)")
-
-        run = self._get_device_loop(vary_batch)
-
-        self._rng, sub = jax.random.split(self._rng)
-        args = (self.params_tree, self._opt_state, self.state_tree,
-                jnp.asarray(self._step, jnp.int32), sub, x, y, fmask, lmask,
-                self._health_nf_in())
-        # profiler cost registry (ISSUE 6): register BEFORE the dispatch
-        # donates params/opt/state; see MultiLayerNetwork.fit_on_device
-        import time as _time
-        from deeplearning4j_tpu import telemetry as _telemetry
-        from deeplearning4j_tpu.telemetry import profiler as _profiler
-        warm = _profiler.register_train_loop(
-            self, ("cg", vary_batch, self._health_key()), run, args,
-            int(steps))
-        t_run = _time.perf_counter()
-        with _telemetry.span("fit_on_device", steps=int(steps), model="cg"):
-            (self.params_tree, self._opt_state, self.state_tree, _, _, div), \
-                losses, health_out = run(*args, n=int(steps))
-        self._step += int(steps)
-        # sticky device-side stash (see DivergenceSentinelMixin)
-        self._stash_pending_div(div)
-        if health_out is not None:
-            self._stash_health(health_out, steps=int(steps))
-        if not sync:
-            self._score = losses[-1]      # device scalar; host sync deferred
-            return losses                 # divergence resolves on _diverged_at
-        losses, div = jax.device_get((losses, self._pending_div))  # ONE readback
-        if warm:
-            # warm + sync only: compile excluded, readback already paid for
-            _profiler.observe("train_step", (_time.perf_counter() - t_run)
-                              * 1e3 / max(1, int(steps)))
-        self._score = float(losses[-1])
-        self._resolve_divergence(int(div))
-        return losses
+        steps, step = int(steps), self._step
+        with _telemetry.span("dl4j.fit_on_device", step=step, steps=steps,
+                             model="cg"):
+            with _telemetry.span("dl4j.fit_on_device.prepare", step=step):
+                x = tuple(jnp.asarray(v, self.dtype) for v in _as_list(x))
+                y = tuple(jnp.asarray(v, self.dtype) for v in _as_list(y))
+                run = self._get_device_loop(vary_batch)
+                self._rng, sub = jax.random.split(self._rng)
+                args = _device_loop_args(self, sub, x, y, fmask, lmask)
+                # profiler cost registry (ISSUE 6): register BEFORE the
+                # dispatch donates params/opt/state; see
+                # MultiLayerNetwork.fit_on_device
+                from deeplearning4j_tpu.telemetry import profiler as _profiler
+                warm = _profiler.register_train_loop(
+                    self, ("cg", vary_batch, self._health_key()), run, args,
+                    steps)
+            t_run = time.perf_counter()
+            with _telemetry.span("dl4j.fit_on_device.dispatch", step=step):
+                (self.params_tree, self._opt_state, self.state_tree, _, _,
+                 div), losses, health_out = run(*args, n=steps)
+            self._step += steps
+            # sticky device-side stash (see DivergenceSentinelMixin)
+            self._stash_pending_div(div)
+            if health_out is not None:
+                self._stash_health(health_out, steps=steps)
+            if not sync:
+                self._score = losses[-1]  # device scalar; host sync deferred
+                return losses             # divergence resolves on _diverged_at
+            with _telemetry.span("dl4j.fit_on_device.readback", step=step):
+                losses, div = jax.device_get(
+                    (losses, self._pending_div))  # ONE readback
+            if warm:
+                # warm + sync only: compile excluded, readback already paid for
+                _profiler.observe("train_step", (time.perf_counter() - t_run)
+                                  * 1e3 / max(1, steps))
+            self._score = float(losses[-1])
+            self._resolve_divergence(int(div))
+            return losses
 
     def _get_device_loop(self, vary_batch: bool = False):
         """Build (or fetch from cache) the jitted scan loop used by fit_on_device /
@@ -506,8 +524,6 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         captured as traced constants — so a warm cache cannot replay the first
         call's batch. vary_batch: see MultiLayerNetwork.fit_on_device (defeats
         loop-invariant hoisting of frozen-vertex forwards)."""
-        import functools
-
         cache_key = ("cg", vary_batch, self._health_key())
         if not hasattr(self, "_device_loop_cache"):
             self._device_loop_cache = {}
@@ -521,8 +537,8 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
 
             @functools.partial(jax.jit, donate_argnums=(0, 1, 2),
                                static_argnames=("n",))
-            def run(params, opt, states, step, rng, x, y, fmask, lmask,
-                    health_nf_in, n):
+            def dl4j_cg_device_loop(params, opt, states, step, rng, x, y,
+                                    fmask, lmask, health_nf_in, n):
                 def body(carry, _):
                     params_c, opt_c, states_c, step_c, rng_c, div_c, acc = carry
                     rng_c, sub = jax.random.split(rng_c)
@@ -546,8 +562,7 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                         upds, newo = _compute_updates(layer_confs, updaters,
                                                       grads, opt_c, params_c,
                                                       step_c)
-                        newp = [jax.tree_util.tree_map(lambda p, d: p - d, pt, ut)
-                                for pt, ut in zip(params_c, upds)]
+                        newp = _subtract_updates(params_c, upds)
                         stats, badg = _health.summarize(params_c, grads, upds,
                                                         loss)
                         acc = _health.accumulate(acc, stats, badg, step_c)
@@ -562,8 +577,9 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                         bad = jnp.logical_or(~jnp.isfinite(loss), div_c >= 0)
                     keep = lambda new, old: jax.tree_util.tree_map(
                         lambda a, b: jnp.where(bad, b, a), new, old)
-                    newp = keep(newp, params_c)
-                    newo = keep(newo, opt_c)
+                    with jax.named_scope("dl4j.updater"):    # XLA fuses these selects
+                        newp = keep(newp, params_c)         # into the update itself
+                        newo = keep(newo, opt_c)
                     ns = keep(ns, states_c)
                     if not protect:
                         div_c = jnp.where(jnp.logical_and(div_c < 0,
@@ -580,20 +596,31 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                 health_out = _health.finalize(accf, n, health_nf_in) \
                     if health_on else None
                 return (newp, newo, ns, stepf, rngf, divf), losses, health_out
-            self._device_loop_cache[cache_key] = run
+            run = self._device_loop_cache[cache_key] = dl4j_cg_device_loop
         return run
 
-    def lower_train_step(self, x, y):
-        """AOT-lower ONE fit_on_device training step (see
+    def lower_train_step(self, x, y, steps: int = 1, vary_batch: bool = False):
+        """AOT-lower the `fit_on_device` loop of `steps` training steps (one
+        by default) from arrays or `jax.ShapeDtypeStruct`s (see
         MultiLayerNetwork.lower_train_step)."""
         self._check_init()
-        x = tuple(jnp.asarray(v, self.dtype) for v in _as_list(x))
-        y = tuple(jnp.asarray(v, self.dtype) for v in _as_list(y))
-        run = self._get_device_loop()
-        return run.lower(
-            self.params_tree, self._opt_state, self.state_tree,
-            jnp.asarray(self._step, jnp.int32), self._rng, x, y, None, None,
-            self._health_nf_in(), n=1)
+        run = self._get_device_loop(vary_batch)
+        return run.lower(*_device_loop_args(
+            self, self._rng, *self._abstract_batch(x, y), None, None),
+            n=int(steps))
+
+    def lower_fit_batch(self, x, y):
+        """AOT-lower the train step that `fit_batch` — and so
+        `fit(iterator)` — dispatches (see MultiLayerNetwork.lower_fit_batch)."""
+        self._check_init()
+        if self._train_step_fn is None:
+            self._build_train_step()
+        return self._train_step_fn.lower(*_train_step_args(
+            self, self._rng, *self._abstract_batch(x, y), None, None, None))
+
+    def _abstract_batch(self, x, y):
+        return tuple(tuple(_abstract(v, self.dtype) for v in _as_list(a))
+                     for a in (x, y))
 
     def train_step_flops(self, x, y) -> Optional[float]:
         """XLA cost-analysis FLOPs of ONE fit_on_device training step (see
@@ -604,7 +631,6 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
     def fit(self, data, labels=None, epochs: int = 1):
         """fit(x(s), y(s)) | fit(DataSet/MultiDataSet) | fit(iterator[, epochs])
         (ref ComputationGraph.fit :852/:972)."""
-        import time
         from deeplearning4j_tpu.datasets.dataset import DataSet, MultiDataSet
         self._check_init()
         if labels is not None:
@@ -615,7 +641,8 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
             for _ in range(epochs):
                 self._fit_one(data)
             return self
-        from deeplearning4j_tpu.datasets.iterators import AsyncDataSetIterator
+        from deeplearning4j_tpu.datasets.iterators import (
+            AsyncDataSetIterator, waited_batches)
         for _ in range(epochs):
             for lst in self._listeners:
                 if hasattr(lst, "on_epoch_start"):
@@ -625,8 +652,9 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                 it.reset()
             if getattr(it, "async_supported", True):
                 it = AsyncDataSetIterator(it)
+                it.first_step = self._step
             t0 = time.time()
-            for ds in it:
+            for ds in waited_batches(it, self):
                 self._last_etl_ms = (time.time() - t0) * 1e3
                 self._fit_one(ds)
                 t0 = time.time()
@@ -653,20 +681,21 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         def seg_mask(m, s, e):
             return None if m is None else m[:, s:e]
 
-        for start in range(0, T, L):
-            end = min(start + L, T)
-            sx = [seg(v, start, end) for v in xs]
-            sy = [seg(v, start, end) for v in ys]
-            fm = None if fmask is None else [seg_mask(m, start, end)
-                                             for m in _as_list(fmask)]
-            lm = None if lmask is None else [seg_mask(m, start, end)
-                                             for m in _as_list(lmask)]
-            final = self.fit_batch(sx, sy, fm, lm, rnn_init_states=carry)
-            if final is not None:
-                carry = [None if s is None else
-                         (jax.lax.stop_gradient(s[0]),
-                          jax.lax.stop_gradient(s[1]))
-                         for s in final]
+        with _telemetry.span("dl4j.fit_tbptt", step=self._step):
+            for start in range(0, T, L):
+                end = min(start + L, T)
+                sx = [seg(v, start, end) for v in xs]
+                sy = [seg(v, start, end) for v in ys]
+                fm = None if fmask is None else [seg_mask(m, start, end)
+                                                 for m in _as_list(fmask)]
+                lm = None if lmask is None else [seg_mask(m, start, end)
+                                                 for m in _as_list(lmask)]
+                final = self.fit_batch(sx, sy, fm, lm, rnn_init_states=carry)
+                if final is not None:
+                    carry = [None if s is None else
+                             (jax.lax.stop_gradient(s[0]),
+                              jax.lax.stop_gradient(s[1]))
+                             for s in final]
 
     def _fit_one(self, ds):
         from deeplearning4j_tpu.datasets.dataset import MultiDataSet

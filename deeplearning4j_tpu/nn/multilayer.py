@@ -11,6 +11,7 @@ BaseOptimizer machinery (ref optimize/Solver.java:43) collapses into that step f
 from __future__ import annotations
 
 import functools
+import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -23,11 +24,32 @@ from deeplearning4j_tpu.nn.conf.input_type import InputType
 from deeplearning4j_tpu.nn.conf.layers.base import BaseLayerConf, apply_dropout
 from deeplearning4j_tpu.nn.conf.layers.recurrent import LSTM
 from deeplearning4j_tpu.nn.divergence import DivergenceSentinelMixin
+from deeplearning4j_tpu import telemetry as _telemetry
 from deeplearning4j_tpu.telemetry import health as _health
 from deeplearning4j_tpu.nn.conf.preprocessors import (
     FeedForwardToRnnPreProcessor, RnnToFeedForwardPreProcessor)
 from deeplearning4j_tpu.nn.updater.updaters import BaseUpdater, Sgd
 from deeplearning4j_tpu.util.flat_params import flatten_params, num_params, unflatten_params
+
+
+_telemetry.count_compiles()   # dl4j.compile.* counters, from here on
+
+
+def _layer_scope(layer, name) -> "jax.named_scope":
+    """The name a layer's or vertex's operations carry in the compiled
+    program: `dl4j.<its class>/<its name>` (telemetry.profiler.op_scopes)."""
+    return jax.named_scope(f"dl4j.{type(layer).__name__}/{name}")
+
+
+def _cast_params(layers, names, params_tree, dtype):
+    """The layers' parameters in the compute type, each layer's casts under
+    its own name."""
+    from deeplearning4j_tpu.util.dtypes import cast_floats
+    out = []
+    for layer, name, p in zip(layers, names, params_tree):
+        with _layer_scope(layer, name):
+            out.append(cast_floats(p, dtype))
+    return out
 
 
 def _normalize_gradients(layer: BaseLayerConf, grads: Dict[str, jnp.ndarray]):
@@ -62,21 +84,62 @@ def _compute_updates(layers, updaters, grads, opt_state, params_tree, step):
     Returns (updates, new_opt_state) — the single shared implementation of the
     reference's Solver/updater step, used by every training path."""
     upds, new_opt = [], []
-    for i, (layer, u) in enumerate(zip(layers, updaters)):
-        g = _normalize_gradients(layer, grads[i])
-        upd, st = u.update(g, opt_state[i], params_tree[i], step)
-        upds.append(upd)
-        new_opt.append(st)
+    with jax.named_scope("dl4j.updater"):
+        for i, (layer, u) in enumerate(zip(layers, updaters)):
+            g = _normalize_gradients(layer, grads[i])
+            upd, st = u.update(g, opt_state[i], params_tree[i], step)
+            upds.append(upd)
+            new_opt.append(st)
     return upds, new_opt
+
+
+def _subtract_updates(params_tree, upds):
+    with jax.named_scope("dl4j.updater"):
+        return [jax.tree_util.tree_map(lambda p, d: p - d, pt, ut)
+                for pt, ut in zip(params_tree, upds)]
 
 
 def _apply_updates(layers, updaters, grads, opt_state, params_tree, step):
     """params' = params - updater(grads) for every layer."""
     upds, new_opt = _compute_updates(layers, updaters, grads, opt_state,
                                      params_tree, step)
-    new_params = [jax.tree_util.tree_map(lambda p, d: p - d, pt, ut)
-                  for pt, ut in zip(params_tree, upds)]
-    return new_params, new_opt
+    return _subtract_updates(params_tree, upds), new_opt
+
+
+def _abstract(a, dtype) -> jax.ShapeDtypeStruct:
+    """The shape a batch array has once `fit_batch`/`fit_on_device` has
+    converted it (`jnp.asarray(a, dtype)`), without the array."""
+    return jax.ShapeDtypeStruct(a.shape if hasattr(a, "shape")
+                                else np.shape(a), dtype)
+
+
+def _device_loop_args(net, rng, x, y, fmask, lmask):
+    """What a net's device loop is called with, all but the static `n`
+    (`fit_on_device`, `lower_train_step`)."""
+    return (net.params_tree, net._opt_state, net.state_tree,
+            jnp.asarray(net._step, jnp.int32), rng, x, y, fmask, lmask,
+            net._health_nf_in())
+
+
+def _train_step_args(net, rng, x, y, fmask, lmask, rnn_init_states):
+    """What a net's `_train_step_fn` is called with (`fit_batch`,
+    `lower_fit_batch`): the loop's, and the carried RNN states."""
+    args = _device_loop_args(net, rng, x, y, fmask, lmask)
+    return args[:-1] + (rnn_init_states, args[-1])
+
+
+def _register_fit_batch_costs(net, step_args):
+    """Profiler cost registry (ISSUE 6): file train_step costs once, BEFORE
+    the dispatch donates params/opt/state (AOT — no exec);
+    telemetry.training.mark_iteration feeds the measured ms side."""
+    from deeplearning4j_tpu.telemetry import profiler as _profiler
+    if _profiler.enabled() and not getattr(net, "_profiled_fit_batch", False):
+        net._profiled_fit_batch = True
+        try:
+            _profiler.register("train_step", net._train_step_fn, step_args,
+                               meta={"loop": "fit_batch"})
+        except Exception:
+            pass
 
 
 class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
@@ -165,7 +228,8 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         cd = self.compute_dtype
         mixed = cd != self.dtype
         if mixed:
-            params_tree = cast_floats(params_tree, cd)
+            params_tree = _cast_params(self.layers, range(len(self.layers)),
+                                       params_tree, cd)
             if rnn_init_states is not None:
                 rnn_init_states = cast_floats(rnn_init_states, cd)
         orig_batch = x.shape[0]
@@ -176,7 +240,8 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         cur = x
         for i, layer in enumerate(self.layers):
             if mixed and not isinstance(layer, EmbeddingLayer):
-                cur = cur.astype(cd)
+                with _layer_scope(layer, i):
+                    cur = cur.astype(cd)
             if i in self.conf.preprocessors:
                 pp = self.conf.preprocessors[i]
                 if isinstance(pp, FeedForwardToRnnPreProcessor):
@@ -192,16 +257,20 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                 rng, lrng = jax.random.split(rng)
             if isinstance(layer, LSTM) and rnn_init_states is not None:
                 init = rnn_init_states[len(final_rnn)]
-                out, (h, c) = layer._scan(params_tree[i], cur, mask,
-                                          h0=None if init is None else init[0],
-                                          c0=None if init is None else init[1])
+                with _layer_scope(layer, i):
+                    out, (h, c) = layer._scan(
+                        params_tree[i], cur, mask,
+                        h0=None if init is None else init[0],
+                        c0=None if init is None else init[1])
                 final_rnn.append((h, c))
                 cur, ns, mask = out, state_tree[i], mask
             else:
                 if isinstance(layer, LSTM):
                     final_rnn.append(None)
-                cur, ns, mask = layer.forward(params_tree[i], state_tree[i], cur,
-                                              train=train, rng=lrng, mask=mask)
+                with _layer_scope(layer, i):
+                    cur, ns, mask = layer.forward(
+                        params_tree[i], state_tree[i], cur, train=train,
+                        rng=lrng, mask=mask)
             new_states.append(ns)
             if collect:
                 acts.append(cur)
@@ -248,7 +317,8 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         mixed = cd != self.dtype
         params_full = params_tree  # storage-dtype originals (score + regularization)
         if mixed:
-            params_tree = cast_floats(params_tree, cd)
+            params_tree = _cast_params(self.layers, range(len(self.layers)),
+                                       params_tree, cd)
             if rnn_init_states is not None:
                 rnn_init_states = cast_floats(rnn_init_states, cd)
         # forward to input of the output layer
@@ -259,7 +329,8 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         final_rnn = []
         for i, layer in enumerate(self.layers[:-1]):
             if mixed and not isinstance(layer, EmbeddingLayer):
-                cur = cur.astype(cd)
+                with _layer_scope(layer, i):
+                    cur = cur.astype(cd)
             if i in self.conf.preprocessors:
                 pp = self.conf.preprocessors[i]
                 if isinstance(pp, FeedForwardToRnnPreProcessor):
@@ -278,9 +349,11 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
             if isinstance(layer, LSTM) and not isinstance(layer, _BiLSTM) \
                     and rnn_init_states is not None:
                 init = rnn_init_states[len(final_rnn)]
-                cur, (h, c) = layer._scan(params_tree[i], cur, mask,
-                                          h0=None if init is None else init[0],
-                                          c0=None if init is None else init[1])
+                with _layer_scope(layer, i):
+                    cur, (h, c) = layer._scan(
+                        params_tree[i], cur, mask,
+                        h0=None if init is None else init[0],
+                        c0=None if init is None else init[1])
                 final_rnn.append((h, c))
                 new_states.append(state_tree[i])
             else:
@@ -298,8 +371,9 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                     # gradient checkpointing: drop this layer's activations and
                     # recompute them in the backward pass (HBM for FLOPs)
                     fwd = jax.checkpoint(fwd)
-                cur, ns, mask = fwd(params_tree[i], state_tree[i], cur, lrng,
-                                    mask)
+                with _layer_scope(layer, i):
+                    cur, ns, mask = fwd(params_tree[i], state_tree[i], cur,
+                                        lrng, mask)
                 new_states.append(ns)
         li = len(self.layers) - 1
         if li in self.conf.preprocessors:
@@ -326,15 +400,19 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                     f"{type(out_layer).__name__} has no per-example scoring")
             loss = fn(params_full[-1], cur, y, score_mask)
         else:
-            loss = out_layer.compute_score(params_full[-1], cur, y, score_mask)
+            with jax.named_scope("dl4j.loss"):
+                loss = out_layer.compute_score(params_full[-1], cur, y,
+                                               score_mask)
         new_states.append(state_tree[-1])
         if per_example:
             # bare per-example data losses; callers add reg/aux themselves
             # (ref scoreExamples addRegularization semantics) — returning
             # before the reg/aux sums keeps the eager path free of dead work
             return loss, (new_states, final_rnn)
-        reg = sum((layer.regularization_score(p)
-                   for layer, p in zip(self.layers, params_full)), jnp.asarray(0.0))
+        with jax.named_scope("dl4j.regularization"):
+            reg = sum((layer.regularization_score(p)
+                       for layer, p in zip(self.layers, params_full)),
+                      jnp.asarray(0.0))
         # auxiliary-loss seam: layers that contribute a data-dependent loss
         # term (MixtureOfExperts load balancing) publish it in their new state
         # under "__aux_loss__"
@@ -351,8 +429,10 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         health_on = hc is not None and hc.enabled
         protect = health_on and hc.protects
 
-        def train_step(params_tree, opt_state, state_tree, step, rng, x, y, fmask, lmask,
-                       rnn_init_states, health_nf_in):
+        # the function's name is the program's in a profile (`XLA Modules`)
+        def dl4j_mln_train_step(params_tree, opt_state, state_tree, step, rng,
+                                x, y, fmask, lmask, rnn_init_states,
+                                health_nf_in):
             (loss, (new_states, final_rnn)), grads = jax.value_and_grad(
                 self._loss_fn, has_aux=True)(params_tree, state_tree, x, y, fmask,
                                              lmask, rng, True, rnn_init_states)
@@ -365,8 +445,7 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
             # observation under policy="record" (bit-parity tested)
             upds, new_opt = _compute_updates(layers, updaters, grads, opt_state,
                                              params_tree, step)
-            new_params = [jax.tree_util.tree_map(lambda p, d: p - d, pt, ut)
-                          for pt, ut in zip(params_tree, upds)]
+            new_params = _subtract_updates(params_tree, upds)
             stats, bad = _health.summarize(params_tree, grads, upds, loss)
             if protect:
                 # skip/raise policy: a nonfinite step leaves every training
@@ -380,42 +459,37 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
             return new_params, new_opt, new_states, loss, final_rnn, stash
 
         # donate params/opt-state/bn-state buffers: in-place update on device
-        self._train_step_fn = jax.jit(train_step, donate_argnums=(0, 1, 2),
+        self._train_step_fn = jax.jit(dl4j_mln_train_step,
+                                      donate_argnums=(0, 1, 2),
                                       static_argnames=())
         return self._train_step_fn
 
     def fit_batch(self, x, y, fmask=None, lmask=None, rnn_init_states=None):
         """One optimization step on one minibatch — the 3.1 call-stack equivalent."""
         self._check_init()
-        x = jnp.asarray(x, self.dtype)
-        y = jnp.asarray(y, self.dtype)
-        if self._train_step_fn is None:
-            self._build_train_step()
-        self._rng, sub = jax.random.split(self._rng)
-        n_rnn = sum(1 for l in self.layers if isinstance(l, LSTM))
-        if rnn_init_states is None:
-            rnn_init_states = [None] * n_rnn
+        with _telemetry.span("dl4j.fit_batch", step=self._step):
+            return self._fit_batch(x, y, fmask, lmask, rnn_init_states)
 
+    def _fit_batch(self, x, y, fmask, lmask, rnn_init_states):
+        step = self._step
+        with _telemetry.span("dl4j.fit_batch.prepare", step=step):
+            x = jnp.asarray(x, self.dtype)
+            y = jnp.asarray(y, self.dtype)
+            if self._train_step_fn is None:
+                self._build_train_step()
+            self._rng, sub = jax.random.split(self._rng)
+            n_rnn = sum(1 for l in self.layers if isinstance(l, LSTM))
+            if rnn_init_states is None:
+                rnn_init_states = [None] * n_rnn
+            if self._accumulator is None:
+                step_args = _train_step_args(
+                    self, sub, x, y, fmask, lmask, rnn_init_states)
+                _register_fit_batch_costs(self, step_args)
         if self._accumulator is not None:
             return self._fit_batch_accumulated(x, y, fmask, lmask, rnn_init_states)
-
-        step_args = (self.params_tree, self._opt_state, self.state_tree,
-                     jnp.asarray(self._step, jnp.int32), sub, x, y, fmask,
-                     lmask, rnn_init_states, self._health_nf_in())
-        # profiler cost registry (ISSUE 6): file train_step costs once,
-        # BEFORE the dispatch donates params/opt/state (AOT — no exec);
-        # telemetry.training.mark_iteration feeds the measured ms side
-        from deeplearning4j_tpu.telemetry import profiler as _profiler
-        if _profiler.enabled() \
-                and not getattr(self, "_profiled_fit_batch", False):
-            self._profiled_fit_batch = True
-            try:
-                _profiler.register("train_step", self._train_step_fn,
-                                   step_args, meta={"loop": "fit_batch"})
-            except Exception:
-                pass
-        new_params, new_opt, new_states, loss, final_rnn, health_stash = \
-            self._train_step_fn(*step_args)
+        with _telemetry.span("dl4j.fit_batch.dispatch", step=step):
+            new_params, new_opt, new_states, loss, final_rnn, health_stash = \
+                self._train_step_fn(*step_args)
         self.params_tree = new_params
         self._opt_state = new_opt
         self.state_tree = new_states
@@ -423,9 +497,11 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         self._score = loss  # device scalar; host sync deferred to score()
         if health_stash is not None:
             self._stash_health(health_stash, steps=1)  # raises under policy="raise"
-        for lst in self._listeners:
-            lst.iteration_done(self, self._step)
+        with _telemetry.span("dl4j.fit_batch.listeners", step=step):
+            for lst in self._listeners:
+                lst.iteration_done(self, self._step)
         return final_rnn
+
 
     def _fit_batch_accumulated(self, x, y, fmask, lmask, rnn_init_states=None):
         """Gradient-sharing path (ref StochasticGradientDescent.java:66-74): compute grads,
@@ -472,61 +548,63 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         269 TFLOPS on a 197 TFLOPS chip). Rolling by the traced step index makes
         every step's input distinct, like a real data pipeline."""
         self._check_init()
-        x = jnp.asarray(x, self.dtype)
-        y = jnp.asarray(y, self.dtype)
         per_step_data = steps is None
-        if per_step_data:
-            steps = x.shape[0]
-        has_fm = fmask is not None
-        has_lm = lmask is not None
+        steps, step = int(np.shape(x)[0] if per_step_data else steps), self._step
+        with _telemetry.span("dl4j.fit_on_device", step=step, steps=steps,
+                             model="mln"):
+            with _telemetry.span("dl4j.fit_on_device.prepare", step=step):
+                x = jnp.asarray(x, self.dtype)
+                y = jnp.asarray(y, self.dtype)
+                has_fm = fmask is not None
+                has_lm = lmask is not None
 
-        # Cache keyed on the static loop mode only; ALL data (x/y/masks) is passed as
-        # jit arguments so the traced computation never captures a batch as a constant
-        # (a warm cache must not replay the first call's data). jax.jit's own aval
-        # cache handles shape/dtype/None changes. In per-step mode masks (when given)
-        # carry a leading step axis and are scanned alongside x/y.
-        if vary_batch and per_step_data:
-            raise ValueError("vary_batch applies to the same-batch benchmark "
-                             "mode only (steps=int)")
-        run = self._get_device_loop(per_step_data, has_fm, has_lm, vary_batch)
-
-        self._rng, sub = jax.random.split(self._rng)
-        args = (self.params_tree, self._opt_state, self.state_tree,
-                jnp.asarray(self._step, jnp.int32), sub, x, y, fmask, lmask,
-                self._health_nf_in())
-        # profiler cost registry (ISSUE 6): file per-step train_step costs
-        # BEFORE the dispatch below donates params/opt/state; `warm` gates
-        # the wall-time observation so compile time never pollutes it
-        import time as _time
-        from deeplearning4j_tpu import telemetry as _telemetry
-        from deeplearning4j_tpu.telemetry import profiler as _profiler
-        warm = _profiler.register_train_loop(
-            self, ("mln", per_step_data, has_fm, has_lm, vary_batch,
-                   self._health_key()), run, args, int(steps))
-        t_run = _time.perf_counter()
-        with _telemetry.span("fit_on_device", steps=int(steps), model="mln"):
-            (self.params_tree, self._opt_state, self.state_tree, _, _, div), \
-                losses, health_out = run(*args, n=int(steps))
-        self._step += int(steps)
-        # sticky device-side stash: a clean later call must not clobber an
-        # unobserved divergence from an earlier deferred call
-        self._stash_pending_div(div)
-        if health_out is not None:
-            # ONE device-side aggregate per fit_on_device call; materializes
-            # lazily via health_report() (raises now under policy="raise")
-            self._stash_health(health_out, steps=int(steps))
-        if not sync:
-            self._score = losses[-1]      # device scalar; host sync deferred
-            return losses                 # divergence resolves on _diverged_at
-        losses, div = jax.device_get((losses, self._pending_div))  # ONE readback
-        if warm:
-            # warm + sync: the wall spans the whole device loop plus its one
-            # readback — a host value the sync path already paid for
-            _profiler.observe("train_step", (_time.perf_counter() - t_run)
-                              * 1e3 / max(1, int(steps)))
-        self._score = float(losses[-1])
-        self._resolve_divergence(int(div))
-        return losses
+                # Cache keyed on the static loop mode only; ALL data (x/y/masks)
+                # is passed as jit arguments so the traced computation never
+                # captures a batch as a constant (a warm cache must not replay
+                # the first call's data). jax.jit's own aval cache handles
+                # shape/dtype/None changes. In per-step mode masks (when given)
+                # carry a leading step axis and are scanned alongside x/y.
+                if vary_batch and per_step_data:
+                    raise ValueError("vary_batch applies to the same-batch "
+                                     "benchmark mode only (steps=int)")
+                run = self._get_device_loop(per_step_data, has_fm, has_lm,
+                                            vary_batch)
+                self._rng, sub = jax.random.split(self._rng)
+                args = _device_loop_args(self, sub, x, y, fmask, lmask)
+                # profiler cost registry (ISSUE 6): file per-step train_step
+                # costs BEFORE the dispatch below donates params/opt/state;
+                # `warm` gates the wall-time observation so compile time never
+                # pollutes it
+                from deeplearning4j_tpu.telemetry import profiler as _profiler
+                warm = _profiler.register_train_loop(
+                    self, ("mln", per_step_data, has_fm, has_lm, vary_batch,
+                           self._health_key()), run, args, steps)
+            t_run = time.perf_counter()
+            with _telemetry.span("dl4j.fit_on_device.dispatch", step=step):
+                (self.params_tree, self._opt_state, self.state_tree, _, _,
+                 div), losses, health_out = run(*args, n=steps)
+            self._step += steps
+            # sticky device-side stash: a clean later call must not clobber an
+            # unobserved divergence from an earlier deferred call
+            self._stash_pending_div(div)
+            if health_out is not None:
+                # ONE device-side aggregate per fit_on_device call; materializes
+                # lazily via health_report() (raises now under policy="raise")
+                self._stash_health(health_out, steps=steps)
+            if not sync:
+                self._score = losses[-1]  # device scalar; host sync deferred
+                return losses             # divergence resolves on _diverged_at
+            with _telemetry.span("dl4j.fit_on_device.readback", step=step):
+                losses, div = jax.device_get(
+                    (losses, self._pending_div))  # ONE readback
+            if warm:
+                # warm + sync: the wall spans the whole device loop plus its
+                # one readback — a host value the sync path already paid for
+                _profiler.observe("train_step", (time.perf_counter() - t_run)
+                                  * 1e3 / max(1, steps))
+            self._score = float(losses[-1])
+            self._resolve_divergence(int(div))
+            return losses
 
     def _get_device_loop(self, per_step_data: bool, has_fm: bool, has_lm: bool,
                          vary_batch: bool = False):
@@ -546,8 +624,8 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
 
             @functools.partial(jax.jit, donate_argnums=(0, 1, 2),
                                static_argnames=("n",))
-            def run(params, opt, states, step, rng, x, y, fmask, lmask,
-                    health_nf_in, n):
+            def dl4j_mln_device_loop(params, opt, states, step, rng, x, y,
+                                     fmask, lmask, health_nf_in, n):
                 def body(carry, xs):
                     params_c, opt_c, states_c, step_c, rng_c, div_c, acc = carry
                     if per_step_data:
@@ -577,8 +655,7 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                         # same update math, split to expose the updates
                         upds, newo = _compute_updates(layers, updaters, grads,
                                                       opt_c, params_c, step_c)
-                        newp = [jax.tree_util.tree_map(lambda p, d: p - d, pt, ut)
-                                for pt, ut in zip(params_c, upds)]
+                        newp = _subtract_updates(params_c, upds)
                         stats, badg = _health.summarize(params_c, grads, upds,
                                                         loss)
                         acc = _health.accumulate(acc, stats, badg, step_c)
@@ -599,8 +676,9 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                         bad = jnp.logical_or(~jnp.isfinite(loss), div_c >= 0)
                     keep = lambda new, old: jax.tree_util.tree_map(
                         lambda a, b: jnp.where(bad, b, a), new, old)
-                    newp = keep(newp, params_c)
-                    newo = keep(newo, opt_c)
+                    with jax.named_scope("dl4j.updater"):    # XLA fuses these selects
+                        newp = keep(newp, params_c)         # into the update itself
+                        newo = keep(newo, opt_c)
                     ns = keep(ns, states_c)
                     if not protect:
                         div_c = jnp.where(jnp.logical_and(div_c < 0,
@@ -622,23 +700,36 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                 health_out = _health.finalize(accf, n, health_nf_in) \
                     if health_on else None
                 return (newp, newo, ns, stepf, rngf, divf), losses, health_out
-            self._device_loop_cache[cache_key] = run
+            run = self._device_loop_cache[cache_key] = dl4j_mln_device_loop
         return run
 
-    def lower_train_step(self, x, y):
-        """AOT-lower ONE fit_on_device training step (forward + backward +
-        updater) at this batch's shapes: nothing executes and no buffer is
-        donated. `.as_text()` shows what the step lowers to — an engaged
+    def lower_train_step(self, x, y, steps: int = 1, vary_batch: bool = False):
+        """AOT-lower the `fit_on_device` loop of `steps` training steps
+        (forward + backward + updater; one by default) at this batch's
+        shapes: nothing executes and no buffer is donated. `x`/`y` may be
+        `jax.ShapeDtypeStruct`s — `fit_on_device(x, y, steps=steps,
+        vary_batch=vary_batch)` of arrays of those shapes runs exactly this
+        program. `.as_text()` shows what the step lowers to — an engaged
         helper kernel appears as a `tpu_custom_call` — and the compiled form
-        carries XLA's cost analysis."""
+        carries XLA's cost analysis and, for every operation, the `dl4j.`
+        scope it came from (telemetry.profiler.op_scopes)."""
         self._check_init()
-        x = jnp.asarray(x, self.dtype)
-        y = jnp.asarray(y, self.dtype)
-        run = self._get_device_loop(False, False, False)
-        return run.lower(
-            self.params_tree, self._opt_state, self.state_tree,
-            jnp.asarray(self._step, jnp.int32), self._rng, x, y, None, None,
-            self._health_nf_in(), n=1)
+        run = self._get_device_loop(False, False, False, vary_batch)
+        return run.lower(*_device_loop_args(
+            self, self._rng, _abstract(x, self.dtype),
+            _abstract(y, self.dtype), None, None), n=int(steps))
+
+    def lower_fit_batch(self, x, y):
+        """AOT-lower the train step that `fit_batch` — and so
+        `fit(iterator)` — dispatches, at this batch's shapes (arrays or
+        `jax.ShapeDtypeStruct`s); see `lower_train_step`."""
+        self._check_init()
+        if self._train_step_fn is None:
+            self._build_train_step()
+        n_rnn = sum(1 for l in self.layers if isinstance(l, LSTM))
+        return self._train_step_fn.lower(*_train_step_args(
+            self, self._rng, _abstract(x, self.dtype),
+            _abstract(y, self.dtype), None, None, [None] * n_rnn))
 
     def train_step_flops(self, x, y) -> Optional[float]:
         """XLA cost-analysis FLOPs of ONE fit_on_device training step
@@ -669,7 +760,6 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
     def fit(self, data, labels=None, epochs: int = 1):
         """fit(x, y) | fit(DataSet) | fit(DataSetIterator[, epochs])
         (ref MultiLayerNetwork.fit :1149)."""
-        import time
         from deeplearning4j_tpu.datasets.dataset import DataSet
         self._check_init()
         if labels is not None:
@@ -681,7 +771,8 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                 self._fit_one(data)
             return self
         # iterator path with async prefetch (ref AsyncDataSetIterator wrap :1153-1156)
-        from deeplearning4j_tpu.datasets.iterators import AsyncDataSetIterator
+        from deeplearning4j_tpu.datasets.iterators import (
+            AsyncDataSetIterator, waited_batches)
         for ep in range(epochs):
             for lst in self._listeners:
                 if hasattr(lst, "on_epoch_start"):
@@ -691,6 +782,8 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                 it.reset()
             if getattr(it, "async_supported", True):
                 it = AsyncDataSetIterator(it)
+                it.first_step = self._step
+            it = waited_batches(it, self)
             if self.conf.backprop_type == BackpropType.TruncatedBPTT:
                 # segment loop needs host-side carry; per-batch path
                 t0 = time.time()
@@ -710,7 +803,6 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         lax.scan (fit_on_device per-step mode) — the epoch runner that keeps
         fit(iterator) off the one-host-roundtrip-per-minibatch slow path. Listener
         callbacks fire after each device run with the recorded per-step scores."""
-        import time
         t0 = time.time()
         group: List[Any] = []
         # Cap the stacked super-step so a long epoch never materializes unbounded
@@ -775,17 +867,22 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         L = self.conf.tbptt_fwd_length
         n_rnn = sum(1 for l in self.layers if isinstance(l, LSTM))
         carry = [None] * n_rnn
-        for start in range(0, T, L):
-            end = min(start + L, T)
-            x = ds.features[:, :, start:end]
-            y = ds.labels[:, :, start:end] if ds.labels.ndim == 3 else ds.labels
-            fm = None if ds.features_mask is None else ds.features_mask[:, start:end]
-            lm = None if ds.labels_mask is None else ds.labels_mask[:, start:end]
-            final = self.fit_batch(x, y, fm, lm, rnn_init_states=carry)
-            if final is not None:
-                carry = [None if s is None else
-                         (jax.lax.stop_gradient(s[0]), jax.lax.stop_gradient(s[1]))
-                         for s in final]
+        with _telemetry.span("dl4j.fit_tbptt", step=self._step):
+            for start in range(0, T, L):
+                end = min(start + L, T)
+                x = ds.features[:, :, start:end]
+                y = ds.labels[:, :, start:end] if ds.labels.ndim == 3 \
+                    else ds.labels
+                fm = None if ds.features_mask is None \
+                    else ds.features_mask[:, start:end]
+                lm = None if ds.labels_mask is None \
+                    else ds.labels_mask[:, start:end]
+                final = self.fit_batch(x, y, fm, lm, rnn_init_states=carry)
+                if final is not None:
+                    carry = [None if s is None else
+                             (jax.lax.stop_gradient(s[0]),
+                              jax.lax.stop_gradient(s[1]))
+                             for s in final]
 
     # ------------------------------------------------------------- scoring
     def score(self, ds=None, training: bool = False) -> float:

@@ -410,6 +410,7 @@ def _scan_fwd_impl(xw, rw, pi, pf, po, h0, c0):
         pmap_ = lambda b, t: (0, b, 0)
     ys, cs = pl.pallas_call(
         _make_fwd_kernel(tm, K),
+        name="dl4j_lstm_scan_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((K, bt, 4 * H), xmap),
@@ -491,6 +492,7 @@ def _scan_bwd(saved, cots):
     dcs = _pad_batch(dcs, Bp)
     dxw, drw, dpi, dpf, dpo, dh0, dc0 = pl.pallas_call(
         _make_bwd_kernel(tm, K, direct_prev=direct),
+        name="dl4j_lstm_scan_bwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((K, bt, 4 * H), rev),

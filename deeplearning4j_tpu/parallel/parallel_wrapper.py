@@ -40,6 +40,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from deeplearning4j_tpu import telemetry
 from deeplearning4j_tpu.nn.multilayer import (
     _apply_updates, _compute_updates, _normalize_gradients)
 from deeplearning4j_tpu.parallel.accumulation import threshold_encode
@@ -362,6 +363,12 @@ class ParallelWrapper:
         MultiLayerNetwork.fit_on_device). This is the TPU-idiomatic measurement path:
         per-step host dispatch would measure the dispatch, not the mesh. Not available for CUSTOM mode (its
         accumulator is host-side by contract). Returns per-step mean losses."""
+        step = self.model._step     # the wrapper's own mirror comes with set-up
+        with telemetry.span("dl4j.pw.fit_on_device", step=step,
+                            steps=int(steps)):
+            return self._fit_on_device(x, y, steps, sync, step)
+
+    def _fit_on_device(self, x, y, steps, sync, step):
         if self.training_mode == TrainingMode.CUSTOM:
             raise ValueError(
                 "fit_on_device is unsupported in CUSTOM mode: the caller-provided "
@@ -383,7 +390,7 @@ class ParallelWrapper:
             @functools.partial(jax.jit, donate_argnums=(0,),
                                static_argnames=("n",),
                                out_shardings=(carry_sh, loss_sh))
-            def scan_run(carry, rng, bx, by, n):
+            def dl4j_pw_device_loop(carry, rng, bx, by, n):
                 def body(c, _):
                     carry_c, rng_c = c
                     rng_c, sub = jax.random.split(rng_c)
@@ -393,22 +400,25 @@ class ParallelWrapper:
                 (carry, _), losses = lax.scan(body, (carry, rng), None, length=n)
                 return carry, losses
 
-            self._scan_fn = scan_run
+            self._scan_fn = dl4j_pw_device_loop
         net._rng, sub = jax.random.split(net._rng)
-        self._carry, losses = self._scan_fn(self._carry, sub, x, y, n=int(steps))
+        with telemetry.span("dl4j.pw.fit_on_device.dispatch", step=step):
+            self._carry, losses = self._scan_fn(self._carry, sub, x, y,
+                                                n=int(steps))
         self._host_step += int(steps)
         if not sync:
             # deferred readback (see MultiLayerNetwork.fit_on_device): the
             # returned device array is the completion handle — timed callers
             # block_until_ready on it rather than paying a host copy per call
             self._score = losses[-1]
+        else:
+            # host transfer doubles as the synchronization point: callers must
+            # observe completed work, not queued dispatches
+            with telemetry.span("dl4j.pw.fit_on_device.readback", step=step):
+                losses = np.asarray(losses)
+            self._score = float(losses[-1])
+        with telemetry.span("dl4j.pw.fit_on_device.write_back", step=step):
             self._write_back()
-            return losses
-        # host transfer doubles as the synchronization point: callers must
-        # observe completed work, not queued dispatches
-        losses = np.asarray(losses)
-        self._score = float(losses[-1])
-        self._write_back()
         return losses
 
     def _average_partial_window(self):

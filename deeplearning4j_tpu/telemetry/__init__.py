@@ -10,6 +10,8 @@ Three pieces:
 - `Tracer` (tracing.py): context-manager spans -> Chrome-trace/Perfetto
   JSON. `span("name", **args)` on the module records into the global
   tracer; `maybe_export_trace()` writes it to `$DL4J_TPU_TRACE_PATH`.
+- `count_compiles()`: JAX's monitoring events as `dl4j.compile.*` counters
+  of the default registry (called where the nets' modules are imported).
 - Prometheus text exposition: `registry().prometheus_text()`, served by
   ui/server.py at GET /metrics, or mount `metrics_route()` on any
   util/http.JsonHttpServer.
@@ -24,12 +26,11 @@ Env toggles:
   training-health policy for models that did not call `configure_health`
   (health.py, ISSUE 5). Unset means health is off unless a listener or the
   model opts in.
-- DL4J_TPU_PROFILE=1|costs enables the compiled-function cost registry +
-  per-function MFU/roofline gauges (profiler.py, ISSUE 6); any other
-  non-empty value is additionally the jax.profiler capture directory —
-  `profiler.maybe_capture()` regions write a device trace there and merge
-  it with this tracer's timeline into one Perfetto view. Unset/0 keeps the
-  profiling call sites inert (default).
+- DL4J_TPU_PROFILE=1 (any value but 0/false/off) enables the
+  compiled-function cost registry + per-function MFU/roofline gauges
+  (profiler.py, ISSUE 6). Unset/0 keeps the profiling call sites inert
+  (default). A device trace needs no switch: a span is also a
+  `jax.profiler.TraceAnnotation`, so any recorded profile holds them.
 - DL4J_TPU_FLIGHT_RECORDER=1 attaches a default flight recorder
   (flight_recorder.py, ISSUE 8) to every new ServingEngine: it retains
   lifecycle timelines for the worst-TTFT / SLO-violating requests and
@@ -68,6 +69,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "Tracer",
     "DEFAULT_MS_BUCKETS", "DEFAULT_S_BUCKETS", "registry", "tracer", "span",
     "instant", "enabled", "configure", "maybe_export_trace", "metrics_route",
+    "count_compiles",
     "PROMETHEUS_CONTENT_TYPE", "sanitize_component", "set_track", "health",
     "profiler", "memory", "slo", "flight_recorder", "kv_observatory",
     "blame", "timeseries", "alerts",
@@ -154,6 +156,55 @@ def maybe_export_trace(path: Optional[str] = None) -> Optional[str]:
     if not path or not _ENABLED or _TRACER.n_events == 0:
         return None
     return _TRACER.export(path)
+
+
+# JAX's monitoring events -> counters of the registry: seconds, and events
+_COMPILE_SECONDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "dl4j.compile.trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "dl4j.compile.lower_s",
+    "/jax/core/compile/backend_compile_duration": "dl4j.compile.backend_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        "dl4j.compile.cache_load_s",
+}
+_COMPILE_COUNTS = {
+    "/jax/core/compile/backend_compile_duration": "dl4j.compile.programs",
+    "/jax/compilation_cache/cache_hits": "dl4j.compile.cache_hits",
+    "/jax/compilation_cache/cache_misses": "dl4j.compile.cache_misses",
+}
+_COMPILES_COUNTED = False
+
+
+def count_compiles() -> None:
+    """Count what this process compiles from now on, as counters of the
+    default registry: seconds tracing (`dl4j.compile.trace_s`), lowering
+    (`.lower_s`) and in the backend (`.backend_s`; on a cache hit that is
+    the load) with the programs (`.programs`), and the persistent cache's
+    hits, misses and load seconds. JAX fires these on compile paths only,
+    never per step: a counter that moves during training is a step that
+    recompiled. Idempotent; imports `jax`, so the nets' modules call it and
+    this package does not."""
+    global _COMPILES_COUNTED
+    if _COMPILES_COUNTED:
+        return
+    _COMPILES_COUNTED = True
+    from jax import monitoring
+    seconds = {event: _REGISTRY.counter(name, f"seconds of JAX's {event}")
+               for event, name in _COMPILE_SECONDS.items()}
+    counts = {event: _REGISTRY.counter(name, f"events of JAX's {event}")
+              for event, name in _COMPILE_COUNTS.items()}
+
+    def on_duration(event, secs, **_):
+        if event in seconds:
+            seconds[event].inc(secs)
+        if event in counts:
+            counts[event].inc()
+
+    def on_event(event, **_):
+        if event in counts:
+            counts[event].inc()
+
+    monitoring.register_event_duration_secs_listener(on_duration)
+    monitoring.register_event_listener(on_event)
 
 
 def metrics_route(reg: Optional[MetricsRegistry] = None):

@@ -18,12 +18,17 @@ ISSUE 6 tentpole, part 1+3. Three jobs:
    tests/test_profiler.py). Published per function: an `ms` histogram plus
    `measured_ms` / `mfu` / `roofline_frac` / `x_floor` gauges.
 
-3. **`jax.profiler` capture.** `DL4J_TPU_PROFILE=/some/dir` (or
-   `capture(dir)`) wraps a region in `jax.profiler.start_trace(...,
-   create_perfetto_trace=True)` and `merge_with_tracer` folds the host
-   Tracer timeline into the device trace (host events shifted onto the
-   device trace's clock) so host spans and device ops land in one Perfetto
-   view.
+3. **From a device trace's operation to the program's own name.** The
+   nets put `jax.named_scope("dl4j.<LayerClass>/<name>")` (and `dl4j.loss`,
+   `dl4j.regularization`, `dl4j.updater`) around what they trace. A device
+   trace's events carry the optimised instruction's text and no metadata,
+   but the compiled program's text has an `op_name` for every fusion, dot
+   and custom call: `op_scopes(compiled)` is the table from instruction
+   name to `op_name`, and `scope_phase(op_name)` reduces one to its
+   innermost `dl4j.` scope and forward / backward / update. The table is
+   computed by whoever reads a trace (`net.lower_train_step(...)` /
+   `net.lower_fit_batch(...)` lower the program that ran from shapes
+   alone); nothing is kept at dispatch.
 
 Honesty notes:
 - `mxu_floor_ms` is flops / peak FLOP/s, with the peak taken from
@@ -34,20 +39,15 @@ Honesty notes:
 - `bytes_accessed` is XLA's per-HLO sum (ignores fusion reuse) — the
   optimistic-roof side of the bracket, same caveat as PERF.md.
 
-Env toggle: DL4J_TPU_PROFILE=1|true|costs enables cost registration at the
-instrumented call sites; any other non-empty value additionally names the
-capture directory for `maybe_capture()`. Unset/0 keeps every site inert
+Env toggle: DL4J_TPU_PROFILE (any value but 0/false/off) enables cost
+registration at the instrumented call sites. Unset/0 keeps every site inert
 (one dict/flag check on the compile-miss path, nothing per token/step).
 """
 from __future__ import annotations
 
-import contextlib
-import glob as _glob
-import gzip
-import json
 import os
-import time
-from typing import Dict, List, Optional
+import re
+from typing import Dict, List, Optional, Tuple
 
 from deeplearning4j_tpu.telemetry.registry import (DEFAULT_MS_BUCKETS,
                                                    MetricsRegistry,
@@ -67,12 +67,8 @@ DEVICE_PEAKS: Dict[str, dict] = {
 }
 
 _FALSEY = ("", "0", "false", "off")
-_TRUTHY_COSTS_ONLY = ("1", "true", "on", "costs", "yes")
 
-_env = os.environ.get("DL4J_TPU_PROFILE", "")
-_ENABLED = _env.lower() not in _FALSEY
-_CAPTURE_DIR: Optional[str] = (
-    _env if _ENABLED and _env.lower() not in _TRUTHY_COSTS_ONLY else None)
+_ENABLED = os.environ.get("DL4J_TPU_PROFILE", "").lower() not in _FALSEY
 _PLATFORM: Optional[str] = None          # lazy jax.default_backend()
 _DEVICE_KIND: Optional[str] = None       # lazy jax.devices()[0].device_kind
 
@@ -86,28 +82,19 @@ def enabled() -> bool:
     return _ENABLED
 
 
-def capture_dir() -> Optional[str]:
-    """The jax.profiler capture directory, when DL4J_TPU_PROFILE named one
-    (any value that is not a plain on/off token)."""
-    return _CAPTURE_DIR
-
-
 def configure(enabled: Optional[bool] = None,
               platform: Optional[str] = None,
-              capture_dir: Optional[str] = None,
               device_kind: Optional[str] = None) -> None:
     """Override env defaults at runtime (tests, bench, embedding apps).
     `platform`/`device_kind` pin what would otherwise be detected from
     JAX."""
-    global _ENABLED, _PLATFORM, _CAPTURE_DIR, _DEVICE_KIND
+    global _ENABLED, _PLATFORM, _DEVICE_KIND
     if enabled is not None:
         _ENABLED = bool(enabled)
     if platform is not None:
         _PLATFORM = str(platform)
     if device_kind is not None:
         _DEVICE_KIND = str(device_kind)
-    if capture_dir is not None:
-        _CAPTURE_DIR = capture_dir or None
 
 
 def clear_observations() -> None:
@@ -119,12 +106,9 @@ def clear_observations() -> None:
 
 def reset() -> None:
     """Forget observations and restore env-derived config (tests)."""
-    global _ENABLED, _PLATFORM, _CAPTURE_DIR, _DEVICE_KIND
+    global _ENABLED, _PLATFORM, _DEVICE_KIND
     _OBSERVED.clear()
-    env = os.environ.get("DL4J_TPU_PROFILE", "")
-    _ENABLED = env.lower() not in _FALSEY
-    _CAPTURE_DIR = (env if _ENABLED
-                    and env.lower() not in _TRUTHY_COSTS_ONLY else None)
+    _ENABLED = os.environ.get("DL4J_TPU_PROFILE", "").lower() not in _FALSEY
     _PLATFORM = None
     _DEVICE_KIND = None
 
@@ -331,124 +315,61 @@ def roofline_table(registry: Optional[MetricsRegistry] = None) -> List[dict]:
     return rows
 
 
-def attribute_from_tracer(tracer=None,
-                          names: Optional[List[str]] = None) -> Dict[str, dict]:
-    """Aggregate the Tracer's recorded 'X' spans by name — total/mean ms
-    and count per span name — and join registered costs where the span
-    name matches a cost entry (floor, x_floor vs the span mean). Pure
-    post-hoc host work over the already-recorded buffer; records nothing
-    back (call `observe` for live gauges)."""
-    if tracer is None:
-        from deeplearning4j_tpu import telemetry
-        tracer = telemetry.tracer()
-    agg: Dict[str, dict] = {}
-    for ev in tracer.chrome_trace()["traceEvents"]:
-        if ev.get("ph") != "X":
+# ------------------------------------- device operation -> program's name
+# `  %fusion.7 = bf16[8]{0} fusion(%p.1, %copy-done.3), kind=kLoop, ...,
+#  metadata={op_name="jit(f)/..." ...}`
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*)$")
+_OP_NAME = re.compile(r"metadata=\{[^}]*?op_name=\"([^\"]*)\"")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+# the innermost of these in an op_name is the operation's scope
+_SCOPE = re.compile(r"dl4j\.(?:loss|regularization|updater)\b"
+                    r"|dl4j\.\w+/[^/()]+")
+
+
+def op_scopes(compiled) -> Dict[str, str]:
+    """{instruction name: op_name} of a compiled program (`jitted.lower(
+    ...).compile()`, or its `as_text()`): every instruction of the entry
+    computation and of the computations it calls (a scan's `while` body),
+    the fused computations' insides left out — a device trace has one event
+    per fusion, named by the fusion instruction. XLA keeps one instruction's
+    metadata for a fusion, so a fusion goes whole to that instruction's
+    scope. What the compiler put in to move an operand (`copy`,
+    `copy-done`, `slice-done`, ...) has no metadata: it goes to the
+    `op_name` of the first instruction that consumes its result."""
+    text = compiled if isinstance(compiled, str) else compiled.as_text()
+    rows = []                   # (name, own op_name or None, operands)
+    inside_fusion = False
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            head = _COMPUTATION.match(line)
+            if head is not None:
+                inside_fusion = "fused_computation" in head.group(1)
             continue
-        name = ev.get("name", "")
-        if names is not None and name not in names:
+        found = None if inside_fusion else _INSTRUCTION.match(line)
+        if found is None:
             continue
-        a = agg.setdefault(name, {"count": 0, "total_ms": 0.0})
-        a["count"] += 1
-        a["total_ms"] += ev.get("dur", 0.0) / 1e3
-    for name, a in agg.items():
-        a["mean_ms"] = a["total_ms"] / a["count"] if a["count"] else None
-        rec = _costs.get_costs(name)
-        if rec is not None and a["mean_ms"]:
-            plat = rec.get("meta", {}).get("platform") or _detect_platform()
-            floor = mxu_floor_ms(rec["flops"], plat)
-            a["mxu_floor_ms"] = floor
-            if floor:
-                a["x_floor"] = a["mean_ms"] / floor
-    return agg
+        name, rest = found.groups()
+        own = _OP_NAME.search(rest)
+        rows.append((name, own.group(1) if own else None,
+                     _OPERAND.findall(rest.split(", metadata=")[0])))
+    table = {name: own for name, own, _ in rows if own is not None}
+    inherited: Dict[str, str] = {}
+    # consumers before producers: the first consumer's name is written last
+    for name, own, operands in reversed(rows):
+        op_name = own or inherited.get(name)
+        if op_name is not None:
+            inherited.update((o, op_name) for o in operands if o not in table)
+    return {**inherited, **table}
 
 
-# ------------------------------------------------- jax.profiler capture
-@contextlib.contextmanager
-def capture(log_dir: str, merge: bool = True):
-    """Wrap a region in `jax.profiler.start_trace(log_dir,
-    create_perfetto_trace=True)`. On exit, stop the trace and (when
-    `merge`) fold the host Tracer timeline into the device trace via
-    `merge_with_tracer`. Degrades to a no-op (with a warning) when the
-    backend's profiler is unavailable — never takes the workload down."""
-    import warnings
-    started = False
-    t_start = time.perf_counter()
-    try:
-        import jax
-        jax.profiler.start_trace(log_dir, create_perfetto_trace=True)
-        started = True
-    except Exception as e:
-        warnings.warn(f"jax.profiler capture unavailable "
-                      f"({type(e).__name__}: {e})")
-    try:
-        yield
-    finally:
-        if started:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-                if merge:
-                    merge_with_tracer(log_dir, capture_t0=t_start)
-            except Exception as e:
-                warnings.warn(f"jax.profiler capture failed "
-                              f"({type(e).__name__}: {e})")
-
-
-def maybe_capture(log_dir: Optional[str] = None):
-    """`capture(...)` when a directory is configured (argument or
-    DL4J_TPU_PROFILE=<dir>), else a null context. Lets call sites write
-    `with profiler.maybe_capture(): ...` unconditionally."""
-    log_dir = log_dir or _CAPTURE_DIR
-    if not log_dir:
-        return contextlib.nullcontext()
-    return capture(log_dir)
-
-
-def merge_with_tracer(log_dir: str, out_path: Optional[str] = None,
-                      tracer=None,
-                      capture_t0: Optional[float] = None) -> Optional[str]:
-    """Merge the newest `perfetto_trace.json.gz` under `log_dir` (the
-    jax.profiler device timeline) with the host Tracer's Chrome events
-    into one Perfetto-loadable JSON at `out_path` (default
-    `<log_dir>/merged_trace.json`). Host events keep pid=1 (named
-    "dl4j_tpu host tracer") and are shifted onto the device trace's clock
-    when `capture_t0` (the host perf_counter at capture start) is given —
-    the device trace's ts origin is its own start. Returns the written
-    path, or None when no device trace was found."""
-    if tracer is None:
-        from deeplearning4j_tpu import telemetry
-        tracer = telemetry.tracer()
-    pats = sorted(_glob.glob(os.path.join(
-        log_dir, "**", "perfetto_trace.json.gz"), recursive=True))
-    if not pats:
-        pats = sorted(_glob.glob(os.path.join(
-            log_dir, "**", "*.trace.json.gz"), recursive=True))
-    if not pats:
-        return None
-    with gzip.open(pats[-1], "rt") as f:
-        device_doc = json.load(f)
-    device_events = (device_doc.get("traceEvents", [])
-                     if isinstance(device_doc, dict) else device_doc)
-    host_doc = tracer.chrome_trace()
-    shift_us = 0.0
-    if capture_t0 is not None:
-        # host events' ts origin is the tracer's epoch; the device trace's
-        # is the capture start — shift host events onto the device clock
-        shift_us = (tracer._epoch - capture_t0) * 1e6
-    host_events: List[dict] = [
-        {"ph": "M", "pid": 1, "name": "process_name",
-         "args": {"name": "dl4j_tpu host tracer"}}]
-    for ev in host_doc["traceEvents"]:
-        ev = dict(ev)
-        if "ts" in ev:
-            ev["ts"] = round(ev["ts"] + shift_us, 3)
-        host_events.append(ev)
-    merged = {"displayTimeUnit": "ms",
-              "traceEvents": list(device_events) + host_events,
-              "otherData": {"producer": "deeplearning4j_tpu.telemetry."
-                                        "profiler"}}
-    out_path = out_path or os.path.join(log_dir, "merged_trace.json")
-    with open(out_path, "w") as f:
-        json.dump(merged, f)
-    return out_path
+def scope_phase(op_name: str) -> Tuple[Optional[str], str]:
+    """(scope, phase) of an `op_name`: the innermost `dl4j.` scope in it
+    (`dl4j.ConvolutionLayer/res2a`, `dl4j.updater`; None where the program
+    named nothing) and `update` under `dl4j.updater`, `backward` under a
+    `transpose(`, else `forward` (under `jvp(` only, or plain)."""
+    scopes = _SCOPE.findall(op_name)
+    scope = scopes[-1] if scopes else None
+    if "dl4j.updater" in scopes:
+        return scope, "update"
+    return scope, "backward" if "transpose(" in op_name else "forward"

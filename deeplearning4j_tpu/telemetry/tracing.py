@@ -3,10 +3,17 @@
 Spans are recorded from HOST timestamps only (`time.perf_counter`) — entering
 or exiting a span never materializes device data, so tracing the decode hot
 loop adds zero host syncs (the ISSUE 4 invariant, asserted in
-tests/test_telemetry.py). Events land in a bounded in-memory buffer
-(preallocated-size list, drops counted past the cap) and export as standard
+tests/test_telemetry.py). Events land in a bounded in-memory ring (the
+newest `max_events` stay, what falls out is counted) and export as standard
 Chrome trace JSON (`{"traceEvents": [...]}` — load in chrome://tracing or
 https://ui.perfetto.dev).
+
+Where `jax` is already imported a span is also a
+`jax.profiler.TraceAnnotation(name, **args)`: whenever anyone records a
+profile (`jax.profiler.start_trace`, TensorBoard, the benchmark's traced
+run) the span is in it, on `/host:CPU`, on the device trace's clock, on the
+thread that ran it, with its arguments as stats. With no profile running
+that costs a flag check.
 
 Span vocabulary used across the framework (see serving/engine.py,
 optimize/solvers.py, optimize/listeners.py):
@@ -17,13 +24,32 @@ optimize/solvers.py, optimize/listeners.py):
                     wraps the dispatch that triggered the compile
 - "admit"/"retire" — instant events for scheduling decisions
 - "epoch"/"solver.optimize" — training-side phases
+
+The training path's spans all start with `dl4j.` and carry `step=<the net's
+iteration when the span began>`, so the spans of one step share it:
+- "dl4j.fit.next_batch"   — `fit`'s wait for the iterator (what
+                            `last_etl_ms` times)
+- "dl4j.fit_batch" with children ".prepare" (input conversion, rng split,
+  argument tuple), ".dispatch" (the call of the jitted train step) and
+  ".listeners" (the `iteration_done` loop); "dl4j.fit_tbptt" around the
+  segments of one truncated-BPTT batch
+- "dl4j.fit_on_device" with children ".prepare", ".dispatch" and
+  ".readback" (`jax.device_get` of the losses and the divergence flag)
+- on the producer thread of `AsyncDataSetIterator`: "dl4j.async.produce"
+  (the underlying iterator's `next`), "dl4j.async.stage" (`np.asarray` +
+  `jax.device_put`, args: bytes) and "dl4j.async.put_wait" (blocked on the
+  full queue)
+- "dl4j.pw.fit_on_device" with ".dispatch", ".readback", ".write_back" —
+  `ParallelWrapper.fit_on_device`
 """
 from __future__ import annotations
 
+import collections
 import json
+import sys
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Deque, Dict, Optional
 
 _US = 1e6
 
@@ -52,8 +78,18 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+def _annotation(name: str, args: Optional[dict]):
+    """The span as a `jax.profiler.TraceAnnotation` while a profile is being
+    recorded; None otherwise, and while `jax` is not imported (this module
+    stays importable without it)."""
+    jax = sys.modules.get("jax")
+    if jax is None or not jax.profiler.TraceAnnotation.is_enabled():
+        return None
+    return jax.profiler.TraceAnnotation(name, **(args or {}))
+
+
 class _Span:
-    __slots__ = ("_tracer", "name", "args", "_t0", "_tid")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_tid", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
         self._tracer = tracer
@@ -61,26 +97,31 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        self._ann = _annotation(self.name, self.args)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         self._tid = threading.get_ident()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         self._tracer._record("X", self.name, self._t0, t1 - self._t0,
                              self._tid, self.args)
         return False
 
 
 class Tracer:
-    """Bounded in-memory span recorder. All methods are cheap host work;
-    `export()` is the only I/O."""
+    """Bounded in-memory span recorder: a ring of the newest `max_events`.
+    All methods are cheap host work; `export()` is the only I/O."""
 
     def __init__(self, max_events: int = 65536, enabled: bool = True,
                  drop_counter=None):
         self.max_events = int(max_events)
         self.enabled = bool(enabled)
-        self._events: List[dict] = []
+        self._events: Deque[dict] = collections.deque(maxlen=self.max_events)
         self._dropped = 0
         # optional registry Counter mirroring the drop count on /metrics
         # (ISSUE 6 satellite) — before, drops were only visible in the
@@ -91,7 +132,7 @@ class Tracer:
         # scheduler threads so multi-replica dumps are distinguishable
         self._tracks: Dict[str, int] = {}
         self._track_meta: Dict[str, dict] = {}
-        self._lock = threading.Lock()   # append-side: list.append is atomic
+        self._lock = threading.Lock()   # append-side: deque.append is atomic
         #                                 under the GIL; the lock guards only
         #                                 clear()/export() vs. appends
 
@@ -134,10 +175,10 @@ class Tracer:
     def _record(self, ph: str, name: str, t0: float, dur: Optional[float],
                 tid: int, args: Optional[dict]) -> None:
         if len(self._events) >= self.max_events:
+            # the ring drops its oldest event for this one
             self._dropped += 1
             if self._drop_counter is not None:
                 self._drop_counter.inc()
-            return
         track = getattr(_TRACK, "tid", None)
         if track is not None:
             tid = track
@@ -157,7 +198,7 @@ class Tracer:
     # ------------------------------------------------------------ export
     def clear(self) -> None:
         with self._lock:
-            self._events = []
+            self._events.clear()
             self._dropped = 0
 
     @property

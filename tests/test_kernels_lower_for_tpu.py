@@ -148,6 +148,14 @@ CASES = {
 }
 
 
+# the names a device trace shows the scan's two Mosaic calls under
+# (`kernel_name` of the custom call, the compiled instruction's name)
+KERNEL_NAMES = {
+    "graves_lstm_scan": ("dl4j_lstm_scan_fwd",),
+    "graves_lstm_scan bwd": ("dl4j_lstm_scan_fwd", "dl4j_lstm_scan_bwd"),
+}
+
+
 def test_every_registered_helper_has_a_case():
     covered = {name.split()[0] for name in CASES}
     assert covered == set(helpers.registered_helpers())
@@ -161,8 +169,15 @@ def test_kernel_lowers_and_compiles_for_tpu(name, v5e_sharding):
         lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" in text, f"{name}: no Mosaic call in the " \
         "lowered text — the kernel gave way to its reference"
+    for kernel in KERNEL_NAMES.get(name, ()):
+        assert f'kernel_name = "{kernel}"' in text
     if v5e_sharding is None:
         return
     on_chip = [jax.ShapeDtypeStruct(s, d, sharding=v5e_sharding)
                for s, d in shapes]
-    jax.jit(fn).lower(*on_chip).compile()
+    compiled = jax.jit(fn).lower(*on_chip).compile().as_text()
+    calls = [line.split(" = ")[0] for line in compiled.splitlines()
+             if " custom-call(" in line]
+    for kernel in KERNEL_NAMES.get(name, ()):
+        # `dl4j_lstm_scan_fwd.1` inside a net, `jvp_dl4j_lstm_scan_fwd_.1` here
+        assert any(kernel in call for call in calls), calls

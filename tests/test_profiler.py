@@ -11,8 +11,6 @@ The tentpole invariants:
   degrades gracefully on CPU (live-buffer fallback, platform label);
 - the merged Perfetto trace folds host tracer spans into a device capture.
 """
-import gzip
-import json
 import math
 
 import numpy as np
@@ -341,31 +339,7 @@ def test_param_bytes_is_metadata_only():
     assert "memory_params_m_bytes 192" in reg.prometheus_text()
 
 
-# ------------------------------------------------ trace merge / drops
-def test_merge_with_tracer_folds_host_events(tmp_path):
-    # synthetic "device" perfetto trace, as jax.profiler would write it
-    d = tmp_path / "plugins" / "profile" / "2026_01_01"
-    d.mkdir(parents=True)
-    dev = {"displayTimeUnit": "ms",
-           "traceEvents": [{"ph": "X", "pid": 701, "tid": 1, "name": "fusion",
-                            "ts": 10.0, "dur": 5.0}]}
-    with gzip.open(d / "perfetto_trace.json.gz", "wt") as f:
-        json.dump(dev, f)
-    tr = Tracer()
-    with tr.span("host_work"):
-        pass
-    out = profiler.merge_with_tracer(str(tmp_path), tracer=tr,
-                                     capture_t0=tr._epoch)
-    doc = json.load(open(out))
-    names = [e.get("name") for e in doc["traceEvents"]]
-    assert "fusion" in names and "host_work" in names
-    assert "dl4j_tpu host tracer" in json.dumps(doc)
-
-
-def test_merge_without_device_trace_returns_none(tmp_path):
-    assert profiler.merge_with_tracer(str(tmp_path)) is None
-
-
+# ------------------------------------------------------------ drops
 def test_trace_drop_counter_reaches_metrics():
     reg = MetricsRegistry()
     c = reg.counter("telemetry.trace.dropped_events", "d")
@@ -383,19 +357,10 @@ def test_trace_drop_counter_reaches_metrics():
 def test_profile_env_parsing(monkeypatch):
     monkeypatch.setenv("DL4J_TPU_PROFILE", "0")
     profiler.reset()
-    assert not profiler.enabled() and profiler.capture_dir() is None
+    assert not profiler.enabled()
     monkeypatch.setenv("DL4J_TPU_PROFILE", "1")
     profiler.reset()
-    assert profiler.enabled() and profiler.capture_dir() is None
-    monkeypatch.setenv("DL4J_TPU_PROFILE", "/tmp/prof_dir")
-    profiler.reset()
-    assert profiler.enabled() and profiler.capture_dir() == "/tmp/prof_dir"
+    assert profiler.enabled()
     monkeypatch.delenv("DL4J_TPU_PROFILE")
     profiler.reset()
     assert not profiler.enabled()
-
-
-def test_maybe_capture_nullcontext_when_unconfigured():
-    profiler.configure(enabled=True, capture_dir="")
-    with profiler.maybe_capture():
-        pass                                 # must not start a real trace

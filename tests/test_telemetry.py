@@ -252,7 +252,10 @@ def test_tracer_bounded_buffer_counts_drops():
     for k in range(5):
         tr.instant(f"e{k}")
     assert tr.n_events == 3
-    assert tr.chrome_trace()["otherData"]["dropped_events"] == 2
+    doc = tr.chrome_trace()
+    assert doc["otherData"]["dropped_events"] == 2
+    # a ring: the newest stay (a training run keeps its last steps)
+    assert [e["name"] for e in doc["traceEvents"]] == ["e2", "e3", "e4"]
     tr.clear()
     assert tr.n_events == 0
 
