@@ -411,7 +411,7 @@ def bench_graves_lstm_roofline(lstm_entry, batch=8192, seq_len=100,
     rng = np.random.RandomState(0)
     mk = lambda *s: jnp.asarray(rng.randn(*s).astype(np.float32) * 0.1,
                                 jnp.bfloat16)
-    args = (mk(T, B, 4 * H), mk(H, 4 * H), mk(H), mk(H), mk(H),
+    args = (mk(T, B, 4 * H), mk(4 * H), mk(H, 4 * H), mk(H), mk(H), mk(H),
             mk(B, H), mk(B, H))
 
     def loss(*a):

@@ -105,12 +105,14 @@ class LSTM(FeedForwardLayerConf):
         c = jnp.zeros((b, n), dtype) if c0 is None else c0
         xt = jnp.moveaxis(x, 2, 0)  # (time, batch, size)
         # one big batched input projection — single MXU matmul over all timesteps
-        xw = xt @ params["W"] + params["b"]
+        xw = xt @ params["W"]
         # helper seam, whole-sequence form (the cuDNN-LSTM analog, ref
         # CudnnLSTMHelper.java:175): the ENTIRE recurrence as one Pallas
-        # kernel with h/c resident in VMEM (ops/lstm_scan_fused.py). Zero
-        # peepholes reduce exactly to the plain-LSTM math. Masked sequences
-        # keep the lax.scan path (the kernel has no state-hold select).
+        # kernel with h/c resident in VMEM (ops/lstm_scan_fused.py). It adds
+        # the bias itself, so that its backward sums the bias gradient where
+        # the gate gradients already are. Zero peepholes reduce exactly to
+        # the plain-LSTM math. Masked sequences keep the lax.scan path (the
+        # kernel has no state-hold select).
         if mask is None and self.gate_activation == Activation.SIGMOID \
                 and self.activation == Activation.TANH:
             from deeplearning4j_tpu.ops.helpers import (
@@ -125,11 +127,13 @@ class LSTM(FeedForwardLayerConf):
                 pf = params.get("pf", zero)
                 po = params.get("po", zero)
                 xw_k = xw[::-1] if reverse else xw
-                ys, cs = fused(xw_k, params["RW"], pi, pf, po, h, c)
+                ys, cs = fused(xw_k, params["b"], params["RW"], pi, pf, po,
+                               h, c)
                 h_f, c_f = ys[-1], cs[-1]
                 if reverse:
                     ys = ys[::-1]
                 return jnp.moveaxis(ys, 0, 2), (h_f, c_f)
+        xw = xw + params["b"]
         mt = None if mask is None else jnp.moveaxis(mask, 1, 0)[..., None].astype(dtype)
 
         def body(carry, inp):

@@ -24,6 +24,7 @@ from deeplearning4j_tpu.ops.conv_fused import conv1x1_bn_act
 from deeplearning4j_tpu.ops.decode_attention import (
     flash_decode_attention_paged, flash_decode_attention_spec_paged)
 from deeplearning4j_tpu.ops.flash_attention import flash_attention
+from deeplearning4j_tpu.ops import lstm_scan_fused
 from deeplearning4j_tpu.ops.lstm_scan_fused import graves_lstm_scan_pallas
 from deeplearning4j_tpu.ops.pallas_kernels import (
     graves_gates_pallas, lstm_gates_pallas, threshold_encode_pallas)
@@ -81,8 +82,16 @@ def _flash(window=0, bwd=None):
 
 # fused Graves-LSTM scan: T100 B8192 H256 bf16 (zoo TextGenerationLSTM)
 T, B, H = 100, 8192, 256
-SCAN = [((T, B, 4 * H), BF16), ((H, 4 * H), BF16), ((H,), BF16),
-        ((H,), BF16), ((H,), BF16), ((B, H), BF16), ((B, H), BF16)]
+SCAN = [((T, B, 4 * H), BF16), ((4 * H,), BF16), ((H, 4 * H), BF16),
+        ((H,), BF16), ((H,), BF16), ((H,), BF16), ((B, H), BF16),
+        ((B, H), BF16)]
+
+
+def _scan_ys_only(*a):
+    """What the layers do: cs dropped, so its cotangent is a symbolic zero
+    and the backward is built without the dcs stream."""
+    return graves_lstm_scan_pallas(*a)[0]
+
 
 # paged decode: 8 slots, 4 heads / 2 kv heads x 64, 1024 positions in
 # blocks of 16 (bench_decode_serving through ServingEngine)
@@ -118,7 +127,8 @@ CASES = {
     "flash_attention bwd two_pass window=1024":
         (_grad(_flash(1024, "two_pass"), 3), QKV),
     "graves_lstm_scan": (graves_lstm_scan_pallas, SCAN),
-    "graves_lstm_scan bwd": (_grad(graves_lstm_scan_pallas, 7), SCAN),
+    "graves_lstm_scan bwd": (_grad(graves_lstm_scan_pallas, 8), SCAN),
+    "graves_lstm_scan bwd cs unused": (_grad(_scan_ys_only, 8), SCAN),
     "decode_attention_paged":
         (_decode(flash_decode_attention_paged), _paged((S, NH, D), BF16)),
     "decode_attention_paged window=256":
@@ -153,6 +163,8 @@ CASES = {
 KERNEL_NAMES = {
     "graves_lstm_scan": ("dl4j_lstm_scan_fwd",),
     "graves_lstm_scan bwd": ("dl4j_lstm_scan_fwd", "dl4j_lstm_scan_bwd"),
+    "graves_lstm_scan bwd cs unused":
+        ("dl4j_lstm_scan_fwd", "dl4j_lstm_scan_bwd"),
 }
 
 
@@ -181,3 +193,17 @@ def test_kernel_lowers_and_compiles_for_tpu(name, v5e_sharding):
     for kernel in KERNEL_NAMES.get(name, ()):
         # `dl4j_lstm_scan_fwd.1` inside a net, `jvp_dl4j_lstm_scan_fwd_.1` here
         assert any(kernel in call for call in calls), calls
+
+
+@pytest.mark.parametrize("stream_dcs", [True, False])
+def test_scan_layout_at_the_smoke_shape(stream_dcs):
+    """The estimate that picks the tiles the compiler is shown above: forward
+    1024 and backward 512, batch-major, with the dcs stream and without it
+    (the 0.5 MB it frees does not reach the next tile, which estimates
+    several MB over the budget)."""
+    assert lstm_scan_fused._pick_layout(T, B, H, 2, stream_dcs) == \
+        (False, 1, 1024, 512)
+    fits, next_up = (
+        lstm_scan_fused._vmem_cost(H, 2, bt, True, bt, 1, stream_dcs)
+        for bt in (512, 1024))
+    assert fits <= lstm_scan_fused.VMEM_BUDGET < next_up - 4 * 1024 * 1024

@@ -15,12 +15,13 @@ RNG = np.random.RandomState(7)
 
 def _data(T=9, B=16, H=8, dtype=np.float64):
     xw = jnp.asarray(RNG.randn(T, B, 4 * H).astype(dtype) * 0.5)
+    b = jnp.asarray(RNG.randn(4 * H).astype(dtype) * 0.3)
     rw = jnp.asarray(RNG.randn(H, 4 * H).astype(dtype) * 0.3)
     pi, pf, po = (jnp.asarray(RNG.randn(H).astype(dtype) * 0.1)
                   for _ in range(3))
     h0 = jnp.asarray(RNG.randn(B, H).astype(dtype) * 0.2)
     c0 = jnp.asarray(RNG.randn(B, H).astype(dtype) * 0.2)
-    return xw, rw, pi, pf, po, h0, c0
+    return xw, b, rw, pi, pf, po, h0, c0
 
 
 def test_forward_matches_scan_fp64():
@@ -51,8 +52,8 @@ def test_non_divisible_batch_pads_exactly():
     def loss(fn):
         return lambda *a: jnp.sum(jnp.sin(fn(*a)[0])) + jnp.sum(fn(*a)[1] ** 2)
 
-    gp = jax.grad(loss(graves_lstm_scan_pallas), argnums=tuple(range(7)))(*args)
-    gx = jax.grad(loss(graves_lstm_scan_xla), argnums=tuple(range(7)))(*args)
+    gp = jax.grad(loss(graves_lstm_scan_pallas), argnums=tuple(range(8)))(*args)
+    gx = jax.grad(loss(graves_lstm_scan_xla), argnums=tuple(range(8)))(*args)
     for a, b in zip(gp, gx):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-9)
 
@@ -61,7 +62,7 @@ def test_non_divisible_batch_pads_exactly():
                                     ("tm", 1), ("tm", 2), ("tm", 5)])
 def test_layout_matrix_value_and_grad_fp64(grid, K):
     """Every grid layout x K-step combination the dispatcher can pick must
-    match the lax.scan oracle exactly — value AND all seven gradients
+    match the lax.scan oracle exactly — value AND all eight gradients
     (non-divisible B exercises the padding path in both layouts)."""
     import deeplearning4j_tpu.ops.lstm_scan_fused as m
     args = _data(T=10, B=12, H=8)
@@ -73,11 +74,11 @@ def test_layout_matrix_value_and_grad_fp64(grid, K):
         return f
 
     ref_v, ref_g = jax.value_and_grad(
-        loss(graves_lstm_scan_xla), argnums=tuple(range(7)))(*args)
+        loss(graves_lstm_scan_xla), argnums=tuple(range(8)))(*args)
     prev = m.configure(grid=grid, k_steps=K)
     try:
         v, g = jax.value_and_grad(
-            loss(graves_lstm_scan_pallas), argnums=tuple(range(7)))(*args)
+            loss(graves_lstm_scan_pallas), argnums=tuple(range(8)))(*args)
     finally:
         m.configure(**prev)
     assert abs(float(v - ref_v)) < 1e-10
@@ -99,9 +100,9 @@ def test_backward_matches_scan_autodiff_fp64(use_dcs):
         return f
 
     gp = jax.grad(loss(graves_lstm_scan_pallas),
-                  argnums=tuple(range(7)))(*args)
-    gx = jax.grad(loss(graves_lstm_scan_xla), argnums=tuple(range(7)))(*args)
-    names = ("dxw", "drw", "dpi", "dpf", "dpo", "dh0", "dc0")
+                  argnums=tuple(range(8)))(*args)
+    gx = jax.grad(loss(graves_lstm_scan_xla), argnums=tuple(range(8)))(*args)
+    names = ("dxw", "db", "drw", "dpi", "dpf", "dpo", "dh0", "dc0")
     for n, a, b in zip(names, gp, gx):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-9,
                                    err_msg=n)
@@ -130,6 +131,95 @@ def test_fp64_finite_differences_through_kernel():
         assert abs(num - ana[i]) / denom < 1e-5, (i, num, ana[i])
 
 
+def _bwd_calls(jaxpr):
+    """The backward kernel's pallas_call equations (eight outputs: dxw, db,
+    dRW, three peephole grads, dh0, dc0) anywhere in a closed jaxpr."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call" and len(eqn.outvars) == 8:
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _bwd_calls(sub)
+    return found
+
+
+def _counter(which):
+    from deeplearning4j_tpu import telemetry
+    return telemetry.registry().counter(
+        f"ops.lstm_scan.bwd.dcs_{which}").value
+
+
+@pytest.mark.parametrize("use_cs", [False, True])
+def test_zero_cotangent_of_cs_is_not_streamed(use_cs):
+    """The variant is chosen by the cotangent: a caller that drops cs (the
+    layers do) gets a backward call without the dcs operand — no (T, B, H)
+    block of zeros is built for it — and one that consumes cs streams it as
+    before. Gradients match the lax.scan twin in both; the counters say
+    which variant was traced."""
+    T, B, H = 7, 8, 8
+    args = _data(T=T, B=B, H=H)
+
+    def loss(fn):
+        def f(*a):
+            ys, cs = fn(*a)
+            val = jnp.sum(jnp.sin(ys))
+            return val + jnp.sum(cs[-1] * 0.5) if use_cs else val
+        return f
+
+    grad = jax.grad(loss(graves_lstm_scan_pallas), argnums=tuple(range(8)))
+    before = _counter("streamed"), _counter("elided")
+    closed = jax.make_jaxpr(grad)(*args)
+    after = _counter("streamed"), _counter("elided")
+    assert (after[0] - before[0], after[1] - before[1]) == \
+        ((1, 0) if use_cs else (0, 1))
+    (call,) = _bwd_calls(closed.jaxpr)
+    # xw with the bias, rw, pi, pf, po, h_prev, c_prev, h0, c0, dys (+ dcs)
+    assert len(call.invars) == (11 if use_cs else 10)
+    if not use_cs:
+        made = {v: e.primitive.name for e in closed.jaxpr.eqns
+                for v in e.outvars}
+        stream = [v for v in call.invars
+                  if getattr(v.aval, "shape", None) == (T, B, H)]
+        assert len(stream) == 3                     # ys, cs, dys
+        assert not any(made.get(v) == "broadcast_in_dim" for v in stream)
+    gx = jax.grad(loss(graves_lstm_scan_xla), argnums=tuple(range(8)))(*args)
+    for a, b in zip(grad(*args), gx):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-9)
+
+
+@pytest.mark.parametrize("helpers_on", [True, False])
+def test_train_step_sums_the_bias_gradient_in_the_kernel(helpers_on):
+    """With the fused scan engaged, the lowered train step of the zoo's
+    two-layer GravesLSTM net holds no reduce over a (T, B, 4H) operand —
+    the bias gradient XLA used to take by reading the whole gate gradient
+    again — and both layers' backwards were built without dcs. With the
+    helper off the lax.scan path adds the bias outside and that reduce is
+    there (the control: the pattern finds it)."""
+    import re
+
+    from deeplearning4j_tpu.models import TextGenerationLSTM
+    from deeplearning4j_tpu.nn.conf.layers.recurrent import GravesLSTM
+    from deeplearning4j_tpu.ops.helpers import helpers_enabled_ctx
+
+    vocab, B, T = 12, 4, 6
+    with helpers_enabled_ctx(helpers_on):
+        net = TextGenerationLSTM(total_unique_characters=vocab, seed=5,
+                                 dtype="float64").init()
+        H = next(l.n_out for l in net.layers if isinstance(l, GravesLSTM))
+        xy = jax.ShapeDtypeStruct((B, vocab, T), jnp.float64)
+        before = _counter("elided"), _counter("streamed")
+        text = net.lower_train_step(xy, xy).as_text()
+        after = _counter("elided"), _counter("streamed")
+    reduces = re.findall(
+        rf"stablehlo\.reduce\(.*: \(tensor<{T}x{B}x{4 * H}xf64>", text)
+    if helpers_on:
+        assert not reduces
+        assert (after[0] - before[0], after[1] - before[1]) == (2, 0)
+    else:
+        assert len(reduces) == 2
+        assert after == before
+
+
 @pytest.mark.parametrize("grid", ["bm", "tm"])
 def test_multi_batch_tile_parity(monkeypatch, grid):
     """nb > 1 in BOTH grid layouts: the VMEM state carries must be per-tile
@@ -137,7 +227,8 @@ def test_multi_batch_tile_parity(monkeypatch, grid):
     between tiles)."""
     import deeplearning4j_tpu.ops.lstm_scan_fused as m
     monkeypatch.setattr(
-        m, "_pick_bt", lambda B, H, db, bwd, time_major, K=1: B // 4)
+        m, "_pick_bt",
+        lambda B, H, db, bwd, time_major, K=1, stream_dcs=True: B // 4)
     prev = m.configure(grid=grid)
     try:
         args = _data(T=6, B=16, H=8)
@@ -150,9 +241,9 @@ def test_multi_batch_tile_parity(monkeypatch, grid):
             return lambda *a: jnp.sum(jnp.sin(fn(*a)[0]))
 
         gp = jax.grad(loss(m.graves_lstm_scan_pallas),
-                      argnums=tuple(range(7)))(*args)
+                      argnums=tuple(range(8)))(*args)
         gx = jax.grad(loss(graves_lstm_scan_xla),
-                      argnums=tuple(range(7)))(*args)
+                      argnums=tuple(range(8)))(*args)
         for a, b in zip(gp, gx):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=1e-9)
