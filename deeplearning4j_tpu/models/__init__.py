@@ -7,6 +7,7 @@ from deeplearning4j_tpu.models.lenet import LeNet
 from deeplearning4j_tpu.models.resnet50 import ResNet50
 from deeplearning4j_tpu.models.simple_cnn import SimpleCNN, TextGenerationLSTM
 from deeplearning4j_tpu.models.vgg import VGG16, VGG19
+from deeplearning4j_tpu.models.xing4 import Xing4
 from deeplearning4j_tpu.models.zoo_model import PretrainedType, ZooModel
 
 ZOO = {

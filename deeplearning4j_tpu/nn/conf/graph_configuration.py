@@ -28,13 +28,20 @@ class GraphNode:
     conf: Union[BaseLayerConf, GraphVertex]
     inputs: List[str]
     preprocessor: Optional[InputPreProcessor] = None
+    # a layer node that uses another layer node's parameters (an output head
+    # tied to another, a second reader of one token table): it holds none of
+    # its own, and the gradient of both uses reaches the one copy
+    tied_to: Optional[str] = None
 
     def to_dict(self):
-        return {
+        d = {
             "name": self.name, "kind": self.kind, "conf": self.conf.to_dict(),
             "inputs": list(self.inputs),
             "preprocessor": self.preprocessor.to_dict() if self.preprocessor else None,
         }
+        if self.tied_to is not None:
+            d["tied_to"] = self.tied_to
+        return d
 
     @staticmethod
     def from_dict(d):
@@ -42,7 +49,8 @@ class GraphNode:
         conf = (BaseLayerConf.from_dict(d["conf"]) if kind == "layer"
                 else GraphVertex.from_dict(d["conf"]))
         pp = InputPreProcessor.from_dict(d["preprocessor"]) if d.get("preprocessor") else None
-        return GraphNode(d["name"], kind, conf, list(d["inputs"]), pp)
+        return GraphNode(d["name"], kind, conf, list(d["inputs"]), pp,
+                         d.get("tied_to"))
 
 
 class ComputationGraphConfiguration:
@@ -179,12 +187,16 @@ class GraphBuilder:
     addInputs = add_inputs
 
     def add_layer(self, name: str, layer: BaseLayerConf, *inputs: str,
-                  preprocessor: Optional[InputPreProcessor] = None):
+                  preprocessor: Optional[InputPreProcessor] = None,
+                  tied_to: Optional[str] = None):
+        """`tied_to` names the layer node whose parameters this one uses
+        in place of its own (same layer shapes)."""
         if name in self._nodes or name in self._inputs:
             raise ValueError(f"Duplicate node name '{name}'")
         layer = self._parent._apply_defaults(layer)
         layer.name = name
-        self._nodes[name] = GraphNode(name, "layer", layer, list(inputs), preprocessor)
+        self._nodes[name] = GraphNode(name, "layer", layer, list(inputs),
+                                      preprocessor, tied_to)
         return self
     addLayer = add_layer
 
@@ -239,6 +251,12 @@ class GraphBuilder:
         for out in conf.outputs:
             if out not in conf.nodes:
                 raise ValueError(f"Output '{out}' is not a node in the graph")
+        for name, node in conf.nodes.items():
+            owner = conf.nodes.get(node.tied_to) if node.tied_to else None
+            if node.tied_to and (owner is None or owner.kind != "layer"
+                                 or owner.tied_to is not None):
+                raise ValueError(f"Node '{name}' is tied to '{node.tied_to}', "
+                                 "which is no layer node with parameters of its own")
 
         if conf.input_types is not None:
             if len(conf.input_types) != len(conf.inputs):

@@ -203,6 +203,12 @@ def _deserde_value(hint, v):
     return v
 
 
+def layer_scope(layer, name) -> "jax.named_scope":
+    """The name a layer's or vertex's operations carry in the compiled
+    program: `dl4j.<its class>/<its name>` (telemetry.profiler.op_scopes)."""
+    return jax.named_scope(f"dl4j.{type(layer).__name__}/{name}")
+
+
 def apply_dropout(x: jnp.ndarray, retain_prob: float, rng: jax.Array) -> jnp.ndarray:
     """Inverted dropout on layer *input* (ref util/Dropout.java applied in
     applyDropOutIfNecessary before the layer op)."""

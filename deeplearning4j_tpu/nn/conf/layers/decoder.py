@@ -1,0 +1,562 @@
+"""The layers of a sparse decoder block, each trained through the normal walk.
+
+Beyond-reference (the 2017 reference has no attention, no norm of this kind
+and no experts): RMS norm, a token table over `(batch, time)` ids, latent
+attention with a decoupled rotary key and YaRN frequencies, the gated MLP,
+dropless sigmoid top-k routed experts beside a shared one, the
+manifold-constrained hyper-connection around a sublayer (a wrapping layer
+config, as `Bidirectional` is), the multi-token-prediction module's input
+and a softmax cross-entropy head over integer labels.
+
+Layout: these layers pass `(batch, time, features)` between them (features
+last, as the matrix unit wants them), not DL4J's `(batch, features, time)`;
+the residual state of a hyper-connected stack is `(batch, streams, time,
+features)`. Their `InputType` is `recurrent(features, time)` all the same.
+
+The chip's share is a property of the layers: the table and the head are told
+which rows of the vocabulary they hold, the attention which heads, the expert
+layer which experts, of the published counts. The router still scores every
+published expert and picks its k; the layer adds its own experts' part for the
+tokens routed to them, and what absent heads and experts would have added is
+left out. On one chip a layer runs without its exchange.
+"""
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from deeplearning4j_tpu.nn.conf.input_type import InputType
+from deeplearning4j_tpu.nn.conf.layers.base import (
+    BaseLayerConf, FeedForwardLayerConf, layer_scope, register_layer)
+
+_F32 = jnp.float32
+
+# A sequence no longer than one tile of the matrix unit is one dense product;
+# longer ones go through the flash kernel (`SelfAttentionLayer`'s rule, which
+# has the same number as its default): the kernel would pad a short one to its
+# own tile of 512.
+_DENSE_ATTENTION_MAX_T = 128
+
+# mHC's initial maps (arXiv:2512.24880; the plain reference has the same two
+# numbers): the learned part of each map starts small against its bias, and the
+# residual map's bias is a heavy diagonal, so that Sinkhorn starts it near the
+# identity and a fresh hyper-connection is nearly a plain residual.
+_HC_MAP_SCALE_INIT = 0.1
+_HC_RES_DIAGONAL_INIT = 4.0
+
+
+def rms_norm(x, g, eps):
+    """x / rms(x) * g over the last axis, worked out in float32."""
+    x32 = x.astype(_F32)
+    y = x32 * lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    if g is not None:
+        y = y * g.astype(_F32)
+    return y.astype(x.dtype)
+
+
+def _time_of(input_type) -> int:
+    return getattr(input_type, "timeseries_length", -1)
+
+
+class _TokenLayer(FeedForwardLayerConf):
+    """(batch, time, n_in) -> (batch, time, n_out)."""
+
+    def set_n_in(self, input_type, override=False):
+        if self.n_in == 0 or override:
+            self.n_in = input_type.size
+        if self.n_out == 0:
+            self.n_out = self.n_in
+
+    def get_output_type(self, input_type):
+        return InputType.recurrent(self.n_out, _time_of(input_type))
+
+
+@register_layer
+@dataclass
+class RMSNorm(_TokenLayer):
+    eps: float = 1e-6
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        return {"g": jnp.ones((self.n_in,), dtype)}
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        return rms_norm(x, params["g"], self.eps), state, mask
+
+
+@register_layer
+@dataclass
+class TokenEmbedding(FeedForwardLayerConf):
+    """(batch, time) integer ids -> (batch, time, n_out). `n_in` is the
+    published vocabulary; the table keeps rows `[first_row, first_row +
+    rows_held)` of it (all where `rows_held` is 0) and ids are of those."""
+    rows_held: int = 0
+    first_row: int = 0
+    integer_input = True       # the walk hands it the ids as they are
+
+    @property
+    def rows(self) -> int:
+        return self.rows_held or self.n_in
+
+    def set_n_in(self, input_type, override=False):
+        return None
+
+    def get_output_type(self, input_type):
+        return InputType.recurrent(self.n_out, input_type.size)
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        return {"W": self._winit(key, (self.rows, self.n_out), self.n_in,
+                                 self.n_out, dtype)}
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        return params["W"][x.astype(jnp.int32) - self.first_row], state, mask
+
+
+def yarn_inv_freq(dim: int, theta: float, scaling: Optional[dict]):
+    """Rotary frequencies; with YaRN `scaling` (`factor`,
+    `original_max_position_embeddings`, `beta_fast`, `beta_slow`) those that
+    turn fewer than `beta_slow` times over the original window are divided by
+    `factor`, those over `beta_fast` kept, a linear ramp between."""
+    pos = theta ** (jnp.arange(0, dim, 2, dtype=_F32) / dim)
+    if not scaling or float(scaling.get("factor", 1)) <= 1:
+        return 1.0 / pos
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+
+    def correction(rotations):
+        return dim * math.log(orig / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction(scaling.get("beta_fast", 32))), 0)
+    high = min(math.ceil(correction(scaling.get("beta_slow", 1))), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=_F32) - low) / (high - low), 0.0, 1.0)
+    return (1.0 / (factor * pos)) * ramp + (1.0 / pos) * (1.0 - ramp)
+
+
+def apply_rope(x, inv_freq):
+    """x: (batch, time, heads, dim), rotated in float32, the half-split
+    pairing (entry i with entry i + dim/2)."""
+    t = jnp.arange(x.shape[1], dtype=_F32)
+    ang = t[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    x1, x2 = jnp.split(x.astype(_F32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+@register_layer
+@dataclass
+class LatentAttention(_TokenLayer):
+    """Multi-head latent attention (DeepSeek-V2/V3), causal: queries through a
+    `q_lora_rank` latent, keys and values through a `kv_lora_rank` latent with
+    a rotary key of `qk_rope_head_dim` shared by all heads; heads of
+    `qk_nope_head_dim + qk_rope_head_dim` against values of `v_head_dim`. In
+    training it is its low-rank products and an attention whose QK width
+    differs from its V width. `heads_held` of the `n_heads` published heads
+    are computed here (0: all); the output is their part of the sum."""
+    n_heads: int = 32
+    heads_held: int = 0
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 10000.0
+    rope_scaling: Optional[dict] = None
+    eps: float = 1e-6
+
+    @property
+    def heads(self) -> int:
+        return self.heads_held or self.n_heads
+
+    @property
+    def softmax_scale(self) -> float:
+        rs = self.rope_scaling or {}
+        m = 1.0
+        if float(rs.get("factor", 1)) > 1:
+            m = 0.1 * rs.get("mscale_all_dim", 0) * math.log(rs["factor"]) + 1.0
+        return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5 * m * m
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        d, h = self.n_in, self.heads
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        shapes = {"w_qa": (d, self.q_lora_rank), "w_qb": (self.q_lora_rank, h * qk),
+                  "w_kva": (d, self.kv_lora_rank + self.qk_rope_head_dim),
+                  "w_kvb": (self.kv_lora_rank,
+                            h * (self.qk_nope_head_dim + self.v_head_dim)),
+                  "w_o": (h * self.v_head_dim, self.n_out)}
+        keys = jax.random.split(key, len(shapes))
+        p = {name: self._winit(k, s, s[0], s[1], dtype)
+             for k, (name, s) in zip(keys, shapes.items())}
+        p["q_norm_g"] = jnp.ones((self.q_lora_rank,), dtype)
+        p["kv_norm_g"] = jnp.ones((self.kv_lora_rank,), dtype)
+        return p
+
+    def _attend(self, q, k, v):
+        """(B, H, T, qk), (B, H, T, qk), (B, H, T, v) -> (B, H, T, v)."""
+        t = q.shape[2]
+        from deeplearning4j_tpu.ops.helpers import (
+            helpers_enabled_for, registered_helpers)
+        if t > _DENSE_ATTENTION_MAX_T \
+                and "flash_attention" in registered_helpers() \
+                and helpers_enabled_for("flash_attention"):
+            from deeplearning4j_tpu.ops.flash_attention import flash_attention
+            # the kernel wants one width: V is padded to QK's with zeros,
+            # whose columns of the result are dropped (PERF.md: what it costs)
+            pad = q.shape[-1] - v.shape[-1]
+            vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad)))
+            out = flash_attention(q, k, vp, None, True, self.softmax_scale)
+            return out[..., :v.shape[-1]]
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                            preferred_element_type=_F32) * self.softmax_scale
+        scores = jnp.where(jnp.tril(jnp.ones((t, t), bool)), scores, -jnp.inf)
+        attn = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+        return jnp.einsum("bhqk,bhkd->bhqd", attn, v)
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        b, t, _ = x.shape
+        h, nope, rd = self.heads, self.qk_nope_head_dim, self.qk_rope_head_dim
+        c_q = rms_norm(x @ params["w_qa"], params["q_norm_g"], self.eps)
+        q = (c_q @ params["w_qb"]).reshape(b, t, h, nope + rd)
+        kva = x @ params["w_kva"]
+        c_kv = rms_norm(kva[..., :self.kv_lora_rank], params["kv_norm_g"], self.eps)
+        kv = (c_kv @ params["w_kvb"]).reshape(b, t, h, nope + self.v_head_dim)
+        inv_freq = yarn_inv_freq(rd, self.rope_theta, self.rope_scaling)
+        q_rope = apply_rope(q[..., nope:], inv_freq)
+        k_rope = apply_rope(kva[..., self.kv_lora_rank:].reshape(b, t, 1, rd),
+                            inv_freq)
+        q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+        k = jnp.concatenate([kv[..., :nope],
+                             jnp.broadcast_to(k_rope, (b, t, h, rd))], axis=-1)
+        heads_first = lambda a: jnp.swapaxes(a, 1, 2)
+        out = self._attend(heads_first(q), heads_first(k),
+                           heads_first(kv[..., nope:]))
+        out = jnp.swapaxes(out, 1, 2).reshape(b, t, h * self.v_head_dim)
+        return out @ params["w_o"], state, mask
+
+
+def _gated(x, w_g, w_u, w_d):
+    return (jax.nn.silu(x @ w_g) * (x @ w_u)) @ w_d
+
+
+@register_layer
+@dataclass
+class GatedMLP(_TokenLayer):
+    """SwiGLU: (silu(x W_g) * x W_u) W_d, no biases."""
+    width: int = 0
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        kg, ku, kd = jax.random.split(key, 3)
+        d, f = self.n_in, self.width
+        return {"w_g": self._winit(kg, (d, f), d, f, dtype),
+                "w_u": self._winit(ku, (d, f), d, f, dtype),
+                "w_d": self._winit(kd, (f, self.n_out), f, self.n_out, dtype)}
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        return _gated(x, params["w_g"], params["w_u"], params["w_d"]), state, mask
+
+
+@register_layer
+@dataclass
+class RoutedExperts(_TokenLayer):
+    """Dropless routed experts beside shared ones (DeepSeek-V3's `noaux_tc`
+    gate): sigmoid scores over the `n_experts` published experts, the `top_k`
+    largest of score + bias chosen, their scores normalised and scaled. The
+    layer holds experts `[first_expert, first_expert + experts_held)` (all
+    where `experts_held` is 0) and adds their part for the tokens routed to
+    them: the assignments are sorted by expert and the three products of the
+    held experts are one grouped product each over those rows
+    (`ops/grouped_matmul.py`), with no capacity and no dropped token.
+
+    The selection bias is a buffer (`state["router_bias"]`, no gradient leaf).
+    The state also holds, written on the device by every step, the tokens each
+    held expert took (`expert_load`) and the assignments that fell to absent
+    experts (`assignments_absent`)."""
+    n_experts: int = 64
+    experts_held: int = 0
+    first_expert: int = 0
+    top_k: int = 4
+    width: int = 1024
+    n_shared: int = 1
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.n_experts
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        d, f, e = self.n_in, self.width, self.held
+        keys = jax.random.split(key, 7)
+        p = {"w_r": self._winit(keys[0], (d, self.n_experts), d, self.n_experts, dtype),
+             "e_w_g": self._winit(keys[1], (e, d, f), d, f, dtype),
+             "e_w_u": self._winit(keys[2], (e, d, f), d, f, dtype),
+             "e_w_d": self._winit(keys[3], (e, f, self.n_out), f, self.n_out, dtype)}
+        if self.n_shared:
+            fs = f * self.n_shared
+            p.update({"s_w_g": self._winit(keys[4], (d, fs), d, fs, dtype),
+                      "s_w_u": self._winit(keys[5], (d, fs), d, fs, dtype),
+                      "s_w_d": self._winit(keys[6], (fs, self.n_out), fs,
+                                           self.n_out, dtype)})
+        return p
+
+    def init_state(self, input_type, dtype=jnp.float32):
+        return {"router_bias": jnp.zeros((self.n_experts,), dtype),
+                "expert_load": jnp.zeros((self.held,), jnp.int32),
+                "assignments_absent": jnp.zeros((), jnp.int32)}
+
+    def route(self, params, state, u):
+        """u (N, d) -> (chosen experts (N, k), their weights (N, k) float32)."""
+        s = jax.nn.sigmoid(jnp.dot(u, params["w_r"], preferred_element_type=_F32))
+        _, sel = lax.top_k(s + state["router_bias"].astype(_F32), self.top_k)
+        w = jnp.take_along_axis(s, sel, axis=-1)
+        if self.norm_topk_prob:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        return sel, w * self.routed_scaling_factor
+
+    def _routed(self, params, u, sel, w):
+        """The held experts' part: (N, d), the tokens each took (held,)."""
+        from deeplearning4j_tpu.ops.grouped_matmul import grouped_matmul
+        n, e = u.shape[0], self.held
+        local = sel.reshape(-1) - self.first_expert            # (N*k,)
+        group = jnp.where((local >= 0) & (local < e), local, e)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=e + 1)[:e].astype(jnp.int32)
+        rows = u[order // self.top_k]                          # sorted by expert
+        hidden = jax.nn.silu(grouped_matmul(rows, params["e_w_g"], sizes)) \
+            * grouped_matmul(rows, params["e_w_u"], sizes)
+        out = grouped_matmul(hidden, params["e_w_d"], sizes)
+        out = out * w.reshape(-1)[order][:, None].astype(out.dtype)
+        back = jnp.argsort(order)                              # sorted -> (token, k)
+        return out[back].reshape(n, self.top_k, -1).sum(axis=1), sizes
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        b, t, d = x.shape
+        u = x.reshape(b * t, d)
+        sel, w = self.route(params, state, u)
+        with jax.named_scope("routed"):
+            y, sizes = self._routed(params, u, sel, w)
+        if self.n_shared:
+            with jax.named_scope("shared"):
+                y = y + _gated(u, params["s_w_g"], params["s_w_u"], params["s_w_d"])
+        new_state = dict(state, expert_load=sizes,
+                         assignments_absent=(b * t * self.top_k
+                                             - jnp.sum(sizes)).astype(jnp.int32))
+        return y.reshape(b, t, -1), new_state, mask
+
+    def state_gauges(self, state) -> dict:
+        """What `fit_on_device` publishes of this layer's state after a call
+        (host values): how uneven the held experts' load was in the last step
+        and the share of all assignments that fell to a held expert."""
+        load = [float(v) for v in state["expert_load"]]
+        held, absent = sum(load), float(state["assignments_absent"])
+        if held <= 0:
+            return {}
+        return {"moe.expert_load.max_over_mean": max(load) / (held / len(load)),
+                "moe.assignments_held_share": held / (held + absent)}
+
+
+def sinkhorn(logits, iters: int, eps: float):
+    """logits (n, n, ...): exp, then `iters` rounds of column then row
+    normalisation over the two leading axes: matrices that are nearly doubly
+    stochastic, one for each entry of the trailing axes (the tokens, which
+    stay the minor axis: the sums are additions of whole slabs, no
+    reduction over a 4-wide minor axis)."""
+    n = logits.shape[0]
+
+    def one_round(mat, _):
+        col = sum(mat[i] for i in range(n))                 # (n, ...): by column
+        mat = mat / (col[None] + eps)
+        row = sum(mat[:, j] for j in range(n))              # (n, ...): by row
+        return mat / (row[:, None] + eps), None
+
+    # a scan, not 2 x iters unrolled divisions a layer: the program stays small
+    return lax.scan(one_round, jnp.exp(logits), None, length=iters)[0]
+
+
+_HC_KEYS = ("hc_phi_pre", "hc_phi_post", "hc_phi_res", "hc_a", "hc_b_pre",
+            "hc_b_post", "hc_b_res", "norm_g")
+
+
+@register_layer
+@dataclass
+class HyperConnection(BaseLayerConf):
+    """Manifold-constrained hyper-connection (mHC, arXiv:2512.24880) around a
+    sublayer F: the residual state is `n_streams` streams, X (batch, n,
+    time, d). Per token, from x' = RMSNorm(vec(X)): H_pre = sigmoid(a_pre x'
+    phi_pre + b_pre) (1 x n), H_post = 2 sigmoid(a_post x' phi_post + b_post)
+    (1 x n), H_res = Sinkhorn(clip(a_res mat(x' phi_res) + b_res)) (n x n);
+    X_next = H_res X + H_post^T F(RMSNorm(H_pre X)). The maps, Sinkhorn and
+    the mixing are worked out in float32 whatever the compute type, with the
+    tokens as the minor axis of every map (n and n x n lead) and the mixing
+    written out stream by stream: elementwise work that XLA fuses, where a
+    (tokens, n, n) layout pads every 4 x 4 matrix to a whole tile."""
+    layer: Optional[BaseLayerConf] = None
+    n_streams: int = 4
+    sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    clamp_min: float = -30.0
+    clamp_max: float = 30.0
+    eps: float = 1e-6
+
+    def __post_init__(self):
+        if isinstance(self.layer, dict):
+            self.layer = BaseLayerConf.from_dict(self.layer)
+
+    def set_n_in(self, input_type, override=False):
+        self.layer.set_n_in(self._inner_type(input_type), override)
+
+    def _inner_type(self, input_type):
+        return InputType.recurrent(input_type.size // self.n_streams,
+                                   _time_of(input_type))
+
+    def get_output_type(self, input_type):
+        return input_type
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        k_in, k1, k2, k3 = jax.random.split(key, 4)
+        n, inner = self.n_streams, self._inner_type(input_type)
+        d = inner.size
+        p = dict(self.layer.init_params(k_in, inner, dtype))
+        w = lambda k, cols: self._winit(k, (n * d, cols), n * d, cols, dtype)
+        p.update({"hc_phi_pre": w(k1, n), "hc_phi_post": w(k2, n),
+                  "hc_phi_res": w(k3, n * n),
+                  "hc_a": jnp.full((3,), _HC_MAP_SCALE_INIT, dtype),
+                  "hc_b_pre": jnp.zeros((n,), dtype),
+                  "hc_b_post": jnp.zeros((n,), dtype),
+                  "hc_b_res": _HC_RES_DIAGONAL_INIT * jnp.eye(n, dtype=dtype),
+                  "norm_g": jnp.ones((d,), dtype)})
+        return p
+
+    def init_state(self, input_type, dtype=jnp.float32):
+        return self.layer.init_state(self._inner_type(input_type), dtype)
+
+    def maps(self, params, x):
+        """X (B, n, T, d) -> H_pre (n, B, T), H_post (n, B, T), H_res (n, n, B, T),
+        float32."""
+        n, d = self.n_streams, x.shape[-1]
+        phi = jnp.concatenate([params["hc_phi_pre"], params["hc_phi_post"],
+                               params["hc_phi_res"]], axis=1).astype(x.dtype)
+        raw = sum(jnp.einsum("btd,dc->cbt", x[:, j], phi[j * d:(j + 1) * d],
+                             preferred_element_type=_F32) for j in range(n))
+        square = sum(jnp.mean(jnp.square(x[:, j].astype(_F32)), axis=-1)
+                     for j in range(n)) / n
+        # RMSNorm without a gain is one factor a token: it goes after the product
+        raw = raw * lax.rsqrt(square + self.eps)[None]
+        a = params["hc_a"].astype(_F32)
+        bias = lambda k: params[k].astype(_F32)[..., None, None]
+        h_pre = jax.nn.sigmoid(a[0] * raw[:n] + bias("hc_b_pre"))
+        h_post = 2.0 * jax.nn.sigmoid(a[1] * raw[n:2 * n] + bias("hc_b_post"))
+        res = a[2] * raw[2 * n:].reshape((n, n) + raw.shape[1:]) + bias("hc_b_res")
+        res = jnp.clip(res, self.clamp_min, self.clamp_max)
+        return h_pre, h_post, sinkhorn(res, self.sinkhorn_iters, self.hc_eps)
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        n = self.n_streams
+        inner = {k: v for k, v in params.items() if k not in _HC_KEYS}
+        h_pre, h_post, h_res = self.maps(params, x)
+        streams = [x[:, j].astype(_F32) for j in range(n)]
+        u = sum(h_pre[j][..., None] * streams[j] for j in range(n))
+        u = rms_norm(u, params["norm_g"], self.eps).astype(x.dtype)
+        with layer_scope(self.layer, self.name):
+            y, new_state, mask = self.layer.forward(inner, state, u, train=train,
+                                                    rng=rng, mask=mask)
+        y = y.astype(_F32)
+        out = [sum(h_res[i, j][..., None] * streams[j] for j in range(n))
+               + h_post[i][..., None] * y for i in range(n)]
+        return jnp.stack(out, axis=1).astype(x.dtype), new_state, mask
+
+    def state_gauges(self, state) -> dict:
+        inner = getattr(self.layer, "state_gauges", None)
+        return inner(state) if inner else {}
+
+
+@register_layer
+@dataclass
+class MTPInput(_TokenLayer):
+    """The multi-token-prediction module's input (DeepSeek-V3 section 2.2):
+    from [Emb[t_{i+1}]; h_i] (batch, time, 2d), the concatenation of the next
+    token's embedding and the main model's state,
+    h'_i = W [RMSNorm(Emb[t_{i+1}]); RMSNorm(h_i)] (2d -> d)."""
+    eps: float = 1e-6
+
+    def set_n_in(self, input_type, override=False):
+        if self.n_in == 0 or override:
+            self.n_in = input_type.size
+        if self.n_out == 0:
+            self.n_out = self.n_in // 2
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        d = self.n_in // 2
+        return {"norm_e_g": jnp.ones((d,), dtype), "norm_h_g": jnp.ones((d,), dtype),
+                "W": self._winit(key, (self.n_in, self.n_out), self.n_in,
+                                 self.n_out, dtype)}
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        d = self.n_in // 2
+        both = jnp.concatenate(
+            [rms_norm(x[..., :d], params["norm_e_g"], self.eps),
+             rms_norm(x[..., d:], params["norm_h_g"], self.eps)], axis=-1)
+        return both @ params["W"], state, mask
+
+
+@register_layer
+@dataclass
+class TokenCrossEntropyHead(FeedForwardLayerConf):
+    """Untied output head with softmax cross-entropy over integer labels
+    (batch, time): no one-hot array is ever made. `n_out` is the published
+    vocabulary; the head keeps columns `[first_row, first_row + rows_held)`
+    of it (all where `rows_held` is 0) and labels, logits and loss are over
+    those. `shift` s scores position i against label i + s and leaves the
+    last s positions out (the MTP module, which has read label i already,
+    predicts the one after). `loss_weight` scales this head's loss in the
+    graph's sum."""
+    rows_held: int = 0
+    first_row: int = 0
+    shift: int = 0
+    loss_weight: float = 1.0
+    integer_labels = True      # the walk hands it the labels as they are
+
+    @property
+    def rows(self) -> int:
+        return self.rows_held or self.n_out
+
+    def set_n_in(self, input_type, override=False):
+        if self.n_in == 0 or override:
+            self.n_in = input_type.size
+
+    def get_output_type(self, input_type):
+        return InputType.recurrent(self.rows, _time_of(input_type))
+
+    def is_output_layer(self) -> bool:
+        return True
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        return {"W": self._winit(key, (self.n_in, self.rows), self.n_in,
+                                 self.n_out, dtype)}
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        return x @ params["W"], state, mask
+
+    def compute_score(self, params, x, labels, mask=None):
+        """Mean over the scored positions of -log softmax(x W)[label]."""
+        with layer_scope(self, self.name):
+            return self._score(params, x, labels, mask)
+
+    def _score(self, params, x, labels, mask):
+        labels = labels.astype(jnp.int32) - self.first_row
+        if self.shift:
+            x, labels = x[:, :-self.shift], labels[:, self.shift:]
+            mask = None if mask is None else mask[:, self.shift:]
+        logits = jnp.dot(x, params["W"], preferred_element_type=_F32)
+        picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+        nll = jax.nn.logsumexp(logits, axis=-1) - picked
+        if mask is None:
+            return jnp.mean(nll)
+        m = mask.astype(nll.dtype)
+        return jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1.0)

@@ -40,6 +40,22 @@ def _as_list(x) -> List:
     return [x]
 
 
+def _integer_ports(conf: ComputationGraphConfiguration):
+    """(for each graph input, for each output) whether the batch array stays
+    integer: an input all of whose consumers are layers that declare
+    `integer_input` (a token table), an output layer that declares
+    `integer_labels`. Every other array is cast to the storage type, whatever
+    it comes as (uint8 images, integer one-hot labels)."""
+    def consumers(name):
+        return [n.conf for n in conf.nodes.values() if name in n.inputs]
+    ins = tuple(bool(consumers(name)) and all(
+        getattr(c, "integer_input", False) for c in consumers(name))
+        for name in conf.inputs)
+    outs = tuple(bool(getattr(conf.nodes[name].conf, "integer_labels", False))
+                 for name in conf.outputs)
+    return ins, outs
+
+
 class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
     def __init__(self, conf: ComputationGraphConfiguration):
         self.conf = conf
@@ -58,6 +74,7 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         self._train_step_fn = None
         self._accumulator = None
         self._last_etl_ms = 0.0
+        self._int_inputs, self._int_labels = _integer_ports(conf)
         self.dtype = jnp.dtype(conf.global_conf.dtype)
         gc = conf.global_conf
         self.compute_dtype = (jnp.dtype(gc.compute_dtype)
@@ -76,6 +93,8 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
             it = in_types[name][0]
             if params is not None:
                 p = {k: jnp.array(v, copy=True) for k, v in params[idx].items()}
+            elif self.conf.nodes[name].tied_to is not None:
+                p = {}           # the owner's copy is the only one
             else:
                 p = layer.init_params(sub, it, self.dtype) if layer.has_params() else {}
             self.params_tree.append(p)
@@ -93,6 +112,8 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
             else:
                 self._updaters.append(global_updater)
         self._opt_state = [u.init(p) for u, p in zip(self._updaters, self.params_tree)]
+        self._gauge_layers = [i for i, l in enumerate(self.layers)
+                              if hasattr(l, "state_gauges")]
         self._initialized = True
         self._train_step_fn = None
         self._output_jit = None
@@ -183,7 +204,11 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         cd = self.compute_dtype
         mixed = cd != self.dtype
         params_full = params_tree  # storage-dtype originals (score + regularization)
-        if mixed:
+        # with recomputation by layer the cast goes inside the recomputed
+        # block: no second copy of every weight lives through the step
+        remat = bool(self.conf.global_conf.remat) and train
+        cast_inside = mixed and remat
+        if mixed and not cast_inside:
             params_tree = _cast_params(self.layers, self.layer_names,
                                        params_tree, cd)
         nodes = self.conf.nodes
@@ -192,6 +217,8 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         masks: Dict[str, Optional[jnp.ndarray]] = dict(zip(self.conf.inputs, fmasks))
         new_states = [None] * len(self.layer_names)
         layer_idx = {n: i for i, n in enumerate(self.layer_names)}
+        # where a node's parameters live: its own slot, or its owner's
+        param_idx = {n: layer_idx[nodes[n].tied_to or n] for n in self.layer_names}
         label_map = {}
         lmask_map = {}
         if labels is not None:
@@ -263,9 +290,10 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                 values[name], masks[name] = out, m
                 continue
             layer = node.conf
-            i = layer_idx[name]
+            i, pi = layer_idx[name], param_idx[name]
             cur, mask = in_vals[0], in_masks[0]
-            if mixed and not isinstance(layer, EmbeddingLayer):
+            if mixed and not isinstance(layer, EmbeddingLayer) \
+                    and not getattr(layer, "integer_input", False):
                 with _layer_scope(layer, name):
                     cur = cur.astype(cd)
             if node.preprocessor is not None:
@@ -282,15 +310,18 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                 if lm is None and mask is not None and cur.ndim == 3:
                     lm = mask
                 # output-layer matmul + loss in storage dtype for stability
+                score = jax.checkpoint(layer.compute_score) if remat \
+                    else layer.compute_score
                 with jax.named_scope("dl4j.loss"):
-                    total_loss = total_loss + layer.compute_score(
-                        params_full[i], cur.astype(self.dtype),
-                        label_map[name], lm)
+                    total_loss = total_loss \
+                        + getattr(layer, "loss_weight", 1.0) * score(
+                            params_full[pi], cur.astype(self.dtype),
+                            label_map[name], lm)
                 new_states[i] = state_tree[i]
                 # still produce activation in case downstream nodes consume it
                 with _layer_scope(layer, name):
                     out, ns, m = layer.forward(
-                        params_tree[i], state_tree[i], cur, train=train,
+                        params_tree[pi], state_tree[i], cur, train=train,
                         rng=lrng, mask=mask)
                 values[name], masks[name] = out, m
             else:
@@ -303,7 +334,8 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                     init = rnn_init_states[len(final_rnn)]
                     with _layer_scope(layer, name):
                         out, (h, c) = layer._scan(
-                            params_tree[i], cur, mask,
+                            cast_floats(params_tree[pi], cd) if cast_inside
+                            else params_tree[pi], cur, mask,
                             h0=None if init is None else init[0],
                             c0=None if init is None else init[1])
                     final_rnn.append((h, c))
@@ -311,10 +343,19 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                 else:
                     if isinstance(layer, _LSTM):
                         final_rnn.append(None)
+
+                    def fwd(p, s, c, r, m, _layer=layer):
+                        if cast_inside:
+                            p = cast_floats(p, cd)
+                        return _layer.forward(p, s, c, train=train, rng=r, mask=m)
+
+                    if remat:
+                        # the layer's activations are dropped and recomputed
+                        # in the backward pass (MultiLayerNetwork._loss_fn)
+                        fwd = jax.checkpoint(fwd)
                     with _layer_scope(layer, name):
-                        out, ns, m = layer.forward(
-                            params_tree[i], state_tree[i], cur, train=train,
-                            rng=lrng, mask=mask)
+                        out, ns, m = fwd(params_tree[pi], state_tree[i], cur,
+                                         lrng, mask)
                 new_states[i] = ns
                 values[name], masks[name] = out, m
         if mixed:
@@ -330,7 +371,7 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         self._check_init()
         if len(inputs) == 1 and isinstance(inputs[0], (list, tuple)):
             inputs = tuple(inputs[0])  # output([a, b]) == output(a, b)
-        ins = tuple(jnp.asarray(x, self.dtype) for x in inputs)
+        ins = self._arrays(inputs, self._int_inputs)
         if train:
             values, _, _ = self._forward_all(self.params_tree, self.state_tree,
                                              list(ins), train=True)
@@ -349,7 +390,7 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
     def feed_forward(self, *inputs, train: bool = False) -> Dict[str, jnp.ndarray]:
         """All node activations by name."""
         self._check_init()
-        ins = [jnp.asarray(x, self.dtype) for x in inputs]
+        ins = list(self._arrays(inputs, self._int_inputs))
         values, _, _ = self._forward_all(self.params_tree, self.state_tree, ins,
                                          train=train)
         return values
@@ -427,8 +468,8 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
     def _fit_batch(self, x, y, fmask, lmask, rnn_init_states):
         step = self._step
         with _telemetry.span("dl4j.fit_batch.prepare", step=step):
-            x = tuple(jnp.asarray(v, self.dtype) for v in _as_list(x))
-            y = tuple(jnp.asarray(v, self.dtype) for v in _as_list(y))
+            x = self._arrays(_as_list(x), self._int_inputs)
+            y = self._arrays(_as_list(y), self._int_labels)
             fmask = None if fmask is None else tuple(_as_list(fmask))
             lmask = None if lmask is None else tuple(_as_list(lmask))
             if self._train_step_fn is None:
@@ -483,8 +524,8 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         with _telemetry.span("dl4j.fit_on_device", step=step, steps=steps,
                              model="cg"):
             with _telemetry.span("dl4j.fit_on_device.prepare", step=step):
-                x = tuple(jnp.asarray(v, self.dtype) for v in _as_list(x))
-                y = tuple(jnp.asarray(v, self.dtype) for v in _as_list(y))
+                x = self._arrays(_as_list(x), self._int_inputs)
+                y = self._arrays(_as_list(y), self._int_labels)
                 run = self._get_device_loop(vary_batch)
                 self._rng, sub = jax.random.split(self._rng)
                 args = _device_loop_args(self, sub, x, y, fmask, lmask)
@@ -508,8 +549,10 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                 self._score = losses[-1]  # device scalar; host sync deferred
                 return losses             # divergence resolves on _diverged_at
             with _telemetry.span("dl4j.fit_on_device.readback", step=step):
-                losses, div = jax.device_get(
-                    (losses, self._pending_div))  # ONE readback
+                losses, div, gauge_states = jax.device_get(
+                    (losses, self._pending_div,
+                     [self.state_tree[i] for i in self._gauge_layers]))  # ONE readback
+            self._publish_state_gauges(gauge_states)
             if warm:
                 # warm + sync only: compile excluded, readback already paid for
                 _profiler.observe("train_step", (time.perf_counter() - t_run)
@@ -517,6 +560,16 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
             self._score = float(losses[-1])
             self._resolve_divergence(int(div))
             return losses
+
+    def _publish_state_gauges(self, states) -> None:
+        """Gauges of the layers that keep counters in their state (written on
+        the device by every step, read with the call's losses): the last
+        step's, by the layer's name."""
+        for i, state in zip(self._gauge_layers, states):
+            for gauge, value in self.layers[i].state_gauges(state).items():
+                _telemetry.registry().gauge(
+                    f"{gauge}.{_telemetry.sanitize_component(self.layer_names[i])}"
+                ).set(value)
 
     def _get_device_loop(self, vary_batch: bool = False):
         """Build (or fetch from cache) the jitted scan loop used by fit_on_device /
@@ -618,9 +671,18 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         return self._train_step_fn.lower(*_train_step_args(
             self, self._rng, *self._abstract_batch(x, y), None, None, None))
 
+    def _arrays(self, values, integer) -> tuple:
+        """Batch arrays as the net takes them: each in the storage type, but
+        for the ports whose layer declared integer ids or labels."""
+        return tuple(jnp.asarray(v, jnp.int32 if keep else self.dtype)
+                     for v, keep in zip(values, integer))
+
     def _abstract_batch(self, x, y):
-        return tuple(tuple(_abstract(v, self.dtype) for v in _as_list(a))
-                     for a in (x, y))
+        """Shapes as `fit_on_device` would pass them."""
+        return tuple(tuple(_abstract(v, jnp.int32 if keep else self.dtype)
+                           for v, keep in zip(_as_list(a), integer))
+                     for a, integer in ((x, self._int_inputs),
+                                        (y, self._int_labels)))
 
     def train_step_flops(self, x, y) -> Optional[float]:
         """XLA cost-analysis FLOPs of ONE fit_on_device training step (see

@@ -65,10 +65,12 @@ def _first_mask(masks):
 @dataclass
 class MergeVertex(GraphVertex):
     """Concatenate along the feature/channel axis (axis 1 in NC*/NCHW/NCT layouts)
-    (ref nn/graph/vertex/impl/MergeVertex.java)."""
+    (ref nn/graph/vertex/impl/MergeVertex.java); `axis` -1 for the decoder
+    layers' (batch, time, features)."""
+    axis: int = 1
 
     def forward(self, inputs, masks):
-        return jnp.concatenate(inputs, axis=1), _first_mask(masks)
+        return jnp.concatenate(inputs, axis=self.axis), _first_mask(masks)
 
     def get_output_type(self, input_types):
         t0 = input_types[0]
@@ -289,3 +291,26 @@ class DuplicateToTimeSeriesVertex(GraphVertex):
     def get_output_type(self, input_types):
         t = input_types[1].timeseries_length if len(input_types) > 1 else -1
         return InputType.recurrent(input_types[0].size, t)
+
+
+@register_vertex
+@dataclass
+class HyperStreamsVertex(GraphVertex):
+    """The ends of a hyper-connected stack (nn/conf/layers/decoder.py):
+    `expand` copies (batch, time, d) into `n_streams` residual streams
+    (batch, n, time, d); `sum` adds the streams up again."""
+    n_streams: int = 4
+    mode: str = "expand"
+
+    def forward(self, inputs, masks):
+        x = inputs[0]
+        if self.mode == "expand":
+            return jnp.broadcast_to(x[:, None], (x.shape[0], self.n_streams)
+                                    + x.shape[1:]), masks[0]
+        return jnp.sum(x, axis=1), masks[0]
+
+    def get_output_type(self, input_types):
+        t = input_types[0]
+        size = t.size * self.n_streams if self.mode == "expand" \
+            else t.size // self.n_streams
+        return InputType.recurrent(size, t.timeseries_length)

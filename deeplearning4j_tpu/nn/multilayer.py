@@ -21,7 +21,8 @@ import numpy as np
 from deeplearning4j_tpu.common.enums import BackpropType, GradientNormalization
 from deeplearning4j_tpu.nn.conf.configuration import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.conf.input_type import InputType
-from deeplearning4j_tpu.nn.conf.layers.base import BaseLayerConf, apply_dropout
+from deeplearning4j_tpu.nn.conf.layers.base import (
+    BaseLayerConf, apply_dropout, layer_scope as _layer_scope)
 from deeplearning4j_tpu.nn.conf.layers.recurrent import LSTM
 from deeplearning4j_tpu.nn.divergence import DivergenceSentinelMixin
 from deeplearning4j_tpu import telemetry as _telemetry
@@ -33,12 +34,6 @@ from deeplearning4j_tpu.util.flat_params import flatten_params, num_params, unfl
 
 
 _telemetry.count_compiles()   # dl4j.compile.* counters, from here on
-
-
-def _layer_scope(layer, name) -> "jax.named_scope":
-    """The name a layer's or vertex's operations carry in the compiled
-    program: `dl4j.<its class>/<its name>` (telemetry.profiler.op_scopes)."""
-    return jax.named_scope(f"dl4j.{type(layer).__name__}/{name}")
 
 
 def _cast_params(layers, names, params_tree, dtype):
@@ -316,9 +311,13 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         cd = self.compute_dtype
         mixed = cd != self.dtype
         params_full = params_tree  # storage-dtype originals (score + regularization)
+        # with recomputation by layer the cast goes inside the recomputed
+        # block: no second copy of every weight lives through the step
+        cast_inside = mixed and bool(self.conf.global_conf.remat)
         if mixed:
-            params_tree = _cast_params(self.layers, range(len(self.layers)),
-                                       params_tree, cd)
+            if not cast_inside:
+                params_tree = _cast_params(self.layers, range(len(self.layers)),
+                                           params_tree, cd)
             if rnn_init_states is not None:
                 rnn_init_states = cast_floats(rnn_init_states, cd)
         # forward to input of the output layer
@@ -351,7 +350,8 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                 init = rnn_init_states[len(final_rnn)]
                 with _layer_scope(layer, i):
                     cur, (h, c) = layer._scan(
-                        params_tree[i], cur, mask,
+                        cast_floats(params_tree[i], cd) if cast_inside
+                        else params_tree[i], cur, mask,
                         h0=None if init is None else init[0],
                         c0=None if init is None else init[1])
                 final_rnn.append((h, c))
@@ -365,6 +365,8 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                     final_rnn.append(None)
 
                 def fwd(p, s, c, r, m, _layer=layer):
+                    if cast_inside:
+                        p = cast_floats(p, cd)
                     return _layer.forward(p, s, c, train=train, rng=r, mask=m)
 
                 if self.conf.global_conf.remat:

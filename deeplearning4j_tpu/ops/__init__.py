@@ -13,7 +13,9 @@ from deeplearning4j_tpu.ops import conv_fused  # registers conv1x1_bn_act
 from deeplearning4j_tpu.ops import lstm_scan_fused  # registers graves_lstm_scan
 from deeplearning4j_tpu.ops import flash_attention  # registers flash_attention
 from deeplearning4j_tpu.ops import decode_attention  # registers the paged decode kernels
+from deeplearning4j_tpu.ops import grouped_matmul  # registers grouped_matmul
 
 __all__ = ["enable_helpers", "helpers_enabled", "helper_for", "register_helper",
            "registered_helpers", "pallas_kernels", "conv_fused",
-           "lstm_scan_fused", "flash_attention", "decode_attention"]
+           "lstm_scan_fused", "flash_attention", "decode_attention",
+           "grouped_matmul"]

@@ -24,6 +24,7 @@ from deeplearning4j_tpu.ops.conv_fused import conv1x1_bn_act
 from deeplearning4j_tpu.ops.decode_attention import (
     flash_decode_attention_paged, flash_decode_attention_spec_paged)
 from deeplearning4j_tpu.ops.flash_attention import flash_attention
+from deeplearning4j_tpu.ops.grouped_matmul import grouped_matmul_kernel
 from deeplearning4j_tpu.ops import lstm_scan_fused
 from deeplearning4j_tpu.ops.lstm_scan_fused import graves_lstm_scan_pallas
 from deeplearning4j_tpu.ops.pallas_kernels import (
@@ -114,6 +115,31 @@ def _decode(kernel, window=0):
     return fn
 
 
+# latent attention of the Xing4.0-29B-A4B share: 1 sequence of 4096, 4 heads
+# held, QK 192 wide against V 128 (padded to 192 inside the layer)
+MLA_QKV = [((1, 4, 4096, 192), BF16)] * 2 + [((1, 4, 4096, 128), BF16)]
+
+
+def _latent_attend(q, k, v):
+    from deeplearning4j_tpu.nn.conf.layers.decoder import LatentAttention
+    with helpers.helpers_enabled_ctx(True):
+        return LatentAttention(n_in=3584, n_out=3584, n_heads=32,
+                               heads_held=4)._attend(q, k, v)
+
+
+# the held experts' products of the same share: every assignment of 4096
+# tokens x top-4 as rows (most fall to absent experts: the groups' sum is
+# what runs), 8 experts of 3584 x 1024 and back
+ROWS = 4096 * 4
+GMM_UP = [((ROWS, 3584), BF16), ((8, 3584, 1024), BF16), ((8,), I32)]
+GMM_DOWN = [((ROWS, 1024), BF16), ((8, 1024, 3584), BF16), ((8,), I32)]
+
+
+def _gmm_grad(x, w, sizes):
+    return jax.grad(lambda x_, w_: _sum(grouped_matmul_kernel(x_, w_, sizes)),
+                    argnums=(0, 1))(x, w)
+
+
 GATES = [((B, 4 * H), BF16), ((B, H), BF16)]
 PEEPS = [((H,), BF16)] * 3
 
@@ -126,6 +152,12 @@ CASES = {
     "flash_attention bwd two_pass": (_grad(_flash(bwd="two_pass"), 3), QKV),
     "flash_attention bwd two_pass window=1024":
         (_grad(_flash(1024, "two_pass"), 3), QKV),
+    "flash_attention qk192 v128 (latent attention)": (_latent_attend, MLA_QKV),
+    "flash_attention qk192 v128 bwd": (_grad(_latent_attend, 3), MLA_QKV),
+    "grouped_matmul up": (grouped_matmul_kernel, GMM_UP),
+    "grouped_matmul down": (grouped_matmul_kernel, GMM_DOWN),
+    "grouped_matmul bwd": (_gmm_grad, GMM_UP),
+    "grouped_matmul down bwd": (_gmm_grad, GMM_DOWN),
     "graves_lstm_scan": (graves_lstm_scan_pallas, SCAN),
     "graves_lstm_scan bwd": (_grad(graves_lstm_scan_pallas, 8), SCAN),
     "graves_lstm_scan bwd cs unused": (_grad(_scan_ys_only, 8), SCAN),
