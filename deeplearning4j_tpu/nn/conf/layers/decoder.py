@@ -22,6 +22,7 @@ left out. On one chip a layer runs without its exchange.
 """
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Optional
@@ -261,6 +262,119 @@ class GatedMLP(_TokenLayer):
         return _gated(x, params["w_g"], params["w_u"], params["w_d"]), state, mask
 
 
+@jax.custom_vjp
+def _rows_of_tokens(x, token, place, live):
+    """x (N, d) -> (M, d), row p the row `token[p]` of x. (`place`, `live`)
+    say the same the other way round, for the gradient: the j-th of token
+    n's k assignments sits at row `place[j, n]` of the block where
+    `live[j, n]`, and is not in it elsewhere."""
+    return x[token]
+
+
+@jax.custom_vjp
+def _sum_to_tokens(rows, token, place, live):
+    """rows (M, d) -> (N, d), row n the sum of the block's rows that are
+    token n's: `_rows_of_tokens` transposed, as k gathers of N rows through
+    the inverse permutation (XLA's scatter-add walks its rows one by one)."""
+    wide = jnp.promote_types(rows.dtype, _F32)
+    total = sum(jnp.where(live[j][:, None], rows[place[j]], 0).astype(wide)
+                for j in range(place.shape[0]))
+    return total.astype(rows.dtype)
+
+
+_rows_of_tokens.defvjp(
+    lambda x, *where: (_rows_of_tokens(x, *where), where),
+    lambda where, g: (_sum_to_tokens(g, *where), None, None, None))
+_sum_to_tokens.defvjp(
+    lambda rows, *where: (_sum_to_tokens(rows, *where), where),
+    lambda where, g: (_rows_of_tokens(g, *where), None, None, None))
+
+
+def _experts(e_w_g, e_w_u, e_w_d, rows, sizes):
+    """rows sorted by held expert (M, d) -> their experts' answers (M, d)."""
+    from deeplearning4j_tpu.ops.grouped_matmul import grouped_matmul
+    hidden = jax.nn.silu(grouped_matmul(rows, e_w_g, sizes)) \
+        * grouped_matmul(rows, e_w_u, sizes)
+    return grouped_matmul(hidden, e_w_d, sizes)
+
+
+def _block(m, lo, u, weights, e_w_g, e_w_u, e_w_d, order, back, sizes):
+    """The held experts' part of the assignments at places [lo, lo + m) of
+    the sorted order, summed to their tokens (N, d): `m` rows gathered,
+    multiplied (each group's rows inside the block; the products leave zeros
+    in the rows past them, which add nothing) and scaled."""
+    k = back.shape[0]
+    first = lax.dynamic_slice_in_dim(order, lo, m)
+    place = back - lo
+    where = (first // k, jnp.clip(place, 0, m - 1), (place >= 0) & (place < m))
+    ends = jnp.cumsum(sizes)
+    inside = jnp.clip(ends, lo, lo + m) - jnp.clip(ends - sizes, lo, lo + m)
+    out = _experts(e_w_g, e_w_u, e_w_d, _rows_of_tokens(u, *where), inside)
+    out = out * weights[first][:, None].astype(out.dtype)
+    return _sum_to_tokens(out, *where)
+
+
+def _blocks_needed(m, sizes):
+    return (jnp.sum(sizes) + (m - 1)) // m
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _blocks(m, u, weights, e_w_g, e_w_u, e_w_d, order, back, sizes):
+    """`_block` over as many blocks of `m` rows as the held assignments
+    fill, one after the other (a loop whose length the device decides each
+    step; its gradient, below, is the same loop over each block's own)."""
+    wide = jnp.promote_types(u.dtype, _F32)
+
+    def one_more(c, y):
+        return y + _block(m, c * m, u, weights, e_w_g, e_w_u, e_w_d,
+                          order, back, sizes).astype(wide)
+    with jax.named_scope("blocks"):
+        return lax.fori_loop(0, _blocks_needed(m, sizes), one_more,
+                             jnp.zeros(u.shape, wide)).astype(u.dtype)
+
+
+def _blocks_fwd(m, *operands):
+    return _blocks(m, *operands), operands
+
+
+def _blocks_bwd(m, operands, g):
+    towards, places = operands[:5], operands[5:]
+    wide = jnp.promote_types(g.dtype, _F32)
+
+    def one_more(c, sums):
+        _, pull = jax.vjp(lambda *t: _block(m, c * m, *t, *places), *towards)
+        return tuple(s + d.astype(s.dtype) for s, d in zip(sums, pull(g)))
+    # the rows' and the router weights' sums wide, the experts' matrices'
+    # in their own type (three more float32 copies of them do not fit)
+    start = tuple(jnp.zeros(t.shape, wide if i < 2 else t.dtype)
+                  for i, t in enumerate(towards))
+    with jax.named_scope("blocks"):
+        sums = lax.fori_loop(0, _blocks_needed(m, places[2]), one_more, start)
+    return tuple(s.astype(t.dtype) for s, t in zip(sums, towards)) \
+        + (None,) * len(places)
+
+
+_blocks.defvjp(_blocks_fwd, _blocks_bwd)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _held_experts_part(first_expert, held, bound, e_w_g, e_w_u, e_w_d, u, sel, w):
+    """`RoutedExperts._routed`, traced once for all layers of one shape."""
+    n, k = sel.shape
+    local = sel.reshape(-1) - first_expert                     # (N*k,)
+    group = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    back = jnp.argsort(order).reshape(n, k).T                  # (k, N) places
+    operands = (u, w.reshape(-1), e_w_g, e_w_u, e_w_d)
+    if bound >= n * k:
+        return _block(n * k, 0, *operands, order, back, sizes), sizes
+    # the blocks tile the order: pad it to whole blocks (with any place: the
+    # padding lies past every group)
+    order = jnp.pad(order, (0, -(n * k) % bound))
+    return _blocks(bound, *operands, order, back, sizes), sizes
+
+
 @register_layer
 @dataclass
 class RoutedExperts(_TokenLayer):
@@ -272,6 +386,16 @@ class RoutedExperts(_TokenLayer):
     them: the assignments are sorted by expert and the three products of the
     held experts are one grouped product each over those rows
     (`ops/grouped_matmul.py`), with no capacity and no dropped token.
+
+    A layer that holds a share of the experts moves its rows in blocks of
+    `row_bound` (twice the share's even part, in whole tiles: 4096 of 16384
+    assignments at 4096 tokens, top 4, 8 of 64 held): a block of the sorted
+    order is gathered, multiplied and summed back to its tokens, and the
+    device goes on to the next only while held assignments are left, so a
+    usual step works on one block and a step whose router crowds the share
+    on as many as it needs, up to all N * top_k rows. The rows reach their
+    tokens, and the gradient the rows, by gathers through the sort's inverse
+    permutation; the gradient is the same loop, each block worked out again.
 
     The selection bias is a buffer (`state["router_bias"]`, no gradient leaf).
     The state also holds, written on the device by every step, the tokens each
@@ -319,21 +443,20 @@ class RoutedExperts(_TokenLayer):
             w = w / jnp.sum(w, axis=-1, keepdims=True)
         return sel, w * self.routed_scaling_factor
 
+    def row_bound(self, assignments: int) -> int:
+        """Rows a block of the routed part holds when a step has `assignments`
+        (tokens x top_k): twice the held experts' even share, in whole tiles
+        of the grouped product. At or past `assignments` (every expert held,
+        or few tokens) the part is one pass over all of them."""
+        from deeplearning4j_tpu.ops.grouped_matmul import TILE_ROWS
+        rows = -(-2 * assignments * self.held // self.n_experts)
+        return -(-rows // TILE_ROWS) * TILE_ROWS
+
     def _routed(self, params, u, sel, w):
         """The held experts' part: (N, d), the tokens each took (held,)."""
-        from deeplearning4j_tpu.ops.grouped_matmul import grouped_matmul
-        n, e = u.shape[0], self.held
-        local = sel.reshape(-1) - self.first_expert            # (N*k,)
-        group = jnp.where((local >= 0) & (local < e), local, e)
-        order = jnp.argsort(group, stable=True)
-        sizes = jnp.bincount(group, length=e + 1)[:e].astype(jnp.int32)
-        rows = u[order // self.top_k]                          # sorted by expert
-        hidden = jax.nn.silu(grouped_matmul(rows, params["e_w_g"], sizes)) \
-            * grouped_matmul(rows, params["e_w_u"], sizes)
-        out = grouped_matmul(hidden, params["e_w_d"], sizes)
-        out = out * w.reshape(-1)[order][:, None].astype(out.dtype)
-        back = jnp.argsort(order)                              # sorted -> (token, k)
-        return out[back].reshape(n, self.top_k, -1).sum(axis=1), sizes
+        return _held_experts_part(
+            self.first_expert, self.held, self.row_bound(sel.size),
+            params["e_w_g"], params["e_w_u"], params["e_w_d"], u, sel, w)
 
     def forward(self, params, state, x, *, train, rng=None, mask=None):
         b, t, d = x.shape
@@ -352,13 +475,20 @@ class RoutedExperts(_TokenLayer):
     def state_gauges(self, state) -> dict:
         """What `fit_on_device` publishes of this layer's state after a call
         (host values): how uneven the held experts' load was in the last step
-        and the share of all assignments that fell to a held expert."""
+        and the share of all assignments that fell to a held expert; where
+        the layer moves its rows in blocks, whether the last step needed one
+        alone and the held assignments over a block's rows."""
         load = [float(v) for v in state["expert_load"]]
         held, absent = sum(load), float(state["assignments_absent"])
         if held <= 0:
             return {}
-        return {"moe.expert_load.max_over_mean": max(load) / (held / len(load)),
-                "moe.assignments_held_share": held / (held + absent)}
+        gauges = {"moe.expert_load.max_over_mean": max(load) / (held / len(load)),
+                  "moe.assignments_held_share": held / (held + absent)}
+        bound = self.row_bound(int(held + absent))
+        if bound < held + absent:
+            gauges["moe.routed_rows.bounded"] = float(held <= bound)
+            gauges["moe.routed_rows.held_over_bound"] = held / bound
+        return gauges
 
 
 def sinkhorn(logits, iters: int, eps: float):

@@ -4,16 +4,20 @@
 `sum(group_sizes)` rows are sorted by group, w (G, K, N) and group_sizes (G,)
 gives (M, N): row r of group g is `x[r] @ w[g]`; rows past the groups' sum
 are zero. It is what an expert layer needs for the experts it holds: the
-work follows the rows that were routed, not a capacity.
+work follows the rows that were routed, not a capacity. M is the caller's:
+`RoutedExperts` hands over one block of `row_bound` rows of its sorted
+assignments at a time (4096 of 16384 at the decoder cell's shapes) with the
+groups' sizes inside that block, so the masks below, which run over all M
+rows whatever the groups' sum, cost a block and not every assignment.
 
 Behind the helper seam: the grouped Mosaic kernels that JAX ships
 (`jax.experimental.pallas.ops.tpu.megablox`: `gmm` for the product and for the
 gradient towards the rows, `tgmm` for the gradient towards the weights), walked
 in tiles of 128 rows, each visit of one group, the tiles past the groups' sum
 never visited, so the work follows the rows in steps of 128. At the decoder
-cell's shapes a product with both gradients takes 1.46-1.59 ms against
-1.99-2.15 for `jax.lax.ragged_dot` (tiles of 512 rows), whose kernels also
-reach the profile without the layer's scope (PERF.md, PR 27). The fallback is
+cell's shapes and M = 16384 a product with both gradients takes 1.46-1.59 ms
+against 1.99-2.15 for `jax.lax.ragged_dot` (tiles of 512 rows), whose kernels
+also reach the profile without the layer's scope (PERF.md, PR 27). The fallback is
 one masked dense product a group, which every backend lowers and which costs
 G times the work.
 """

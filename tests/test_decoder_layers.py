@@ -193,8 +193,120 @@ def test_routing_is_dropless_under_a_skewed_router(setup):
     assert int(state["assignments_absent"]) == 0
     _close(out, ref.routed_experts(CFG, skew, "b1_mlp", u, ID))
     gauges = layer.state_gauges(jax.device_get(state))
+    # half the experts held: twice the even share is every assignment, the
+    # layer has no bounded part and `moe.routed_rows.*` stay away
+    assert layer.row_bound(64) >= 64
     assert gauges == {"moe.expert_load.max_over_mean": 2.0,
                       "moe.assignments_held_share": 1.0}
+
+
+# 2 of 16 experts held, top 4: 200 tokens make 800 assignments of which 100
+# fall to the share when the router is even; twice that in whole tiles, 256,
+# is the bound and the block, and 800 is no whole number of blocks
+SPARSE = dict(CFG, n_routed_experts=2, num_experts_per_tok=4,
+              published=dict(CFG["published"], n_routed_experts=16))
+EVERY = dict(SPARSE, n_routed_experts=16)
+TOKENS = 200
+
+
+def _steered(cfg, both, one):
+    """Weights and tokens of which the first `both` are routed to both held
+    experts, the next `one` to the first alone and the others to none (two
+    rows of the router's matrix read two features that only these tokens
+    carry); nothing steered where both are None."""
+    params = dict(ref.init_params(cfg, jax.random.PRNGKey(3)))
+    u = _tokens(17, 1, TOKENS, cfg["hidden_size"])
+    if both is not None:
+        w_r = params["b1_mlp/w_r"].at[:2].set(0.0)
+        params["b1_mlp/w_r"] = w_r.at[0, :2].set(1.0).at[1, 0].set(1.0)
+        kind = jnp.arange(TOKENS)
+        u = u.at[0, :, 0].set(jnp.where(kind < both, 20.0, -20.0))
+        u = u.at[0, :, 1].set(jnp.where((kind >= both) & (kind < both + one),
+                                        40.0, 0.0))
+    return params, u
+
+
+ROUTED_ROWS = {
+    # name: (configuration, steering, held assignments, within one block,
+    #        the grouped product's kernel (interpreted) or its fallback)
+    "well_under_the_bound": (SPARSE, (None, None), None, 1.0, False),
+    "at_the_bound": (SPARSE, (128, 0), 256, 1.0, False),
+    "at_the_bound_through_the_kernel": (SPARSE, (128, 0), 256, 1.0, True),
+    "one_over_the_bound": (SPARSE, (128, 1), 257, 0.0, False),
+    "every_token_to_both_held_experts": (SPARSE, (TOKENS, 0), 400, 0.0, False),
+    "every_expert_held": (EVERY, (None, None), 800, None, False),
+}
+
+
+@pytest.mark.parametrize("case", ROUTED_ROWS)
+def test_routed_rows_are_moved_in_blocks_of_twice_the_even_share(case, monkeypatch):
+    """One block of `row_bound` rows while the held assignments fit it, a
+    second where they pass it by one or fill it half, and the single pass of
+    a layer that holds every expert: the same answer, counters and gradients
+    as the reference and as one pass over every row."""
+    cfg, steering, held, bounded, kernel = ROUTED_ROWS[case]
+    params, u = _steered(cfg, *steering)
+    layer = prog.zoo(cfg, 0).conf().nodes["b1_mlp"].conf.layer
+    rows = TOKENS * 4
+    assert layer.row_bound(rows) == (256 if cfg is SPARSE else 1664)
+    leaves = ("w_r", "e_w_g", "e_w_u", "e_w_d")
+    ct = _tokens(18, 1, TOKENS, cfg["hidden_size"])
+
+    def through_layer(u_, own):
+        out, state, _ = layer.forward(own, _state(layer, params, "b1_mlp"), u_,
+                                      train=True)
+        return jnp.sum(out * ct), (out, state)
+
+    def through_reference(u_, own):
+        p = dict(params, **{f"b1_mlp/{k}": v for k, v in own.items()})
+        return jnp.sum(ref.routed_experts(cfg, p, "b1_mlp", u_, ID) * ct)
+
+    own = _of(params, "b1_mlp", layer)
+    run = jax.jit(jax.value_and_grad(through_layer, (0, 1), has_aux=True))
+    with helpers_enabled_ctx(kernel):
+        (_, (out, state)), (du, dp) = run(u, own)
+        lowered = run.lower(u, own).as_text(debug_info=True)
+    assert ("blocks/while" in lowered) == (bounded is not None)
+    _close(out, ref.routed_experts(cfg, params, "b1_mlp", u, ID))
+    ref_du, ref_dp = jax.grad(through_reference, (0, 1))(u, own)
+    _close(du, ref_du)
+    for leaf in leaves:
+        _close(dp[leaf], ref_dp[leaf])
+    sel, _ = ref.route(cfg, params, "b1_mlp", u)
+    load = [int((np.asarray(sel) == e).sum()) for e in range(layer.held)]
+    assert held in (None, sum(load))
+    assert [int(v) for v in state["expert_load"]] == load
+    assert int(state["assignments_absent"]) == rows - sum(load)
+    gauges = layer.state_gauges(jax.device_get(state))
+    assert gauges.get("moe.routed_rows.bounded") == bounded
+    assert gauges.get("moe.routed_rows.held_over_bound") == \
+        (None if bounded is None else sum(load) / 256)
+    # one pass over every row: a bound of all of them, as where all are held
+    monkeypatch.setattr(decoder.RoutedExperts, "row_bound", lambda self, rows: rows)
+    whole = jax.jit(jax.value_and_grad(through_layer, (0, 1), has_aux=True))
+    (_, (out_w, state_w)), (du_w, dp_w) = whole(u, own)
+    assert "blocks/while" not in whole.lower(u, own).as_text(debug_info=True)
+    _close(out, out_w)
+    _close(du, du_w)
+    for leaf in leaves:
+        _close(dp[leaf], dp_w[leaf])
+    assert jax.tree_util.tree_all(jax.tree_util.tree_map(
+        lambda a, b: bool(jnp.all(a == b)), state, state_w))
+
+
+def test_routed_rows_gauges_reach_the_registry_after_fit_on_device():
+    """A tiny decoder whose expert layers hold 2 of 16 experts, 128 tokens a
+    step: the bound is 128 of 512 assignment rows and fresh weights route
+    about 64 to the share, so the step runs bounded and says so."""
+    from deeplearning4j_tpu import telemetry
+    cfg = dict(SPARSE, sequence_length=64)
+    net = prog.build(cfg, ref.init_params(cfg, jax.random.PRNGKey(4)), 0)
+    ids = jax.random.randint(jax.random.PRNGKey(5), (2, 65), 0, cfg["vocab_size"])
+    net.fit_on_device(*prog.batch_of(ids[:, :-1], ids[:, 1:]), steps=1)
+    for node in ("b1_mlp", "mtp_mlp"):
+        assert telemetry.registry().get(f"moe.routed_rows.bounded.{node}").value == 1.0
+        assert 0.0 < telemetry.registry().get(
+            f"moe.routed_rows.held_over_bound.{node}").value < 1.0
 
 
 @pytest.mark.parametrize("node", ["b0_attn", "b1_mlp"])

@@ -140,6 +140,28 @@ def _gmm_grad(x, w, sizes):
                     argnums=(0, 1))(x, w)
 
 
+# the expert layer around them at the same shapes, value and gradient: 8 of
+# 64 experts held, so the routed part is traced twice, over the 4096 rows of
+# its bound and over all 16384, under one `conditional`
+EXPERT_LAYER = [((1, 4096, 3584), BF16), ((3584, 64), BF16)] \
+    + [((8, 3584, 1024), BF16)] * 2 + [((8, 1024, 3584), BF16)] \
+    + [((3584, 1024), BF16)] * 2 + [((1024, 3584), BF16), ((64,), F32)]
+
+
+def _expert_layer_grad(x, *leaves):
+    from deeplearning4j_tpu.nn.conf.layers.decoder import RoutedExperts
+    layer = RoutedExperts(n_in=3584, n_out=3584, n_experts=64, experts_held=8,
+                          top_k=4, width=1024, routed_scaling_factor=2.0)
+    assert layer.row_bound(ROWS) == 4096
+    names = ("w_r", "e_w_g", "e_w_u", "e_w_d", "s_w_g", "s_w_u", "s_w_d")
+
+    def loss(x_, params):
+        state = dict(layer.init_state(None), router_bias=leaves[-1])
+        return _sum(layer.forward(params, state, x_, train=True)[0])
+    with helpers.helpers_enabled_ctx(True):
+        return jax.value_and_grad(loss, (0, 1))(x, dict(zip(names, leaves)))
+
+
 GATES = [((B, 4 * H), BF16), ((B, H), BF16)]
 PEEPS = [((H,), BF16)] * 3
 
@@ -158,6 +180,8 @@ CASES = {
     "grouped_matmul down": (grouped_matmul_kernel, GMM_DOWN),
     "grouped_matmul bwd": (_gmm_grad, GMM_UP),
     "grouped_matmul down bwd": (_gmm_grad, GMM_DOWN),
+    "grouped_matmul in the expert layer, bounded and whole":
+        (_expert_layer_grad, EXPERT_LAYER),
     "graves_lstm_scan": (graves_lstm_scan_pallas, SCAN),
     "graves_lstm_scan bwd": (_grad(graves_lstm_scan_pallas, 8), SCAN),
     "graves_lstm_scan bwd cs unused": (_grad(_scan_ys_only, 8), SCAN),
@@ -190,6 +214,10 @@ CASES = {
 }
 
 
+# what else the lowered text has to hold: both branches of the routed part
+LOWERED = {"grouped_matmul in the expert layer, bounded and whole":
+           ("blocks/while",)}
+
 # the names a device trace shows the scan's two Mosaic calls under
 # (`kernel_name` of the custom call, the compiled instruction's name)
 KERNEL_NAMES = {
@@ -210,11 +238,13 @@ def test_kernel_lowers_and_compiles_for_tpu(name, v5e_sharding):
     fn, shapes = CASES[name]
     args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
     text = jax.jit(fn).trace(*args).lower(
-        lowering_platforms=("tpu",)).as_text()
+        lowering_platforms=("tpu",)).as_text(debug_info=name in LOWERED)
     assert "tpu_custom_call" in text, f"{name}: no Mosaic call in the " \
         "lowered text — the kernel gave way to its reference"
     for kernel in KERNEL_NAMES.get(name, ()):
         assert f'kernel_name = "{kernel}"' in text
+    for piece in LOWERED.get(name, ()):
+        assert piece in text
     if v5e_sharding is None:
         return
     on_chip = [jax.ShapeDtypeStruct(s, d, sharding=v5e_sharding)
