@@ -182,9 +182,6 @@ def bench_resnet50(batch=256, steps=30, compute_dtype="bfloat16",
            "batch": batch, "compute_dtype": compute_dtype or "float32",
            "params": net.num_params(),
            "mfu": _sanity_check_peak(name, flops, ms)}
-    if helpers:
-        out["helpers"] = ("on: graph-fused conv1x1+BN+relu Pallas kernel "
-                          f"({len(net._conv_bn_fusable())} pairs fused)")
     return out
 
 

@@ -1,12 +1,14 @@
 #!/usr/bin/env python3
 """Chip smoke: does the program still start, compiled, on the chip?
 
-Drives the paths a default user hits — the trainer on the zoo ResNet50, the
-two default-on training kernels (flash attention, fused Graves-LSTM scan)
-and the serving engine with its paged flash-decode kernel — once each,
-through the normal entry points, at the widths bench.py records. Weights
-are random from a seed; steps and request counts are cut to fit the time
-limit, widths never.
+Drives the paths a default user hits — the trainer on the zoo ResNet50, two
+of the training kernels behind the helper seam (flash attention, fused
+Graves-LSTM scan) and the serving engine with its paged flash-decode kernel —
+once each, through the normal entry points, at the widths of the
+benchmark's configurations (`benchmark/configs/`: ResNet50 and the LSTM; the
+attention and serving nets have no cell yet and keep the widths their phase
+names). Weights are random from a seed; steps and request counts are cut to
+fit the time limit, widths never.
 
     python chip_smoke.py            # every phase, one child process each
     python chip_smoke.py serve      # only the named phases
@@ -109,10 +111,10 @@ def _compile_counter() -> _Compiles:
 
 
 def _kernel_policy():
-    """The helper policy the kernel side of a phase runs under: None — the
-    default a user gets, which engages default-on kernels on a TPU — on the
-    chip; forced on elsewhere, so that the CPU tests drive the same control
-    flow with the kernels in interpret mode."""
+    """The override the kernel side of a phase runs under (ops/helpers.py):
+    None on the chip — the default a user gets, under which every registered
+    kernel runs on a TPU; True elsewhere, so that the CPU tests drive the
+    same control flow with the kernels in interpret mode."""
     import jax
     return None if jax.default_backend() == "tpu" else True
 

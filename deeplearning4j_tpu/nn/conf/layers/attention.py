@@ -139,16 +139,13 @@ class SelfAttentionLayer(FeedForwardLayerConf):
             # parallelism: there the DENSE einsums are what XLA partitions
             # over the seq axis — a lax.scan over reshaped k/v blocks
             # would force cross-shard gathers instead
-            from deeplearning4j_tpu.ops.helpers import (
-                helpers_enabled_for, registered_helpers)
-            if "flash_attention" in registered_helpers() \
-                    and helpers_enabled_for("flash_attention"):
-                from deeplearning4j_tpu.ops.flash_attention import (
-                    flash_attention)
+            from deeplearning4j_tpu.ops.helpers import helper_for
+            flash = helper_for("flash_attention", None)
+            if flash is not None:
                 # the kernel picks its own MXU-sized tiles; the layer's
                 # block_size only governs the fallback scan granularity
-                out = flash_attention(q, k, v, mask, self.causal, None,
-                                      0, 0, self.attention_window)
+                out = flash(q, k, v, mask, self.causal, None,
+                            0, 0, self.attention_window)
             else:
                 out = blockwise_attention(q, k, v, self.block_size,
                                           causal=self.causal, mask=mask,

@@ -201,17 +201,15 @@ class LatentAttention(_TokenLayer):
     def _attend(self, q, k, v):
         """(B, H, T, qk), (B, H, T, qk), (B, H, T, v) -> (B, H, T, v)."""
         t = q.shape[2]
-        from deeplearning4j_tpu.ops.helpers import (
-            helpers_enabled_for, registered_helpers)
-        if t > _DENSE_ATTENTION_MAX_T \
-                and "flash_attention" in registered_helpers() \
-                and helpers_enabled_for("flash_attention"):
-            from deeplearning4j_tpu.ops.flash_attention import flash_attention
+        from deeplearning4j_tpu.ops.helpers import helper_for
+        flash = helper_for("flash_attention", None) \
+            if t > _DENSE_ATTENTION_MAX_T else None
+        if flash is not None:
             # the kernel wants one width: V is padded to QK's with zeros,
             # whose columns of the result are dropped (PERF.md: what it costs)
             pad = q.shape[-1] - v.shape[-1]
             vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad)))
-            out = flash_attention(q, k, vp, None, True, self.softmax_scale)
+            out = flash(q, k, vp, None, True, self.softmax_scale)
             return out[..., :v.shape[-1]]
         scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                             preferred_element_type=_F32) * self.softmax_scale

@@ -66,35 +66,17 @@ class LSTM(FeedForwardLayerConf):
     def _step(self, params, xw_t, h, c):
         n = self.n_out
         gates = xw_t + h @ params["RW"]
-        std_acts = (self.gate_activation == Activation.SIGMOID
-                    and self.activation == Activation.TANH)
-        if not self.peephole and std_acts:
-            # helper seam (ref LSTMHelper.java fast path): fused Pallas gate
-            # kernel when enabled, identical math either way
-            from deeplearning4j_tpu.ops.helpers import helper_for
-            from deeplearning4j_tpu.ops.pallas_kernels import lstm_gates_xla
-            c_new, h_new = helper_for("lstm_gates", lstm_gates_xla)(gates, c)
-            return h_new, c_new
-        if self.peephole and std_acts:
-            # Graves/peephole fast path (ref CudnnLSTMHelper.java:175)
-            from deeplearning4j_tpu.ops.helpers import helper_for
-            from deeplearning4j_tpu.ops.pallas_kernels import graves_gates_xla
-            c_new, h_new = helper_for("graves_lstm_gates", graves_gates_xla)(
-                gates, c, params["pi"], params["pf"], params["po"])
-            return h_new, c_new
-        zi, zf, zo, zg = (gates[:, :n], gates[:, n:2 * n],
-                          gates[:, 2 * n:3 * n], gates[:, 3 * n:])
         gact = lambda v: apply_activation(self.gate_activation, v)
-        if self.peephole:
-            i = gact(zi + c * params["pi"])
-            f = gact(zf + c * params["pf"])
-        else:
-            i, f = gact(zi), gact(zf)
-        g = apply_activation(self.activation, zg)
+        act = lambda v: apply_activation(self.activation, v)
+        # peephole: the gate also sees the cell state through a diagonal
+        peep = (lambda z, cell, k: z + cell * params[k]) if self.peephole \
+            else (lambda z, cell, k: z)
+        i = gact(peep(gates[:, :n], c, "pi"))
+        f = gact(peep(gates[:, n:2 * n], c, "pf"))
+        g = act(gates[:, 3 * n:])
         c_new = f * c + i * g
-        o = gact(zo + c_new * params["po"]) if self.peephole else gact(zo)
-        h_new = o * apply_activation(self.activation, c_new)
-        return h_new, c_new
+        o = gact(peep(gates[:, 2 * n:3 * n], c_new, "po"))
+        return o * act(c_new), c_new
 
     def _scan(self, params, x, mask, h0=None, c0=None, reverse=False):
         """x: (batch, size, time) → outputs (batch, n_out, time), final (h, c)."""
@@ -112,16 +94,15 @@ class LSTM(FeedForwardLayerConf):
         # the bias itself, so that its backward sums the bias gradient where
         # the gate gradients already are. Zero peepholes reduce exactly to
         # the plain-LSTM math. Masked sequences keep the lax.scan path (the
-        # kernel has no state-hold select).
+        # kernel has no state-hold select); what the site can see itself is
+        # checked before the seam is asked.
+        from deeplearning4j_tpu.ops.helpers import helper_for
+        from deeplearning4j_tpu.ops.lstm_scan_fused import fits_vmem
         if mask is None and self.gate_activation == Activation.SIGMOID \
-                and self.activation == Activation.TANH:
-            from deeplearning4j_tpu.ops.helpers import (
-                helpers_enabled_for, registered_helpers)
-            from deeplearning4j_tpu.ops.lstm_scan_fused import fits_vmem
-            if helpers_enabled_for("graves_lstm_scan") \
-                    and "graves_lstm_scan" in registered_helpers() \
-                    and fits_vmem(b, n, jnp.dtype(dtype).itemsize):
-                fused = registered_helpers()["graves_lstm_scan"]
+                and self.activation == Activation.TANH \
+                and fits_vmem(b, n, jnp.dtype(dtype).itemsize):
+            fused = helper_for("graves_lstm_scan", None)
+            if fused is not None:
                 zero = jnp.zeros((n,), dtype)
                 pi = params.get("pi", zero)
                 pf = params.get("pf", zero)

@@ -140,56 +140,6 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         self._opt_state = unflatten_params(self._opt_state, jnp.asarray(flat))
 
     # ------------------------------------------------------------- forward
-    def _conv_bn_fusable(self):
-        """{conv_vertex_name: bn_vertex_name} for 1x1-conv -> BatchNorm pairs
-        eligible for the fused Pallas helper (ops/conv_fused.py — the
-        CudnnConvolutionHelper-analog plug point): identity-activation 1x1
-        conv, no dropout, whose ONLY consumer is a BN layer with IDENTITY or
-        RELU activation. Computed once per net."""
-        cached = getattr(self, "_conv_bn_fusable_cache", None)
-        if cached is not None:
-            return cached
-        from deeplearning4j_tpu.common.enums import Activation
-        from deeplearning4j_tpu.nn.conf.layers.convolutional import (
-            ConvolutionLayer)
-        from deeplearning4j_tpu.nn.conf.layers.normalization import (
-            BatchNormalization)
-        nodes = self.conf.nodes
-        consumers: Dict[str, List[str]] = {}
-        for name, node in nodes.items():
-            for src in node.inputs:
-                consumers.setdefault(src, []).append(name)
-        fusable: Dict[str, str] = {}
-        for name, node in nodes.items():
-            if node.kind != "layer" or not isinstance(node.conf,
-                                                      ConvolutionLayer):
-                continue
-            conv = node.conf
-            if type(conv) is not ConvolutionLayer:
-                continue  # subclasses (1D/depthwise/...) keep the plain path
-            if tuple(conv.kernel_size) != (1, 1) \
-                    or tuple(conv.dilation) != (1, 1) \
-                    or tuple(conv.padding) != (0, 0) \
-                    or conv.stride[0] != conv.stride[1] \
-                    or conv.activation != Activation.IDENTITY \
-                    or conv.dropout > 0 or node.preprocessor is not None:
-                continue
-            outs = consumers.get(name, [])
-            if len(outs) != 1 or name in self.conf.outputs:
-                continue  # a declared graph output must stay materialized
-            bn_node = nodes[outs[0]]
-            if bn_node.kind != "layer" \
-                    or type(bn_node.conf) is not BatchNormalization \
-                    or bn_node.conf.lock_gamma_beta \
-                    or bn_node.conf.dropout > 0 \
-                    or bn_node.preprocessor is not None \
-                    or bn_node.conf.activation not in (Activation.IDENTITY,
-                                                       Activation.RELU):
-                continue
-            fusable[name] = outs[0]
-        self._conv_bn_fusable_cache = fusable
-        return fusable
-
     def _forward_all(self, params_tree, state_tree, inputs: List[jnp.ndarray], *,
                      train: bool, rng=None, fmasks: Optional[List] = None,
                      stop_at_scores: bool = False, labels=None, lmasks=None,
@@ -226,14 +176,6 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
             lmask_map = dict(zip(self.conf.outputs, lmasks or [None] * len(labels)))
         total_loss = jnp.asarray(0.0, self.dtype) if stop_at_scores else None
         from deeplearning4j_tpu.nn.conf.layers.recurrent import LSTM as _LSTM
-        # conv+BN fused fast path (train only; eval BN uses running stats)
-        from deeplearning4j_tpu.ops.helpers import helpers_enabled, \
-            registered_helpers
-        fusable = {}
-        if train and helpers_enabled() \
-                and "conv1x1_bn_act" in registered_helpers():
-            fusable = self._conv_bn_fusable()
-        pending_fused: Dict[str, tuple] = {}  # conv name -> (conv input, idx)
         final_rnn: List = []
         if rnn_init_states is not None:
             from deeplearning4j_tpu.util.dtypes import cast_floats as _cf
@@ -242,46 +184,6 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
 
         for name in self.conf.topo_order:
             node = nodes[name]
-            if name in fusable:
-                # stash the conv's input; the (sole-consumer) BN node below
-                # runs the fused kernel over it
-                i = layer_idx[name]
-                cur = values[node.inputs[0]]
-                if mixed:
-                    with _layer_scope(node.conf, name):
-                        cur = cur.astype(cd)
-                pending_fused[name] = (cur, i, node.conf)
-                values[name] = None  # guarded by the single-consumer check
-                masks[name] = masks.get(node.inputs[0])
-                new_states[i] = state_tree[i]
-                continue
-            if node.kind == "layer" and node.inputs \
-                    and node.inputs[0] in pending_fused:
-                from deeplearning4j_tpu.ops.conv_fused import conv1x1_bn_act
-                from deeplearning4j_tpu.common.enums import Activation as _Act
-                x0, ci, conv = pending_fused.pop(node.inputs[0])
-                bn = node.conf
-                i = layer_idx[name]
-                cp, bp = params_tree[ci], params_tree[i]
-                w = cp["W"][:, :, 0, 0]
-                bias = cp.get("b")
-                if bias is None:
-                    bias = jnp.zeros((w.shape[0],), w.dtype)
-                # one kernel for both: it goes under the convolution's name
-                with _layer_scope(conv, node.inputs[0]):
-                    out, m_b, v_b = conv1x1_bn_act(
-                        x0, w, bp["gamma_w"], bp["beta"], bias, bn.eps,
-                        bn.activation == _Act.RELU, conv.stride[0])
-                d = bn.decay
-                st = state_tree[i]
-                # match BatchNormalization.forward's running update exactly
-                # (batch stats cast to activation dtype before the blend)
-                mb, vb = m_b.astype(x0.dtype), v_b.astype(x0.dtype)
-                new_states[i] = {"mean": d * st["mean"] + (1 - d) * mb,
-                                 "var": d * st["var"] + (1 - d) * vb}
-                values[name] = out
-                masks[name] = masks.get(node.inputs[0])
-                continue
             in_vals = [values[i] for i in node.inputs]
             in_masks = [masks.get(i) for i in node.inputs]
             if node.kind == "vertex":

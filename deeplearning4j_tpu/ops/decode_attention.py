@@ -300,8 +300,8 @@ def flash_decode_attention_paged(q, kp, vp, block_tables, visible, scale,
     return out.reshape(S, H, D).astype(q.dtype)
 
 
-register_helper("decode_attention_paged",
-                default_on=True)(flash_decode_attention_paged)
+register_helper("decode_attention_paged")(
+    flash_decode_attention_paged)
 
 
 # ------------------------------------------------- speculative (multi-query)
@@ -362,8 +362,8 @@ def flash_decode_attention_spec_paged(q, kp, vp, block_tables, visible,
     return out.reshape(S, Q, H, D).astype(q.dtype)
 
 
-register_helper("decode_attention_spec_paged",
-                default_on=True)(flash_decode_attention_spec_paged)
+register_helper("decode_attention_spec_paged")(
+    flash_decode_attention_spec_paged)
 
 
 def paged_spec_decode_specs(tensor_axis: str = "tensor",

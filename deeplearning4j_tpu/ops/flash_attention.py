@@ -36,13 +36,13 @@ Score/softmax math is fp32 (flash convention); q/k/v stream in their
 storage dtype (bf16 on TPU).
 
 Registered as helper "flash_attention" (default-on for TPU);
-SelfAttentionLayer's long-context path dispatches here when enabled, with
-the lax.scan blockwise recurrence as the universal fallback.
+SelfAttentionLayer's long-context path, the decoder's LatentAttention and
+the ring's rounds dispatch here through `ops/helpers.helper_for`, with the
+lax.scan blockwise recurrence (the dense product in the decoder) as fallback.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -73,16 +73,16 @@ DEFAULT_BK = 0
 # matching the two-pass dq scratch's full-precision accumulation) or "io"
 # (q.dtype — halves the partial-buffer HBM traffic at the cost of one bf16
 # rounding per k block before the sum).
-_CONFIG = {"bwd": os.environ.get("DL4J_TPU_FLASH_BWD", "fused"),
-           "dq_partials": os.environ.get("DL4J_TPU_FLASH_DQ_PARTIALS", "acc")}
+_CONFIG = {"bwd": "fused",
+           "dq_partials": "acc"}
 
 # HBM ceiling for the fused schedule's (BH, nk, Tp, D) dq-partials buffer —
 # it grows O(T^2 * D / bk), so long contexts (T=32k is ~4.3 GB fp32 at the
 # bench head count) must not pay it. Above the cap the backward silently
 # takes the two_pass schedule (O(T * block) memory, same math). The bench
-# shape T=8192 stays comfortably under the default 2 GiB.
-DQ_PARTIALS_MAX_BYTES = int(os.environ.get(
-    "DL4J_TPU_FLASH_DQP_MAX_BYTES", 2 * 1024 ** 3))
+# shape T=8192 stays comfortably under the 2 GiB (a constant: tests set the
+# module attribute).
+DQ_PARTIALS_MAX_BYTES = 2 * 1024 ** 3
 
 
 def configure(bwd: str | None = None, dq_partials: str | None = None):
@@ -643,7 +643,7 @@ def _fa_bwd_impl(causal, scale, bq, bk, saved, dout, dlse, window=0,
 
 
 _flash_core.defvjp(_fa_fwd, _fa_bwd)
-register_helper("flash_attention", default_on=True)(flash_attention)
+register_helper("flash_attention")(flash_attention)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))

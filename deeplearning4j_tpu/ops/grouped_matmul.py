@@ -98,7 +98,7 @@ def _grouped_bwd(res, g):
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-@register_helper("grouped_matmul", default_on=True)
+@register_helper("grouped_matmul")
 def grouped_matmul_kernel(x, w, group_sizes):
     m = x.shape[0]
     pad = -m % TILE_ROWS

@@ -1,23 +1,25 @@
-"""Acceleration-helper seam (L2).
+"""Acceleration-helper seam (L2): the one place that chooses an implementation.
 
 Parity: ref nn/layers/LayerHelper + ConvolutionHelper/LSTMHelper/
 BatchNormalizationHelper — the reference's pluggable cudnn fast-path interfaces
-(e.g. nn/layers/recurrent/LSTMHelper.java). TPU rendering: ops register named
-accelerated implementations (Pallas kernels) keyed by op name; call sites dispatch
-through `helper_for`, which returns the registered kernel when the seam is enabled
-and the platform supports it, else the XLA-fallback the caller supplies. XLA's
-default codegen is already excellent — kernels go through this seam only where
-hand-tiling beats the compiler, and everything keeps working with the seam off.
+(e.g. nn/layers/recurrent/LSTMHelper.java). TPU rendering: a kernel module
+registers its Pallas implementation under an op name, and every call site asks
+`helper_for(name, fallback)` and nothing else. The policy is one sentence: a
+registered kernel runs on a TPU unless the override says otherwise, and off a
+TPU only when the override forces it (interpreted). What a site can observe
+itself (a mask, a shape the kernel refuses) it checks BEFORE it asks, so that
+`ops.helper.<name>.kernel|fallback` count decisions that were really open.
+XLA's default codegen is already excellent — a kernel stays registered only
+where hand-tiling beats the compiler, and everything keeps working with the
+override off.
 """
 from __future__ import annotations
 
 import contextlib
-import os
 from typing import Callable, Dict, Optional
 
 _REGISTRY: Dict[str, Callable] = {}
-_DEFAULT_ON: set = set()
-_ENABLED: Optional[bool] = None
+_OVERRIDE: Optional[bool] = None
 
 
 def interpret_mode() -> bool:
@@ -27,39 +29,28 @@ def interpret_mode() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def register_helper(op_name: str, default_on: bool = False):
-    """Decorator: register an accelerated implementation for `op_name`.
-    `default_on=True` marks kernels that engage automatically on TPU when
-    nothing was set explicitly — the reference's 'cuDNN used when supported'
-    behavior (ConvolutionLayer.java:72 reflection-load) — reserved for
-    kernels with a MEASURED same-session win and exact-parity tests."""
+def register_helper(op_name: str):
+    """Decorator: register the accelerated implementation of `op_name` — the
+    reference's 'cuDNN used when supported' (ConvolutionLayer.java:72
+    reflection-load)."""
     def deco(fn):
         _REGISTRY[op_name] = fn
-        if default_on:
-            _DEFAULT_ON.add(op_name)
         return fn
     return deco
 
 
 def enable_helpers(flag: Optional[bool] = True) -> None:
-    """Programmatic switch (env DL4J_TPU_HELPERS=1/0 also works; None resets
-    to the default policy: default_on kernels engage on TPU only)."""
-    global _ENABLED
-    _ENABLED = None if flag is None else bool(flag)
-
-
-def helpers_override() -> Optional[bool]:
-    """The current explicit override, for save/restore around temporary
-    enable_helpers() flips (None = default per-op policy active)."""
-    return _ENABLED
+    """The override: True forces every registered kernel (interpreted off a
+    TPU), False none, None resets to the default policy."""
+    global _OVERRIDE
+    _OVERRIDE = None if flag is None else bool(flag)
 
 
 @contextlib.contextmanager
 def helpers_enabled_ctx(flag: Optional[bool]):
     """Scoped enable_helpers: restores the previous override on exit, so a
-    temporary flip can never pin the global switch for the rest of the
-    process."""
-    prev = helpers_override()
+    temporary flip can never pin it for the rest of the process."""
+    prev = _OVERRIDE
     enable_helpers(flag)
     try:
         yield
@@ -67,33 +58,25 @@ def helpers_enabled_ctx(flag: Optional[bool]):
         enable_helpers(prev)
 
 
-def helpers_enabled() -> bool:
-    """The explicit global switch (ignores per-op defaults)."""
-    if _ENABLED is not None:
-        return _ENABLED
-    return os.environ.get("DL4J_TPU_HELPERS", "0") == "1"
-
-
 def helpers_enabled_for(op_name: str) -> bool:
-    """Per-op resolution: explicit switch > env var > per-op TPU default."""
-    if _ENABLED is not None:
-        return _ENABLED
-    env = os.environ.get("DL4J_TPU_HELPERS")
-    if env is not None:
-        return env == "1"  # same parse as helpers_enabled: only "1" enables
-    if op_name in _DEFAULT_ON:
-        import jax
-        return jax.default_backend() == "tpu"
-    return False
+    """The policy `helper_for` asks: the override, else 'on a TPU'."""
+    if op_name not in _REGISTRY:
+        return False
+    if _OVERRIDE is not None:
+        return _OVERRIDE
+    import jax
+    return jax.default_backend() == "tpu"
 
 
-def helper_for(op_name: str, fallback: Callable) -> Callable:
-    """The seam: accelerated impl if registered+enabled, else the fallback
-    (ref LayerHelper selection in BaseLayer.initializeHelper)."""
-    engaged = op_name in _REGISTRY and helpers_enabled_for(op_name)
+def helper_for(op_name: str, fallback: Optional[Callable]) -> Optional[Callable]:
+    """The seam: the registered kernel where the policy engages it, else the
+    fallback (ref LayerHelper selection in BaseLayer.initializeHelper). A
+    site whose fallback has another signature than the kernel passes None
+    and branches on the answer."""
+    engaged = helpers_enabled_for(op_name)
     # seam attribution (ISSUE 6): count which path resolved, at resolve
     # time — under jit that is trace time, never per step. Sanitized: op
-    # names are free-form ("conv1x1-bn-relu" would break exposition).
+    # names are free-form.
     try:
         from deeplearning4j_tpu import telemetry
         telemetry.registry().counter(
@@ -102,9 +85,7 @@ def helper_for(op_name: str, fallback: Callable) -> Callable:
             "helper-seam resolutions by path (counted at trace time)").inc()
     except Exception:
         pass
-    if engaged:
-        return _REGISTRY[op_name]
-    return fallback
+    return _REGISTRY[op_name] if engaged else fallback
 
 
 def registered_helpers():

@@ -69,11 +69,11 @@ is fp32 by default (accumulated one width above bf16 activations); h/c
 carries round-trip through the activation dtype between steps exactly like
 the unfused scan, so helpers-on training matches helpers-off within bf16
 rounding (exact in fp32/fp64 tests). `configure(gate_math="native")` keeps
-gate math in the activation dtype.
+gate math in the activation dtype. The layouts are module constants that
+only `configure()` changes (no environment variable reads them): what is
+left to select is ROADMAP.md, Design 11.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -108,9 +108,9 @@ _TILES = (2048, 1024, 512, 256, 128, 64, 32, 16, 8)
 #   gate_math: "fp32" promotes gate math one width up; "native" keeps the
 #              activation dtype (bf16 in, bf16 math).
 _CONFIG = {
-    "grid": os.environ.get("DL4J_TPU_LSTM_GRID", "auto"),
-    "k_steps": int(os.environ.get("DL4J_TPU_LSTM_KSTEPS", "0")),
-    "gate_math": os.environ.get("DL4J_TPU_LSTM_GATE_MATH", "fp32"),
+    "grid": "auto",
+    "k_steps": 0,
+    "gate_math": "fp32",
 }
 
 _K_CANDIDATES = (8, 5, 4, 2, 1)
@@ -601,7 +601,7 @@ graves_lstm_scan_pallas.defvjp(_scan_fwd, _scan_bwd, symbolic_zeros=True)
 # default-on for TPU (batch-major fwd-1024/bwd-512 K=1 with the direct-prev
 # backward); exact fp64 parity + bf16 net-level equivalence tests gate every
 # layout. What it measures on today's code: PERF.md sections 5 and 6.
-register_helper("graves_lstm_scan", default_on=True)(graves_lstm_scan_pallas)
+register_helper("graves_lstm_scan")(graves_lstm_scan_pallas)
 
 
 def graves_lstm_scan_xla(xw, b, rw, pi, pf, po, h0, c0):

@@ -21,15 +21,7 @@ import numpy as np
 
 def threshold_encode(update: jnp.ndarray, residual: jnp.ndarray, threshold: float):
     """Quantize update+residual to {-t, 0, +t}; remainder stays in the residual
-    (ref EncodingHandler threshold logic). Returns (message, new_residual).
-
-    Dispatches through the L2 helper seam: the Pallas quantization kernel when
-    enabled (ops/pallas_kernels.py), this inline XLA form otherwise."""
-    from deeplearning4j_tpu.ops.helpers import helpers_enabled
-
-    if helpers_enabled() and update.ndim == 1:
-        from deeplearning4j_tpu.ops.pallas_kernels import threshold_encode_pallas
-        return threshold_encode_pallas(update, residual, float(threshold))
+    (ref EncodingHandler threshold logic). Returns (message, new_residual)."""
     acc = update + residual
     mask = jnp.abs(acc) >= threshold
     message = jnp.where(mask, jnp.sign(acc) * threshold, 0.0).astype(update.dtype)

@@ -183,11 +183,11 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "seq",
     assert seq % n_dev == 0, f"seq {seq} not divisible by mesh axis {n_dev}"
     blk = seq // n_dev
     has_mask = mask is not None
-    if use_flash is None:
-        from deeplearning4j_tpu.ops.helpers import helpers_enabled_for
-        use_flash = helpers_enabled_for("flash_attention")
     if window:
         use_flash = False  # see docstring: the ring offset is traced
+    elif use_flash is None:
+        from deeplearning4j_tpu.ops.helpers import helper_for
+        use_flash = helper_for("flash_attention", None) is not None
 
     def _rotate(kb, vb, mb):
         """One neighbor hop of the visiting k/v (+ key-mask) blocks —

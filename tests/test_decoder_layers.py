@@ -131,12 +131,13 @@ def test_latent_attention_matches_the_reference(setup):
     _close(out, ref.latent_attention(CFG, params, "b0_attn", u, ID))
 
 
-def test_latent_attention_through_the_flash_kernel_with_padded_values(setup):
+def test_latent_attention_through_the_flash_kernel_with_padded_values(
+        setup, monkeypatch):
     """The kernel path (V padded to QK's width), interpreted on the CPU."""
     params, _, _, net = setup
-    import copy
-    layer = copy.deepcopy(net.conf.nodes["b0_attn"].conf.layer)
-    layer.block_size = 8
+    layer = net.conf.nodes["b0_attn"].conf.layer
+    # 16 tokens are under the width from which the layer asks for the kernel
+    monkeypatch.setattr(decoder, "_DENSE_ATTENTION_MAX_T", 8)
     u = _tokens(3, 1, 16, CFG["hidden_size"])
     with helpers_enabled_ctx(True):
         out, _, _ = layer.forward(_of(params, "b0_attn", layer), {}, u, train=True)

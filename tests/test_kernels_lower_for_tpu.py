@@ -20,15 +20,12 @@ import jax.numpy as jnp
 import pytest
 
 from deeplearning4j_tpu.ops import helpers
-from deeplearning4j_tpu.ops.conv_fused import conv1x1_bn_act
 from deeplearning4j_tpu.ops.decode_attention import (
     flash_decode_attention_paged, flash_decode_attention_spec_paged)
 from deeplearning4j_tpu.ops.flash_attention import flash_attention
 from deeplearning4j_tpu.ops.grouped_matmul import grouped_matmul_kernel
 from deeplearning4j_tpu.ops import lstm_scan_fused
 from deeplearning4j_tpu.ops.lstm_scan_fused import graves_lstm_scan_pallas
-from deeplearning4j_tpu.ops.pallas_kernels import (
-    graves_gates_pallas, lstm_gates_pallas, threshold_encode_pallas)
 
 BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
 
@@ -162,11 +159,7 @@ def _expert_layer_grad(x, *leaves):
         return jax.value_and_grad(loss, (0, 1))(x, dict(zip(names, leaves)))
 
 
-GATES = [((B, 4 * H), BF16), ((B, H), BF16)]
-PEEPS = [((H,), BF16)] * 3
-
 CASES = {
-    # default-on
     "flash_attention": (_flash(), QKV),
     "flash_attention window=1024": (_flash(1024), QKV),
     "flash_attention bwd fused": (_grad(_flash(), 3), QKV),
@@ -198,19 +191,6 @@ CASES = {
     "decode_attention_spec_paged Q=4 int8 window=256":
         (_decode(flash_decode_attention_spec_paged, 256),
          _paged((S, 4, NH, D), I8)),
-    # registered, default-off
-    "lstm_gates": (lstm_gates_pallas, GATES),
-    "lstm_gates bwd": (_grad(lstm_gates_pallas, 2), GATES),
-    "graves_lstm_gates": (graves_gates_pallas, GATES + PEEPS),
-    "graves_lstm_gates bwd": (_grad(graves_gates_pallas, 5), GATES + PEEPS),
-    "threshold_encode":
-        (lambda u, r: threshold_encode_pallas(u, r, 1e-3),
-         [((1 << 20,), F32)] * 2),
-    # ResNet50's widest 1x1: 56x56, 64 -> 256 channels
-    "conv1x1_bn_act":
-        (lambda x, w, g, b, c: conv1x1_bn_act(x, w, g, b, c, 1e-5, True, 1),
-         [((32, 64, 56, 56), BF16), ((256, 64), BF16)]
-         + [((256,), F32)] * 3),
 }
 
 

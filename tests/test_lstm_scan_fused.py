@@ -292,7 +292,9 @@ def test_net_level_training_identical_with_fused_scan(monkeypatch):
 
 def test_masked_sequences_keep_the_scan_path():
     """Masks must fall back to lax.scan (the kernel has no state-hold):
-    masked training with helpers on == helpers off exactly."""
+    masked training with helpers on == helpers off exactly, and the seam is
+    not asked — a decision that was never open is not counted as one."""
+    from deeplearning4j_tpu import telemetry
     from deeplearning4j_tpu import (
         Activation, InputType, MultiLayerNetwork, NeuralNetConfiguration,
         RnnOutputLayer, Sgd, WeightInit)
@@ -318,12 +320,17 @@ def test_masked_sequences_keep_the_scan_path():
         enable_helpers(False)
         return np.asarray(net.params())
 
+    asked = lambda: [telemetry.registry().counter(
+        f"ops.helper.graves_lstm_scan.{path}").value
+        for path in ("kernel", "fallback")]
+    before = asked()
     try:
         p_off = run(False)
         p_on = run(True)
     finally:
         enable_helpers(False)
     np.testing.assert_allclose(p_on, p_off, atol=1e-12)
+    assert asked() == before
 
 
 def test_fused_scan_composes_with_sharded_trainer_gspmd():
