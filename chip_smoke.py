@@ -1,13 +1,15 @@
 #!/usr/bin/env python3
 """Chip smoke: does the program still start, compiled, on the chip?
 
-Drives the paths a default user hits — the trainer on the zoo ResNet50, two
-of the training kernels behind the helper seam (flash attention, fused
-Graves-LSTM scan) and the serving engine with its paged flash-decode kernel —
+Drives the paths a default user hits — the trainer on the zoo ResNet50, the
+training kernels behind the helper seam (flash attention, fused Graves-LSTM
+scan, and in the zoo's sparse decoder the hyper-connection's calls and the
+grouped product) and the serving engine with its paged flash-decode kernel —
 once each, through the normal entry points, at the widths of the
 benchmark's configurations (`benchmark/configs/`: ResNet50 and the LSTM; the
 attention and serving nets have no cell yet and keep the widths their phase
-names). Weights are random from a seed; steps and request counts are cut to
+names; the decoder's cell fills the chip, so its phase is the zoo class cut
+small). Weights are random from a seed; steps and request counts are cut to
 fit the time limit, widths never.
 
     python chip_smoke.py            # every phase, one child process each
@@ -42,8 +44,8 @@ import sys
 import threading
 import time
 
-PHASES = ("train_resnet50", "train_attention", "train_graves_lstm", "serve",
-          "multichip")
+PHASES = ("train_resnet50", "train_attention", "train_graves_lstm",
+          "train_decoder", "serve", "multichip")
 EXIT_NO_ACCELERATOR = 4
 # the driver allows 1200 s in all, compilation included
 DEADLINE_S = 1140.0
@@ -163,6 +165,7 @@ def _kernel_vs_reference(build_net, x, y, steps: int, what: str,
     """Train a fresh net with the default-on kernel and, from the same seed,
     one step with helpers off; the first-step losses (same params, same
     batch) must agree within bf16 tolerance."""
+    import jax
     import numpy as np
 
     from deeplearning4j_tpu.ops.helpers import helpers_enabled_ctx
@@ -171,6 +174,9 @@ def _kernel_vs_reference(build_net, x, y, steps: int, what: str,
         net = build_net()
         _assert_mosaic(net.lower_train_step(x, y).as_text(), True, what)
         out = _fit_on_device_twice(net, x, y, steps, what)
+    # a module-level jit that asks the seam while it is traced (the decoder's
+    # `_held_experts_part`) would hand the kernel side's answer on
+    jax.clear_caches()
     with helpers_enabled_ctx(False):
         ref = build_net()
         _assert_mosaic(ref.lower_train_step(x, y).as_text(), False,
@@ -296,6 +302,42 @@ def _helper_counts(op: str) -> dict:
     reg = telemetry.registry()
     return {path: reg.counter(f"ops.helper.{op}.{path}", "").value
             for path in ("kernel", "fallback")}
+
+
+def phase_train_decoder(seq_len=1024, hidden=512, heads=4, experts=8,
+                        vocab=2048, steps=2, sinkhorn_rounds=20,
+                        compute_dtype="bfloat16"):
+    """The zoo's sparse decoder (models/xing4.py) cut small, bf16 compute,
+    recomputation by block: one dense and one expert block and the
+    multi-token-prediction module, six hyper-connections in all. Its three
+    default-on kernels (the hyper-connection's four calls, the grouped
+    product, flash attention) against the same net with helpers off; the
+    seam has to have answered "kernel" for every hyper-connection."""
+    import numpy as np
+
+    from deeplearning4j_tpu.models.xing4 import PUBLISHED, Xing4
+    config = dict(
+        PUBLISHED, hidden_size=hidden, intermediate_size=2 * hidden,
+        moe_intermediate_size=hidden // 2, q_lora_rank=hidden // 4,
+        kv_lora_rank=hidden // 4, qk_nope_head_dim=32, qk_rope_head_dim=32,
+        v_head_dim=32, num_attention_heads=heads, n_routed_experts=experts,
+        num_experts_per_tok=2, num_hidden_layers=2, first_k_dense_replace=1,
+        hc_sinkhorn_iters=sinkhorn_rounds, vocab_size=vocab)
+    ids = np.random.RandomState(0).randint(0, vocab, (1, seq_len + 1))
+    x, y = (ids[:, :-1], ids[:, 1:]), (ids[:, 1:], ids[:, 1:])
+    before = _helper_counts("hyper_connection")
+    out = _kernel_vs_reference(
+        lambda: Xing4(config, seed=42, sequence_length=seq_len,
+                      compute_dtype=compute_dtype).init(),
+        x, y, steps, "decoder")
+    after = _helper_counts("hyper_connection")
+    out["seam"] = {k: after[k] - before[k] for k in after}
+    # the kernel side traces its six layers once or more; the helpers-off
+    # side never asks on a chip's behalf: its answers are the fallbacks
+    assert out["seam"]["kernel"] >= 6 and out["seam"]["kernel"] % 6 == 0, \
+        f"decoder: the seam answered {out['seam']} for six hyper-connections"
+    _rate(seq_len * steps, out["warm_call_s"], "tokens/s in the warm call")
+    return out
 
 
 def _serve(net, helpers, prompts, new_tokens, max_seqs, max_len):

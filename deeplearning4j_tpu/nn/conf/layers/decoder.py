@@ -523,7 +523,10 @@ class HyperConnection(BaseLayerConf):
     the mixing are worked out in float32 whatever the compute type, with the
     tokens as the minor axis of every map (n and n x n lead) and the mixing
     written out stream by stream: elementwise work that XLA fuses, where a
-    (tokens, n, n) layout pads every 4 x 4 matrix to a whole tile."""
+    (tokens, n, n) layout pads every 4 x 4 matrix to a whole tile. That is the
+    plain body. Where the helper seam engages it (a TPU, a shape
+    `ops/hyper_connection.token_tile` takes, no mask) the same mathematics
+    runs as four Pallas calls that pass over the state once a direction."""
     layer: Optional[BaseLayerConf] = None
     n_streams: int = 4
     sinkhorn_iters: int = 20
@@ -564,12 +567,17 @@ class HyperConnection(BaseLayerConf):
     def init_state(self, input_type, dtype=jnp.float32):
         return self.layer.init_state(self._inner_type(input_type), dtype)
 
+    @staticmethod
+    def _phi(params):
+        """The maps' weights side by side, pre, post, res: (n d, 2n + n n)."""
+        return jnp.concatenate([params["hc_phi_pre"], params["hc_phi_post"],
+                                params["hc_phi_res"]], axis=1)
+
     def maps(self, params, x):
         """X (B, n, T, d) -> H_pre (n, B, T), H_post (n, B, T), H_res (n, n, B, T),
         float32."""
         n, d = self.n_streams, x.shape[-1]
-        phi = jnp.concatenate([params["hc_phi_pre"], params["hc_phi_post"],
-                               params["hc_phi_res"]], axis=1).astype(x.dtype)
+        phi = self._phi(params).astype(x.dtype)
         raw = sum(jnp.einsum("btd,dc->cbt", x[:, j], phi[j * d:(j + 1) * d],
                              preferred_element_type=_F32) for j in range(n))
         square = sum(jnp.mean(jnp.square(x[:, j].astype(_F32)), axis=-1)
@@ -585,15 +593,35 @@ class HyperConnection(BaseLayerConf):
         return h_pre, h_post, sinkhorn(res, self.sinkhorn_iters, self.hc_eps)
 
     def forward(self, params, state, x, *, train, rng=None, mask=None):
+        from deeplearning4j_tpu.ops.helpers import helper_for
+        from deeplearning4j_tpu.ops.hyper_connection import token_tile
         n = self.n_streams
         inner = {k: v for k, v in params.items() if k not in _HC_KEYS}
+
+        def sublayer(u, mask=mask):
+            with layer_scope(self.layer, self.name):
+                y, new_state, mask = self.layer.forward(
+                    inner, state, u, train=train, rng=rng, mask=mask)
+            return y, (new_state, mask)
+
+        # the kernels pass over the state once a direction (ops/
+        # hyper_connection.py); what this site can see itself it checks first
+        fused = None
+        if mask is None and token_tile(n, x.shape[2], x.shape[3],
+                                       x.dtype.itemsize) is not None:
+            fused = helper_for("hyper_connection", None)
+        if fused is not None:
+            out, (new_state, mask) = fused(
+                x, self._phi(params), params["hc_a"], params["hc_b_pre"], params["hc_b_post"],
+                params["hc_b_res"], params["norm_g"], sublayer,
+                sinkhorn_iters=self.sinkhorn_iters, hc_eps=self.hc_eps,
+                clamp_min=self.clamp_min, clamp_max=self.clamp_max, eps=self.eps)
+            return out, new_state, mask
         h_pre, h_post, h_res = self.maps(params, x)
         streams = [x[:, j].astype(_F32) for j in range(n)]
         u = sum(h_pre[j][..., None] * streams[j] for j in range(n))
         u = rms_norm(u, params["norm_g"], self.eps).astype(x.dtype)
-        with layer_scope(self.layer, self.name):
-            y, new_state, mask = self.layer.forward(inner, state, u, train=train,
-                                                    rng=rng, mask=mask)
+        y, (new_state, mask) = sublayer(u)
         y = y.astype(_F32)
         out = [sum(h_res[i, j][..., None] * streams[j] for j in range(n))
                + h_post[i][..., None] * y for i in range(n)]

@@ -143,6 +143,16 @@ def test_phase_train_graves_lstm_tiny():
     assert len(out["losses"]) == 4 and out["first_loss_helpers_off"] > 0
 
 
+@pytest.mark.slow   # ~45 s: five compiles of the decoder on the CPU
+def test_phase_train_decoder_tiny():
+    # float32: the CPU's runtime has no bf16 x bf16 -> f32 product
+    out = chip_smoke.phase_train_decoder(
+        seq_len=128, hidden=128, heads=2, experts=4, vocab=64, steps=1,
+        sinkhorn_rounds=2, compute_dtype="float32")
+    assert len(out["losses"]) == 2
+    assert out["seam"]["kernel"] >= 6 and out["seam"]["fallback"] == 6
+
+
 def test_phase_serve_tiny():
     out = chip_smoke.phase_serve(
         d_model=32, heads=4, kv_heads=2, vocab=16, max_seqs=4, max_len=64,

@@ -15,6 +15,8 @@ False and x64 off as on the chip, and
    the chip's own compiler, so a scoped-VMEM overflow or an op Mosaic cannot
    lay out fails here and not on the first chip run.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -159,7 +161,34 @@ def _expert_layer_grad(x, *leaves):
         return jax.value_and_grad(loss, (0, 1))(x, dict(zip(names, leaves)))
 
 
+# the hyper-connection round a sublayer at the decoder cell's shape: the
+# state (1, 4, 4096, 3584), phi's three pieces side by side, the maps' scales
+# and biases, the norm's gain; the sublayer costs nothing
+HC_N, HC_T, HC_D = 4, 4096, 3584
+HYPER = [((1, HC_N, HC_T, HC_D), BF16), ((HC_N * HC_D, 2 * HC_N + HC_N ** 2), BF16),
+         ((3,), BF16), ((HC_N,), BF16), ((HC_N,), BF16), ((HC_N, HC_N), BF16),
+         ((HC_D,), BF16)]
+
+
+def _hyper(x, *leaves):
+    from deeplearning4j_tpu.ops.hyper_connection import hyper_connection
+    return hyper_connection(
+        x, *leaves, lambda u: (u, None), sinkhorn_iters=20, hc_eps=1e-6,
+        clamp_min=-30.0, clamp_max=30.0, eps=1e-6)[0]
+
+
+def _hyper_grad_recomputed(*a):
+    """As `ComputationGraph._forward_all` has it: the layer under
+    `jax.checkpoint`, so its forward is traced again in the backward pass;
+    the value is kept, as the next layer keeps it."""
+    layer = jax.checkpoint(_hyper)
+    return jax.value_and_grad(lambda *b: _sum(layer(*b)),
+                              argnums=tuple(range(len(a))))(*a)
+
+
 CASES = {
+    "hyper_connection": (_hyper, HYPER),
+    "hyper_connection bwd recomputed": (_hyper_grad_recomputed, HYPER),
     "flash_attention": (_flash(), QKV),
     "flash_attention window=1024": (_flash(1024), QKV),
     "flash_attention bwd fused": (_grad(_flash(), 3), QKV),
@@ -205,6 +234,17 @@ KERNEL_NAMES = {
     "graves_lstm_scan bwd": ("dl4j_lstm_scan_fwd", "dl4j_lstm_scan_bwd"),
     "graves_lstm_scan bwd cs unused":
         ("dl4j_lstm_scan_fwd", "dl4j_lstm_scan_bwd"),
+    "hyper_connection": ("dl4j_hc_pre", "dl4j_hc_post"),
+    "hyper_connection bwd recomputed":
+        ("dl4j_hc_pre", "dl4j_hc_post", "dl4j_hc_post_bwd", "dl4j_hc_pre_bwd"),
+}
+
+# how often a kernel stays in the compiled program: the recomputed forward's
+# `post` writes an `out` nothing reads, and XLA drops the call
+COMPILED_CALLS = {
+    "hyper_connection bwd recomputed":
+        {"dl4j_hc_pre": 2, "dl4j_hc_post": 1, "dl4j_hc_post_bwd": 1,
+         "dl4j_hc_pre_bwd": 1},
 }
 
 
@@ -235,6 +275,11 @@ def test_kernel_lowers_and_compiles_for_tpu(name, v5e_sharding):
     for kernel in KERNEL_NAMES.get(name, ()):
         # `dl4j_lstm_scan_fwd.1` inside a net, `jvp_dl4j_lstm_scan_fwd_.1` here
         assert any(kernel in call for call in calls), calls
+    for kernel, times in COMPILED_CALLS.get(name, {}).items():
+        # `dl4j_hc_pre.1` the forward's, `jvp_dl4j_hc_pre_.1` the recomputed
+        named = [c for c in calls if re.fullmatch(
+            rf"%(jvp_)?{kernel}_?(\.\d+)?", c.strip())]
+        assert len(named) == times, (kernel, calls)
 
 
 @pytest.mark.parametrize("stream_dcs", [True, False])

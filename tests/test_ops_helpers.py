@@ -15,7 +15,8 @@ from deeplearning4j_tpu.ops.helpers import (
     helpers_enabled_ctx, helpers_enabled_for)
 
 SHIPPED = {"graves_lstm_scan", "flash_attention", "grouped_matmul",
-           "decode_attention_paged", "decode_attention_spec_paged"}
+           "decode_attention_paged", "decode_attention_spec_paged",
+           "hyper_connection"}
 
 
 @pytest.fixture(autouse=True)
@@ -257,9 +258,23 @@ def _site_decode_spec_paged(monkeypatch):
     return decode_attention_spec_paged(*_spec_case(3, 3, 4, 2, 16, 16, 4, 5))
 
 
+def _site_hyper_connection(monkeypatch, t=128):
+    from deeplearning4j_tpu import InputType
+    from deeplearning4j_tpu.nn.conf.layers import decoder
+    layer = decoder.HyperConnection(layer=decoder.RMSNorm(n_in=128), n_streams=4,
+                                    sinkhorn_iters=3)
+    layer.name = "hc"
+    kind = InputType.recurrent(4 * 128, t)
+    layer.set_n_in(kind)
+    params = layer.init_params(jax.random.PRNGKey(5), kind, jnp.float32)
+    x = jnp.asarray(np.random.RandomState(16).randn(1, 4, t, 128), jnp.float32)
+    return layer.forward(params, {}, x, train=False)[0]
+
+
 # site -> (the name it asks the seam for, the driver, the kernel's tolerance
 # against its fallback: float64 but where the fallback (the latent
-# attention's scores) or the kernel (the grouped product) is float32)
+# attention's scores) or the kernel (the grouped product, the hyper-connection)
+# is float32)
 SITES = {
     "LSTM._scan": ("graves_lstm_scan", _site_lstm_scan, 1e-10),
     "SelfAttentionLayer.forward": ("flash_attention", _site_self_attention,
@@ -272,6 +287,8 @@ SITES = {
                                1e-12),
     "decode_attention_spec_paged": ("decode_attention_spec_paged",
                                     _site_decode_spec_paged, 1e-12),
+    "HyperConnection.forward": ("hyper_connection", _site_hyper_connection,
+                                1e-5),
 }
 
 
@@ -313,6 +330,8 @@ REFUSED = {
         ("graves_lstm_scan", _refused_by_activation),
     "a batch fits_vmem refuses": ("graves_lstm_scan", _refused_by_vmem),
     "a ring with a window": ("flash_attention", lambda m: _ring(window=5)),
+    "a hyper-connected sequence that is no whole tile":
+        ("hyper_connection", lambda m: _site_hyper_connection(m, t=96)),
 }
 
 
