@@ -22,7 +22,9 @@ against the labels:
 `share` cuts what one chip of a layer group holds, at the published widths:
 `{"heads": 4, "experts": 8, "vocab": 16384, "index": 0}` is chip `index` of
 the 8 that share each layer of the 32-head, 64-expert, 131072-row model
-(nn/conf/layers/decoder.py says what a layer does with its share).
+(nn/conf/layers/decoder.py says what a layer does with its share);
+`"train_gate": False` beside them takes the chosen experts' weights as
+constants of the backward pass (`RoutedExperts`; default: trained).
 """
 from __future__ import annotations
 
@@ -126,7 +128,8 @@ class Xing4(ZooModel):
             first_expert=first, top_k=c["num_experts_per_tok"],
             width=c["moe_intermediate_size"], n_shared=c["n_shared_experts"],
             routed_scaling_factor=float(c["routed_scaling_factor"]),
-            norm_topk_prob=bool(c["norm_topk_prob"]), **self._init())
+            norm_topk_prob=bool(c["norm_topk_prob"]),
+            train_gate=bool(self.share.get("train_gate", True)), **self._init())
 
     def _block(self, g, attn: str, mlp: str, dense: bool, inp: str) -> str:
         g.add_layer(attn, self._hyper(self._attention()), inp)

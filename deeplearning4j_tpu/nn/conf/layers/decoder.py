@@ -6,7 +6,10 @@ attention with a decoupled rotary key and YaRN frequencies, the gated MLP,
 dropless sigmoid top-k routed experts beside a shared one, the
 manifold-constrained hyper-connection around a sublayer (a wrapping layer
 config, as `Bidirectional` is), the multi-token-prediction module's input
-and a softmax cross-entropy head over integer labels.
+and a softmax cross-entropy head over integer labels; the plain pre-norm
+residual block round a sublayer, the Gated DeltaNet mixer (a recurrent layer
+whose state is a matrix a head, `ops/gated_delta_rule.py`) and the gated
+attention mixer on grouped k/v heads with rotary on part of each head.
 
 Layout: these layers pass `(batch, time, features)` between them (features
 last, as the matrix unit wants them), not DL4J's `(batch, features, time)`;
@@ -51,13 +54,18 @@ _HC_MAP_SCALE_INIT = 0.1
 _HC_RES_DIAGONAL_INIT = 4.0
 
 
-def rms_norm(x, g, eps):
-    """x / rms(x) * g over the last axis, worked out in float32."""
+def rms_norm(x, g, eps, zero_centred=False):
+    """x / rms(x) * g over the last axis, worked out in float32; with
+    `zero_centred` the gain is 1 + g (g starts at 0)."""
     x32 = x.astype(_F32)
     y = x32 * lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
     if g is not None:
-        y = y * g.astype(_F32)
+        y = y * (1.0 + g.astype(_F32) if zero_centred else g.astype(_F32))
     return y.astype(x.dtype)
+
+
+def _gain(shape, zero_centred, dtype):
+    return (jnp.zeros if zero_centred else jnp.ones)(shape, dtype)
 
 
 def _time_of(input_type) -> int:
@@ -81,12 +89,13 @@ class _TokenLayer(FeedForwardLayerConf):
 @dataclass
 class RMSNorm(_TokenLayer):
     eps: float = 1e-6
+    zero_centred: bool = False      # y = x / rms(x) * (1 + g), g from 0
 
     def init_params(self, key, input_type, dtype=jnp.float32):
-        return {"g": jnp.ones((self.n_in,), dtype)}
+        return {"g": _gain((self.n_in,), self.zero_centred, dtype)}
 
     def forward(self, params, state, x, *, train, rng=None, mask=None):
-        return rms_norm(x, params["g"], self.eps), state, mask
+        return rms_norm(x, params["g"], self.eps, self.zero_centred), state, mask
 
 
 @register_layer
@@ -150,6 +159,17 @@ def apply_rope(x, inv_freq):
                            axis=-1).astype(x.dtype)
 
 
+def _dense_causal_attention(q, k, v, scale):
+    """(B, H, T, qk), (B, H, T, qk), (B, H, T, v) -> (B, H, T, v): one masked
+    softmax over all T keys, for a sequence of a tile or less."""
+    t = q.shape[2]
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                        preferred_element_type=_F32) * scale
+    scores = jnp.where(jnp.tril(jnp.ones((t, t), bool)), scores, -jnp.inf)
+    attn = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", attn, v)
+
+
 @register_layer
 @dataclass
 class LatentAttention(_TokenLayer):
@@ -211,11 +231,7 @@ class LatentAttention(_TokenLayer):
             vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad)))
             out = flash(q, k, vp, None, True, self.softmax_scale)
             return out[..., :v.shape[-1]]
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                            preferred_element_type=_F32) * self.softmax_scale
-        scores = jnp.where(jnp.tril(jnp.ones((t, t), bool)), scores, -jnp.inf)
-        attn = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-        return jnp.einsum("bhqk,bhkd->bhqd", attn, v)
+        return _dense_causal_attention(q, k, v, self.softmax_scale)
 
     def forward(self, params, state, x, *, train, rng=None, mask=None):
         b, t, _ = x.shape
@@ -237,6 +253,142 @@ class LatentAttention(_TokenLayer):
                            heads_first(kv[..., nope:]))
         out = jnp.swapaxes(out, 1, 2).reshape(b, t, h * self.v_head_dim)
         return out @ params["w_o"], state, mask
+
+
+@register_layer
+@dataclass
+class GatedAttention(_TokenLayer):
+    """Causal softmax attention on grouped k/v heads with an output gate
+    (Qwen3-Next's full-attention layer): [q, gate] = x W_q a head, k = x W_k,
+    v = x W_v on `n_kv_heads` heads, each shared by n_heads / n_kv_heads
+    query heads; a zero-centred RMS norm a head on q and on k; rotary (the
+    half-split pairing) on the first `rotary_dim` entries of each head; y =
+    (attention * sigmoid(gate)) W_o. No bias. Past a tile's length the
+    attention is the flash kernel, which reads a query head's k/v at its
+    group's row and sums dk, dv over the group: no repeat is written."""
+    n_heads: int = 16
+    n_kv_heads: int = 2
+    head_dim: int = 256
+    rotary_dim: int = 64
+    rope_theta: float = 1e7
+    eps: float = 1e-6
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        d, h, hk, hd = self.n_in, self.n_heads, self.n_kv_heads, self.head_dim
+        shapes = {"w_q": (d, h * 2 * hd), "w_k": (d, hk * hd), "w_v": (d, hk * hd),
+                  "w_o": (h * hd, self.n_out)}
+        keys = jax.random.split(key, len(shapes))
+        p = {name: self._winit(k, s, s[0], s[1], dtype)
+             for k, (name, s) in zip(keys, shapes.items())}
+        p["q_norm_g"] = _gain((hd,), True, dtype)
+        p["k_norm_g"] = _gain((hd,), True, dtype)
+        return p
+
+    def _attend(self, q, k, v):
+        """(B, H, T, hd), (B, Hk, T, hd) twice -> (B, H, T, hd)."""
+        scale = self.head_dim ** -0.5
+        from deeplearning4j_tpu.ops.helpers import helper_for
+        flash = helper_for("flash_attention", None) \
+            if q.shape[2] > _DENSE_ATTENTION_MAX_T else None
+        if flash is not None:
+            return flash(q, k, v, None, True, scale)
+        group = self.n_heads // self.n_kv_heads
+        return _dense_causal_attention(q, jnp.repeat(k, group, axis=1),
+                                       jnp.repeat(v, group, axis=1), scale)
+
+    def _rotate(self, x, inv_freq):
+        rd = self.rotary_dim
+        return jnp.concatenate([apply_rope(x[..., :rd], inv_freq), x[..., rd:]],
+                               axis=-1)
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        b, t, _ = x.shape
+        h, hk, hd = self.n_heads, self.n_kv_heads, self.head_dim
+        q, gate = jnp.split((x @ params["w_q"]).reshape(b, t, h, 2 * hd), 2, axis=-1)
+        k = (x @ params["w_k"]).reshape(b, t, hk, hd)
+        v = (x @ params["w_v"]).reshape(b, t, hk, hd)
+        inv_freq = yarn_inv_freq(self.rotary_dim, self.rope_theta, None)
+        q = self._rotate(rms_norm(q, params["q_norm_g"], self.eps, True), inv_freq)
+        k = self._rotate(rms_norm(k, params["k_norm_g"], self.eps, True), inv_freq)
+        heads_first = lambda a: jnp.swapaxes(a, 1, 2)
+        out = jnp.swapaxes(self._attend(*map(heads_first, (q, k, v))), 1, 2)
+        out = out * jax.nn.sigmoid(gate.astype(_F32)).astype(out.dtype)
+        return out.reshape(b, t, h * hd) @ params["w_o"], state, mask
+
+
+def _causal_depthwise_conv(x, w):
+    """x (B, T, C), w (width, C): y_t = sum_i w[i] x_{t - width + 1 + i}, the
+    positions before the first taken as zero."""
+    width, t = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    return sum(padded[:, i:i + t] * w[i] for i in range(width))
+
+
+@register_layer
+@dataclass
+class GatedDeltaNet(_TokenLayer):
+    """Gated DeltaNet mixer (arXiv:2412.06464; Qwen3-Next's linear-attention
+    layer): [q, k, v, z] = x W_qkvz, [b, a] = x W_ba; q, k, v through a causal
+    depthwise convolution of `conv_width` and silu; beta = sigmoid(b), g =
+    -exp(A_log) softplus(a + dt_bias) a value head (float32); q, k scaled to
+    unit length a head, q by d_k^-1/2 more, each of the `n_k_heads` key heads
+    serving n_v_heads / n_k_heads value heads; the gated delta rule
+    (`ops/gated_delta_rule.py`: one (d_k, d_v) state a value head); y =
+    (RMSNorm(o) * silu(z)) W_out, the norm a head with its own gain. The
+    columns of W_qkvz are [q | k | v | z], of W_ba [b | a]."""
+    n_k_heads: int = 16
+    n_v_heads: int = 32
+    d_k: int = 128
+    d_v: int = 128
+    conv_width: int = 4
+    eps: float = 1e-6
+
+    @property
+    def _widths(self):
+        """(q and k together, v) as the projection lays them out."""
+        return 2 * self.n_k_heads * self.d_k, self.n_v_heads * self.d_v
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        d, nv = self.n_in, self.n_v_heads
+        qk, v = self._widths
+        keys = jax.random.split(key, 5)
+        w = lambda k, *shape: self._winit(k, shape, shape[0], shape[1], dtype)
+        return {"w_qkvz": w(keys[0], d, qk + 2 * v), "w_ba": w(keys[1], d, 2 * nv),
+                "conv_w": w(keys[2], self.conv_width, qk + v),
+                # the decay's rate a head from ln U(1, 16), its bias 1 (the
+                # Mamba-2 convention the published model keeps)
+                "a_log": jnp.log(jax.random.uniform(keys[3], (nv,), dtype, 1.0, 16.0)),
+                "dt_bias": jnp.ones((nv,), dtype),
+                "o_norm_w": jnp.ones((self.d_v,), dtype),
+                "w_out": w(keys[4], v, self.n_out)}
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        from deeplearning4j_tpu.ops.gated_delta_rule import gated_delta_rule_scan
+        from deeplearning4j_tpu.ops.helpers import helper_for
+        b, t, _ = x.shape
+        nk, nv, dk, dv = self.n_k_heads, self.n_v_heads, self.d_k, self.d_v
+        qk_w, v_w = self._widths
+        proj = x @ params["w_qkvz"]
+        ba = jnp.dot(x, params["w_ba"], preferred_element_type=_F32)
+        mixed = jax.nn.silu(_causal_depthwise_conv(proj[..., :qk_w + v_w],
+                                                   params["conv_w"]))
+        q, k = jnp.split(mixed[..., :qk_w].reshape(b, t, 2 * nk, dk), 2, axis=2)
+        v = mixed[..., qk_w:].reshape(b, t, nv, dv)
+        z = proj[..., qk_w + v_w:].reshape(b, t, nv, dv)
+
+        def unit(a, scale=1.0):
+            a32 = a.astype(_F32)
+            norm = lax.rsqrt(jnp.sum(jnp.square(a32), axis=-1, keepdims=True) + 1e-6)
+            return jnp.repeat((a32 * norm * scale).astype(a.dtype), nv // nk, axis=2)
+        beta = jax.nn.sigmoid(ba[..., :nv])
+        g = -jnp.exp(params["a_log"].astype(_F32)) \
+            * jax.nn.softplus(ba[..., nv:] + params["dt_bias"].astype(_F32))
+        with jax.named_scope("delta_rule"):
+            rule = helper_for("gated_delta_rule", gated_delta_rule_scan)
+            o = rule(unit(q, dk ** -0.5), unit(k), v, g, beta)
+        y = rms_norm(o, params["o_norm_w"], self.eps).astype(_F32) \
+            * jax.nn.silu(z.astype(_F32))
+        return y.astype(x.dtype).reshape(b, t, nv * dv) @ params["w_out"], state, mask
 
 
 def _gated(x, w_g, w_u, w_d):
@@ -377,8 +529,10 @@ def _held_experts_part(first_expert, held, bound, e_w_g, e_w_u, e_w_d, u, sel, w
 @dataclass
 class RoutedExperts(_TokenLayer):
     """Dropless routed experts beside shared ones (DeepSeek-V3's `noaux_tc`
-    gate): sigmoid scores over the `n_experts` published experts, the `top_k`
-    largest of score + bias chosen, their scores normalised and scaled. The
+    gate): `scoring_func` scores (sigmoid, or a softmax over all of them) over
+    the `n_experts` published experts, the `top_k` largest of score + bias
+    chosen, their scores normalised and scaled; with `shared_gate` the shared
+    expert's part is weighted by sigmoid(u w_s) a token (Qwen's). The
     layer holds experts `[first_expert, first_expert + experts_held)` (all
     where `experts_held` is 0) and adds their part for the tokens routed to
     them: the assignments are sorted by expert and the three products of the
@@ -398,7 +552,14 @@ class RoutedExperts(_TokenLayer):
     The selection bias is a buffer (`state["router_bias"]`, no gradient leaf).
     The state also holds, written on the device by every step, the tokens each
     held expert took (`expert_load`) and the assignments that fell to absent
-    experts (`assignments_absent`)."""
+    experts (`assignments_absent`).
+
+    `train_gate` False takes the chosen experts' weights as constants of the
+    backward pass: nothing reaches the router's weights, nor the hidden state
+    through the gate. It is for a layer that holds a share and runs without
+    its exchange: in the deployment the router is trained by the group's
+    summed gradient, and the held experts' part of it, applied alone, walks
+    the router towards them or away from them (PERF.md section 7 (b))."""
     n_experts: int = 64
     experts_held: int = 0
     first_expert: int = 0
@@ -407,6 +568,14 @@ class RoutedExperts(_TokenLayer):
     n_shared: int = 1
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    scoring_func: str = "sigmoid"
+    shared_gate: bool = False
+    train_gate: bool = True
+
+    def __post_init__(self):
+        if self.scoring_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring_func {self.scoring_func!r}: sigmoid or "
+                             "softmax")
 
     @property
     def held(self) -> int:
@@ -414,7 +583,7 @@ class RoutedExperts(_TokenLayer):
 
     def init_params(self, key, input_type, dtype=jnp.float32):
         d, f, e = self.n_in, self.width, self.held
-        keys = jax.random.split(key, 7)
+        keys = jax.random.split(key, 8)
         p = {"w_r": self._winit(keys[0], (d, self.n_experts), d, self.n_experts, dtype),
              "e_w_g": self._winit(keys[1], (e, d, f), d, f, dtype),
              "e_w_u": self._winit(keys[2], (e, d, f), d, f, dtype),
@@ -425,6 +594,8 @@ class RoutedExperts(_TokenLayer):
                       "s_w_u": self._winit(keys[5], (d, fs), d, fs, dtype),
                       "s_w_d": self._winit(keys[6], (fs, self.n_out), fs,
                                            self.n_out, dtype)})
+            if self.shared_gate:
+                p["s_gate"] = self._winit(keys[7], (d, 1), d, 1, dtype)
         return p
 
     def init_state(self, input_type, dtype=jnp.float32):
@@ -434,12 +605,15 @@ class RoutedExperts(_TokenLayer):
 
     def route(self, params, state, u):
         """u (N, d) -> (chosen experts (N, k), their weights (N, k) float32)."""
-        s = jax.nn.sigmoid(jnp.dot(u, params["w_r"], preferred_element_type=_F32))
+        logits = jnp.dot(u, params["w_r"], preferred_element_type=_F32)
+        s = jax.nn.sigmoid(logits) if self.scoring_func == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)
         _, sel = lax.top_k(s + state["router_bias"].astype(_F32), self.top_k)
         w = jnp.take_along_axis(s, sel, axis=-1)
         if self.norm_topk_prob:
             w = w / jnp.sum(w, axis=-1, keepdims=True)
-        return sel, w * self.routed_scaling_factor
+        w = w * self.routed_scaling_factor
+        return sel, w if self.train_gate else lax.stop_gradient(w)
 
     def row_bound(self, assignments: int) -> int:
         """Rows a block of the routed part holds when a step has `assignments`
@@ -464,7 +638,12 @@ class RoutedExperts(_TokenLayer):
             y, sizes = self._routed(params, u, sel, w)
         if self.n_shared:
             with jax.named_scope("shared"):
-                y = y + _gated(u, params["s_w_g"], params["s_w_u"], params["s_w_d"])
+                shared = _gated(u, params["s_w_g"], params["s_w_u"], params["s_w_d"])
+                if self.shared_gate:
+                    open_ = jax.nn.sigmoid(jnp.dot(u, params["s_gate"],
+                                                   preferred_element_type=_F32))
+                    shared = (shared * open_).astype(shared.dtype)
+                y = y + shared
         new_state = dict(state, expert_load=sizes,
                          assignments_absent=(b * t * self.top_k
                                              - jnp.sum(sizes)).astype(jnp.int32))
@@ -626,6 +805,49 @@ class HyperConnection(BaseLayerConf):
         out = [sum(h_res[i, j][..., None] * streams[j] for j in range(n))
                + h_post[i][..., None] * y for i in range(n)]
         return jnp.stack(out, axis=1).astype(x.dtype), new_state, mask
+
+    def state_gauges(self, state) -> dict:
+        inner = getattr(self.layer, "state_gauges", None)
+        return inner(state) if inner else {}
+
+
+@register_layer
+@dataclass
+class PreNormResidual(BaseLayerConf):
+    """The plain pre-norm residual block round a sublayer F: x + F(norm(x)),
+    the norm an RMS norm with its own gain (`norm_g`; zero-centred by
+    default, as the models that use the block have it). A wrapping layer
+    config as `HyperConnection` is, so that norm, sublayer and add are one
+    recomputed block under one scope."""
+    layer: Optional[BaseLayerConf] = None
+    eps: float = 1e-6
+    zero_centred: bool = True
+
+    def __post_init__(self):
+        if isinstance(self.layer, dict):
+            self.layer = BaseLayerConf.from_dict(self.layer)
+
+    def set_n_in(self, input_type, override=False):
+        self.layer.set_n_in(input_type, override)
+
+    def get_output_type(self, input_type):
+        return input_type
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        p = dict(self.layer.init_params(key, input_type, dtype))
+        p["norm_g"] = _gain((input_type.size,), self.zero_centred, dtype)
+        return p
+
+    def init_state(self, input_type, dtype=jnp.float32):
+        return self.layer.init_state(input_type, dtype)
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        inner = {k: v for k, v in params.items() if k != "norm_g"}
+        u = rms_norm(x, params["norm_g"], self.eps, self.zero_centred)
+        with layer_scope(self.layer, self.name):
+            y, new_state, mask = self.layer.forward(
+                inner, state, u, train=train, rng=rng, mask=mask)
+        return x + y.astype(x.dtype), new_state, mask
 
     def state_gauges(self, state) -> dict:
         inner = getattr(self.layer, "state_gauges", None)

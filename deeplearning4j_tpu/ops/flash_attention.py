@@ -500,8 +500,9 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
                     bk: int = DEFAULT_BK, window: int = 0,
                     bwd: str | None = None, dq_partials: str | None = None):
     """q/k/v: (B, H, T, D); k/v may carry Hk | H heads (grouped-query
-    attention — forward only; the grouped backward is not implemented and
-    raises). mask: optional (B, T) key-padding mask. Returns (B, H, T, D).
+    attention: the repeat is never written, forward or backward; dk and dv
+    are summed over each group's query heads). mask: optional (B, T)
+    key-padding mask. Returns (B, H, T, D).
     Fused online-softmax attention; see module docstring. `window` > 0 =
     sliding-window (local) attention: causal keeps the trailing window
     qi-window < kj <= qi; non-causal keeps the symmetric band |qi-kj| <
@@ -536,17 +537,6 @@ def _fa_bwd_impl(causal, scale, bq, bk, saved, dout, dlse, window=0,
     if dq_partials is None:
         dq_partials = _CONFIG["dq_partials"]
     q, k, v, mask, o, L = saved
-    if k.shape[1] != q.shape[1]:
-        # the kernels below index the (B*Hk, ...) k/v buffers with the
-        # q-head grid index and would return dk/dv with the q aval —
-        # silently wrong for grouped-query attention. The grouped backward
-        # (head-group segment-sum of dk/dv partials) is not implemented;
-        # GQA TRAINING paths must broadcast k/v to full heads first (what
-        # SelfAttentionLayer does), GQA INFERENCE may use this forward.
-        raise NotImplementedError(
-            f"flash_attention backward with grouped k/v heads "
-            f"(H={q.shape[1]}, Hk={k.shape[1]}) is not implemented; "
-            "repeat k/v to the full head count before differentiating")
     B, H, T, D = q.shape
     bq, bk = _resolve_blocks(bq, bk, T)
     scale_ = float(scale) if scale is not None else 1.0 / float(np.sqrt(D))
@@ -563,6 +553,16 @@ def _fa_bwd_impl(causal, scale, bq, bk, saved, dout, dlse, window=0,
         Di = Di - dl
     BH = B * H
     nq, nk = Tp // bq, Tp // bk
+    # grouped k/v heads: the kernels read a query head's k/v at its group's
+    # row (the repeat is never written) and write dk/dv a QUERY head; the
+    # group's heads are summed below
+    Hk = k.shape[1]
+    kv = _kv_row(H, Hk)
+
+    def shp_kv(a):
+        a = a[:, :T].reshape(B, Hk, H // Hk, T, D)
+        return a[:, :, 0] if H == Hk else \
+            jnp.sum(a.astype(acc_dt), axis=2).astype(a.dtype)
     if bwd == "fused":
         dqp_dt = acc_dt if dq_partials == "acc" else q.dtype
         # the dq-partials buffer is O(T^2 * D / bk) — above the HBM cap the
@@ -573,13 +573,14 @@ def _fa_bwd_impl(causal, scale, bq, bk, saved, dout, dlse, window=0,
     if bwd == "fused":
         qspec2 = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0))
         kspec2 = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0))
+        kin2 = pl.BlockSpec((1, bk, D), lambda b, j, i: (kv(b), j, 0))
         dk, dv, dqp = pl.pallas_call(
             functools.partial(_fused_bwd_kernel, causal=causal, scale=scale_,
                               bq=bq, bk=bk, T=T, Tp=Tp,
                               has_mask=mask is not None, acc_dt=acc_dt,
                               window=window),
             grid=(BH, nk, nq),
-            in_specs=[qspec2, kspec2, kspec2,
+            in_specs=[qspec2, kin2, kin2,
                       pl.BlockSpec((1, 1, Tp), lambda b, j, i: (b, 0, 0)),
                       qspec2,
                       pl.BlockSpec((1, 1, Tp), lambda b, j, i: (b, 0, 0)),
@@ -597,9 +598,9 @@ def _fa_bwd_impl(causal, scale, bq, bk, saved, dout, dlse, window=0,
         dq = jnp.sum(dqp.astype(acc_dt), axis=1).astype(q.dtype)
         shp = lambda a: a[:, :T].reshape(B, H, T, D)
         dmask = None if mask is None else jnp.zeros_like(mask)
-        return shp(dq), shp(dk), shp(dv), dmask
+        return shp(dq), shp_kv(dk), shp_kv(dv), dmask
     qspec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0))
+    kspec = pl.BlockSpec((1, bk, D), lambda b, i, j: (kv(b), j, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, scale=scale_,
                           bq=bq, bk=bk, T=T, Tp=Tp,
@@ -619,13 +620,14 @@ def _fa_bwd_impl(causal, scale, bq, bk, saved, dout, dlse, window=0,
     # dk/dv: q index fastest — grid (BH, nk, nq)
     qspec2 = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0))
     kspec2 = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0))
+    kin2 = pl.BlockSpec((1, bk, D), lambda b, j, i: (kv(b), j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, scale=scale_,
                           bq=bq, bk=bk, T=T, Tp=Tp,
                           has_mask=mask is not None, acc_dt=acc_dt,
                           window=window),
         grid=(BH, nk, nq),
-        in_specs=[qspec2, kspec2, kspec2,
+        in_specs=[qspec2, kin2, kin2,
                   pl.BlockSpec((1, 1, Tp), lambda b, j, i: (b, 0, 0)),
                   qspec2,
                   pl.BlockSpec((1, 1, Tp), lambda b, j, i: (b, 0, 0)),
@@ -639,7 +641,7 @@ def _fa_bwd_impl(causal, scale, bq, bk, saved, dout, dlse, window=0,
     )(qp, kp, vp, km, dop, L, Di)
     shp = lambda a: a[:, :T].reshape(B, H, T, D)
     dmask = None if mask is None else jnp.zeros_like(mask)
-    return shp(dq), shp(dk), shp(dv), dmask
+    return shp(dq), shp_kv(dk), shp_kv(dv), dmask
 
 
 _flash_core.defvjp(_fa_fwd, _fa_bwd)

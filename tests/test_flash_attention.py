@@ -355,16 +355,28 @@ def test_gqa_forward_matches_dense_oracle(causal, hk):
     np.testing.assert_allclose(np.asarray(out), np.asarray(full), atol=1e-10)
 
 
-def test_gqa_backward_raises_not_implemented():
-    """The grouped backward is a known hole: the kernels would index the
-    (B*Hk, ...) buffers with the q-head grid index and return dk/dv with
-    the wrong aval. It must fail LOUDLY, not silently corrupt gradients."""
-    B, H, T, D = 1, 4, 16, 8
+@pytest.mark.parametrize("bwd", ["fused", "two_pass"])
+@pytest.mark.parametrize("hk,causal", [(2, True), (1, True), (2, False)])
+def test_gqa_backward_sums_each_groups_query_heads(bwd, hk, causal):
+    """Grouped k/v heads train through the kernels: k/v are read at the
+    group's row (no repeat is written), dk and dv come back a query head and
+    are summed over each group. Against the dense path on repeated k/v, whose
+    transpose makes the same sum (interpreted; float64, so the two differ by
+    the order of summation only)."""
+    B, H, T, D = 2, 4, 20, 8
     q = jnp.asarray(RNG.randn(B, H, T, D))
-    k, v = (jnp.asarray(RNG.randn(B, 2, T, D)) for _ in range(2))
-    with pytest.raises(NotImplementedError, match="grouped"):
-        jax.grad(lambda q: jnp.sum(flash_attention(q, k, v, None, True,
-                                                   None, 8, 8)))(q)
+    k, v = (jnp.asarray(RNG.randn(B, hk, T, D)) for _ in range(2))
+    w = jnp.asarray(RNG.randn(B, H, T, D))
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(attend(q, k, v) * w)
+    got = jax.grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, None, causal, None, 8, 8, bwd=bwd)), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: flash_attention_reference(
+        q, k, v, None, causal)), argnums=(0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        assert g.shape == r.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=1e-10)
 
 
 def test_gqa_layer_trains_and_roundtrips():

@@ -16,7 +16,7 @@ from deeplearning4j_tpu.ops.helpers import (
 
 SHIPPED = {"graves_lstm_scan", "flash_attention", "grouped_matmul",
            "decode_attention_paged", "decode_attention_spec_paged",
-           "hyper_connection"}
+           "hyper_connection", "gated_delta_rule"}
 
 
 @pytest.fixture(autouse=True)
@@ -275,6 +275,15 @@ def _site_hyper_connection(monkeypatch, t=128):
 # against its fallback: float64 but where the fallback (the latent
 # attention's scores) or the kernel (the grouped product, the hyper-connection)
 # is float32)
+def _site_gated_delta_net(monkeypatch):
+    from deeplearning4j_tpu.nn.conf.layers.decoder import GatedDeltaNet
+    layer = GatedDeltaNet(n_in=16, n_out=16, n_k_heads=2, n_v_heads=4, d_k=8,
+                          d_v=8)
+    params = layer.init_params(jax.random.PRNGKey(0), None, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 70, 16), jnp.float32)
+    return layer.forward(params, {}, x, train=True)[0]
+
+
 SITES = {
     "LSTM._scan": ("graves_lstm_scan", _site_lstm_scan, 1e-10),
     "SelfAttentionLayer.forward": ("flash_attention", _site_self_attention,
@@ -289,6 +298,7 @@ SITES = {
                                     _site_decode_spec_paged, 1e-12),
     "HyperConnection.forward": ("hyper_connection", _site_hyper_connection,
                                 1e-5),
+    "GatedDeltaNet.forward": ("gated_delta_rule", _site_gated_delta_net, 1e-5),
 }
 
 
