@@ -3,8 +3,9 @@
 
 Drives the paths a default user hits — the trainer on the zoo ResNet50, the
 training kernels behind the helper seam (flash attention, fused Graves-LSTM
-scan, and in the zoo's sparse decoder the hyper-connection's calls and the
-grouped product) and the serving engine with its paged flash-decode kernel —
+scan, in the zoo's sparse decoder the hyper-connection's calls and the
+grouped product, and the hybrid decoder's Gated DeltaNet layer on the gated
+delta rule's kernels) and the serving engine with its paged flash-decode kernel —
 once each, through the normal entry points, at the widths of the
 benchmark's configurations (`benchmark/configs/`: ResNet50 and the LSTM; the
 attention and serving nets have no cell yet and keep the widths their phase
@@ -45,7 +46,7 @@ import threading
 import time
 
 PHASES = ("train_resnet50", "train_attention", "train_graves_lstm",
-          "train_decoder", "serve", "multichip")
+          "train_decoder", "delta_net", "serve", "multichip")
 EXIT_NO_ACCELERATOR = 4
 # the driver allows 1200 s in all, compilation included
 DEADLINE_S = 1140.0
@@ -338,6 +339,59 @@ def phase_train_decoder(seq_len=1024, hidden=512, heads=4, experts=8,
         f"decoder: the seam answered {out['seam']} for six hyper-connections"
     _rate(seq_len * steps, out["warm_call_s"], "tokens/s in the warm call")
     return out
+
+
+def phase_delta_net(batch=1, seq_len=1280, hidden=2048, k_heads=16, v_heads=32,
+                    width=128, compute_dtype="bfloat16"):
+    """One `GatedDeltaNet` layer at the Qwen3-Next share's widths, value and
+    gradients, the gated delta rule as its two Mosaic kernels (ten chunks a
+    sequence, two tiles of the grid) against the same layer on the token
+    scan; the seam has to have answered "kernel" for the one and to have been
+    asked for the other."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deeplearning4j_tpu.nn.conf.layers.decoder import GatedDeltaNet
+    from deeplearning4j_tpu.ops.helpers import helpers_enabled_ctx
+    layer = GatedDeltaNet(n_in=hidden, n_out=hidden, n_k_heads=k_heads,
+                          n_v_heads=v_heads, d_k=width, d_v=width)
+    dtype = jnp.dtype(compute_dtype)
+    keys = jax.random.split(jax.random.PRNGKey(42), 3)
+    params = layer.init_params(keys[0], None, dtype)
+    x = jax.random.normal(keys[1], (batch, seq_len, hidden), dtype)
+    weigh = jax.random.normal(keys[2], x.shape, jnp.float32)
+
+    def loss(p, x_):
+        out = layer.forward(p, {}, x_, train=True)[0]
+        return jnp.mean(jnp.square(out.astype(jnp.float32) - weigh))
+
+    def side(policy, mosaic, what):
+        before = _helper_counts("gated_delta_rule")
+        with helpers_enabled_ctx(policy):
+            step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))
+            _assert_mosaic(step.lower(params, x).as_text(), mosaic, what)
+            got = jax.block_until_ready(step(params, x))
+        after = _helper_counts("gated_delta_rule")
+        return got, {k: after[k] - before[k] for k in after}
+    kernel, seam = side(_kernel_policy(), True, "delta_net")
+    scan, seam_off = side(False, False, "delta_net (helpers off)")
+    assert seam["kernel"] >= 1 and seam["fallback"] == 0, \
+        f"delta_net: the seam answered {seam} for one layer"
+    assert seam_off["kernel"] == 0 and seam_off["fallback"] >= 1, seam_off
+    _finite([kernel[0]], "delta_net")
+    # bf16: both sides round every product's operands, in another order
+    far = {}
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(kernel),
+                            jax.tree.leaves(scan)):
+        a, b = (np.asarray(v, np.float64) for v in (a, b))
+        far[jax.tree_util.keystr(path)] = float(
+            np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+    worst = max(far, key=far.get)
+    assert far[worst] <= (3e-2 if dtype.itemsize < 4 else 1e-4), \
+        f"delta_net: {worst} is {far[worst]:.3e} off the token scan's"
+    return {"loss": float(kernel[0]), "loss_scan": float(scan[0]),
+            "farthest": [worst, far[worst]], "seam": seam}
 
 
 def _serve(net, helpers, prompts, new_tokens, max_seqs, max_len):

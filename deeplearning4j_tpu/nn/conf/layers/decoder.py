@@ -333,7 +333,8 @@ class GatedDeltaNet(_TokenLayer):
     -exp(A_log) softplus(a + dt_bias) a value head (float32); q, k scaled to
     unit length a head, q by d_k^-1/2 more, each of the `n_k_heads` key heads
     serving n_v_heads / n_k_heads value heads; the gated delta rule
-    (`ops/gated_delta_rule.py`: one (d_k, d_v) state a value head); y =
+    (`ops/gated_delta_rule.py`: one (d_k, d_v) state a value head; its
+    kernels where `heads_a_step` takes the heads, else the token scan); y =
     (RMSNorm(o) * silu(z)) W_out, the norm a head with its own gain. The
     columns of W_qkvz are [q | k | v | z], of W_ba [b | a]."""
     n_k_heads: int = 16
@@ -363,7 +364,8 @@ class GatedDeltaNet(_TokenLayer):
                 "w_out": w(keys[4], v, self.n_out)}
 
     def forward(self, params, state, x, *, train, rng=None, mask=None):
-        from deeplearning4j_tpu.ops.gated_delta_rule import gated_delta_rule_scan
+        from deeplearning4j_tpu.ops.gated_delta_rule import (
+            gated_delta_rule_scan, heads_a_step)
         from deeplearning4j_tpu.ops.helpers import helper_for
         b, t, _ = x.shape
         nk, nv, dk, dv = self.n_k_heads, self.n_v_heads, self.d_k, self.d_v
@@ -379,12 +381,14 @@ class GatedDeltaNet(_TokenLayer):
         def unit(a, scale=1.0):
             a32 = a.astype(_F32)
             norm = lax.rsqrt(jnp.sum(jnp.square(a32), axis=-1, keepdims=True) + 1e-6)
-            return jnp.repeat((a32 * norm * scale).astype(a.dtype), nv // nk, axis=2)
+            return (a32 * norm * scale).astype(a.dtype)
         beta = jax.nn.sigmoid(ba[..., :nv])
         g = -jnp.exp(params["a_log"].astype(_F32)) \
             * jax.nn.softplus(ba[..., nv:] + params["dt_bias"].astype(_F32))
         with jax.named_scope("delta_rule"):
-            rule = helper_for("gated_delta_rule", gated_delta_rule_scan)
+            rule = gated_delta_rule_scan
+            if heads_a_step(nk, nv, dk, dv, q.dtype.itemsize) is not None:
+                rule = helper_for("gated_delta_rule", rule)
             o = rule(unit(q, dk ** -0.5), unit(k), v, g, beta)
         y = rms_norm(o, params["o_norm_w"], self.eps).astype(_F32) \
             * jax.nn.silu(z.astype(_F32))

@@ -153,6 +153,15 @@ def test_phase_train_decoder_tiny():
     assert out["seam"]["kernel"] >= 6 and out["seam"]["fallback"] == 6
 
 
+def test_phase_delta_net_tiny():
+    # float32: the CPU's runtime has no bf16 x bf16 -> f32 product
+    out = chip_smoke.phase_delta_net(batch=2, seq_len=40, hidden=16, k_heads=2,
+                                     v_heads=4, width=8,
+                                     compute_dtype="float32")
+    assert out["seam"] == {"kernel": 1, "fallback": 0}
+    assert out["farthest"][1] <= 1e-4
+
+
 def test_phase_serve_tiny():
     out = chip_smoke.phase_serve(
         d_model=32, heads=4, kv_heads=2, vocab=16, max_seqs=4, max_len=64,

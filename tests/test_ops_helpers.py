@@ -333,6 +333,14 @@ def _refused_by_vmem(monkeypatch):
     return layer._scan(params, x, None)
 
 
+def _refused_by_width(monkeypatch):
+    """Compiled for the chip the rule's kernels want whole lane tiles a head;
+    interpreted they take any width."""
+    from deeplearning4j_tpu.ops import helpers
+    monkeypatch.setattr(helpers, "interpret_mode", lambda: False)
+    return _site_gated_delta_net(monkeypatch)
+
+
 # (the masked LSTM is tests/test_lstm_scan_fused.py::
 # test_masked_sequences_keep_the_scan_path)
 REFUSED = {
@@ -342,6 +350,8 @@ REFUSED = {
     "a ring with a window": ("flash_attention", lambda m: _ring(window=5)),
     "a hyper-connected sequence that is no whole tile":
         ("hyper_connection", lambda m: _site_hyper_connection(m, t=96)),
+    "a delta net whose heads are no whole lane tiles, compiled":
+        ("gated_delta_rule", _refused_by_width),
 }
 
 

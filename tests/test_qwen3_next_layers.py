@@ -8,9 +8,11 @@ weights, at tiny widths.
 Tolerances: both sides work in float32 here (compute type float32, no
 kernel), so they differ by the order of summation only: 2e-5 relative to the
 largest entry for activations and gradients, 1e-5 between the chunked
-recurrence and the token-by-token scan (the chunked form solves a triangular
-system and sums a chunk's decays as `exp` of differences where the scan
-multiplies them one by one: rounding of a few float32 ulps a chunk); 1e-5 on
+recurrence's kernels (interpreted here) and the token-by-token scan (the
+chunked form inverts a triangular system and takes a decay as the `exp` of the
+sum of the g it spans where the scan multiplies them one by one: rounding of a
+few float32 ulps a chunk), and between a chunk's T, W, U_0 and a float64
+solve, whatever type the keys and values come in; 1e-5 on
 the parameters after three Adam steps (leaves whose gradient is rounding
 noise are left out, as in test_decoder_layers.py).
 """
@@ -90,32 +92,60 @@ def setup():
 
 
 # ------------------------------------------------------------ the recurrence
-def _rule_inputs(t=37, b=2, h=3, d_k=8, d_v=6, dtype=jnp.float32):
+def _rule_inputs(t=37, b=2, h=3, d_k=8, d_v=6, dtype=jnp.float32, h_k=None):
     ks = jax.random.split(jax.random.PRNGKey(3), 5)
     unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
     draw = lambda key, *shape: jax.random.normal(key, shape, jnp.float32)
-    q = unit(draw(ks[0], b, t, h, d_k)) * d_k ** -0.5
-    k = unit(draw(ks[1], b, t, h, d_k))
+    q = unit(draw(ks[0], b, t, h_k or h, d_k)) * d_k ** -0.5
+    k = unit(draw(ks[1], b, t, h_k or h, d_k))
     v = draw(ks[2], b, t, h, d_v)
     g = -2.0 * jax.nn.softplus(draw(ks[3], b, t, h))
     beta = jax.nn.sigmoid(draw(ks[4], b, t, h))
     return tuple(a.astype(dtype) for a in (q, k, v)) + (g, beta)
 
 
+def _value_and_grads(rule, args):
+    w = _tokens(9, *args[2].shape)
+    return jax.value_and_grad(lambda *a: jnp.sum(rule(*a) * w),
+                              argnums=(0, 1, 2, 3, 4))(*args)
+
+
+def _kernel_matches(args, chunk, reference=gdr.gated_delta_rule_scan):
+    """The kernels (interpreted) against the scan: values and all five
+    gradients, float32 at 1e-5."""
+    _close(gdr.gated_delta_rule(*args, chunk), reference(*args), 1e-5)
+    got = _value_and_grads(lambda *a: gdr.gated_delta_rule(*a, chunk), args)[1]
+    for a, b in zip(got, _value_and_grads(reference, args)[1]):
+        _close(a, b, 1e-5)
+
+
 @pytest.mark.parametrize("chunk", [4, 16])
 def test_chunked_recurrence_matches_the_token_by_token_scan(chunk):
     """Values and every gradient, at a length (37) that is no whole number
-    of chunks of either size."""
-    args = _rule_inputs()
-    want = gdr.gated_delta_rule_scan(*args)
-    _close(gdr.gated_delta_rule_chunked(*args, chunk), want, 1e-5)
-    w = _tokens(9, *want.shape)
-    loss = lambda rule: lambda *a: jnp.sum(rule(*a) * w)
-    got = jax.grad(loss(lambda *a: gdr.gated_delta_rule_chunked(*a, chunk)),
-                   argnums=(0, 1, 2, 3, 4))(*args)
-    ref_grads = jax.grad(loss(gdr.gated_delta_rule_scan), argnums=(0, 1, 2, 3, 4))(*args)
-    for a, b in zip(got, ref_grads):
-        _close(a, b, 1e-5)
+    of chunks of either size; at 4 a sequence is two tiles of the grid, so the
+    state and its cotangent cross a tile's border in VMEM, and the second
+    sequence starts from zero."""
+    _kernel_matches(_rule_inputs(), chunk)
+
+
+def test_grouped_key_heads_against_the_scan_on_repeated_q_and_k():
+    """4 value heads on 2 key heads: the kernel maps a value head to its key
+    head, and dq, dk come summed over the group."""
+    args = _rule_inputs(h=4, h_k=2)
+
+    def repeated(q, k, *rest):
+        return gdr.gated_delta_rule_scan(jnp.repeat(q, 2, axis=2),
+                                         jnp.repeat(k, 2, axis=2), *rest)
+    _kernel_matches(args, 4, repeated)
+    _close(gdr.gated_delta_rule_scan(*args), repeated(*args), 0.0)
+
+
+def test_the_cells_widths_over_chunk_and_tile_borders():
+    """d_k = d_v = 128 in the chunks the layer gets, two sequences of ten
+    chunks (nine and a few tokens): two tiles of the grid, two value heads on
+    their key head."""
+    _kernel_matches(_rule_inputs(t=9 * gdr.CHUNK + 5, h=2, h_k=1, d_k=128,
+                                 d_v=128), gdr.CHUNK)
 
 
 def test_the_scan_is_the_references_recurrence():
@@ -128,20 +158,68 @@ def test_strong_decays_stay_finite_in_the_chunked_form():
     one chunk; only differences are ever exponentiated."""
     q, k, v, g, beta = _rule_inputs(t=64)
     g = jnp.full_like(g, -40.0)
-    out = gdr.gated_delta_rule_chunked(q, k, v, g, beta, 64)
+    out = gdr.gated_delta_rule(q, k, v, g, beta, 64)
     assert bool(jnp.all(jnp.isfinite(out)))
     _close(out, gdr.gated_delta_rule_scan(q, k, v, g, beta), 1e-5)
-    grads = jax.grad(lambda *a: jnp.sum(gdr.gated_delta_rule_chunked(*a, 64)),
+    grads = jax.grad(lambda *a: jnp.sum(gdr.gated_delta_rule(*a, 64)),
                      argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
     assert all(bool(jnp.all(jnp.isfinite(a))) for a in grads)
 
 
 def test_bfloat16_operands_keep_the_state_in_float32():
     args = _rule_inputs(t=48, dtype=jnp.bfloat16)
-    out = gdr.gated_delta_rule_chunked(*args, 16)
+    out = gdr.gated_delta_rule(*args, 16)
     assert out.dtype == jnp.bfloat16
     want = gdr.gated_delta_rule_scan(*(a.astype(jnp.float32) for a in args))
     _close(out.astype(jnp.float32), want, 0.03)
+    grads = _value_and_grads(lambda *a: gdr.gated_delta_rule(*a, 16), args)[1]
+    assert [a.dtype for a in grads] == [a.dtype for a in args]
+
+
+def _one_chunk(dtype, alike, rates, chunk=128, d=16):
+    """A chunk of one head whose keys share `alike` of a common direction and
+    whose log-decays are about -`rates[0]` a token in its first half and
+    -`rates[1]` in its second; k and v rounded to `dtype`."""
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    draw = lambda key, *shape: jax.random.normal(key, shape, jnp.float32)
+    k = alike * draw(ks[0], 1, d) + (1 - alike) * draw(ks[1], chunk, d)
+    k = (k / jnp.linalg.norm(k, axis=-1, keepdims=True)).astype(dtype)
+    v = draw(ks[2], chunk, d).astype(dtype)
+    g = -jnp.repeat(jnp.asarray(rates, jnp.float32), chunk // 2) \
+        * jax.random.uniform(ks[3], (1, chunk), jnp.float32, 0.5, 1.5)
+    beta = jax.random.uniform(ks[4], (1, chunk), jnp.float32, 0.4, 0.8)
+    return k, v, g, beta
+
+
+@pytest.mark.parametrize("alike,rates", [(0.8, (0.01, 0.01)), (0.5, (30.0, 0.1))],
+                         ids=["keys alike, weak decays", "strong decays first"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=lambda t: jnp.dtype(t).name)
+def test_a_chunks_solve_is_a_float32_solve_whatever_the_operands_type(
+        dtype, alike, rates):
+    """T, W and U_0 as the kernels make them (the same functions, on arrays)
+    against a float64 triangular solve of the same system: the decays, the
+    system and its inverse are float32 where k and v come in bfloat16 too.
+    Keys that are alike under weak decays make a system whose powers grow
+    without bound while its inverse stays small; after a half chunk of strong
+    decays the cumulative sums are in the thousands, and the decays between
+    the neighbours that follow are their differences."""
+    from jax.scipy.linalg import solve_triangular
+    k, v, g, beta = _one_chunk(dtype, alike, rates)
+    masks = gdr._masks(k.shape[0])
+    decays = gdr._decays(g, masks)
+    _, inverse, w, u0 = gdr._solved(k, v, gdr._mm(k, k, gdr._NT), beta, decays,
+                                    masks)
+    k64, v64, g64, beta64 = (np.asarray(a, np.float64) for a in (k, v, g, beta))
+    total = np.cumsum(g64[0])
+    decay = np.exp(np.minimum(total[:, None] - total[None, :], 0.0))
+    system = np.tril(beta64[0][:, None] * decay * (k64 @ k64.T), -1)
+    _close(decays[0], np.tril(decay), 1e-6)
+    eye = np.eye(len(total))
+    want = np.asarray(solve_triangular(eye + system, eye, lower=True))
+    _close(inverse, want, 1e-5)
+    _close(w, want @ ((beta64[0] * np.exp(total))[:, None] * k64), 1e-5)
+    _close(u0, want @ (beta64[0][:, None] * v64), 1e-5)
 
 
 # ------------------------------------------------------------ layer by layer
@@ -155,8 +233,8 @@ def test_gated_delta_net_matches_the_reference(setup):
 
 
 def test_gated_delta_net_through_the_chunked_form(setup):
-    """The seam forced: the layer's chunked path (interpreted nowhere: it is
-    JAX) against the same layer on the scan, value and gradient."""
+    """The seam forced: the layer on the kernels (interpreted) against the
+    same layer on the scan, value and gradient."""
     params, _, _, net = setup
     layer = net.conf.nodes["b1_mix"].conf.layer
     p, u = _of(params, "b1_mix"), _tokens(4, 2, 24, CFG["hidden_size"])
