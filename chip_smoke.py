@@ -342,12 +342,27 @@ def phase_train_decoder(seq_len=1024, hidden=512, heads=4, experts=8,
 
 
 def phase_delta_net(batch=1, seq_len=1280, hidden=2048, k_heads=16, v_heads=32,
-                    width=128, compute_dtype="bfloat16"):
+                    width=128, compute_dtype="bfloat16",
+                    second=(3840, 15, 96, 192)):
     """One `GatedDeltaNet` layer at the Qwen3-Next share's widths, value and
     gradients, the gated delta rule as its two Mosaic kernels (ten chunks a
     sequence, two tiles of the grid) against the same layer on the token
     scan; the seam has to have answered "kernel" for the one and to have been
-    asked for the other."""
+    asked for the other. Then the same at `second` = (hidden, heads, key
+    width, value width), the Olmo-Hybrid share's: one value head a key head,
+    widths that are no whole lane tiles (96 and 192), a write strength up to
+    2, and a grid step's last block of heads reaching past the fifteenth."""
+    out = _delta_net_layer(batch, seq_len, hidden, k_heads, v_heads, width, width,
+                           1.0, compute_dtype, "delta_net")
+    if second:
+        d, heads, d_k, d_v = second
+        out["second"] = _delta_net_layer(batch, seq_len, d, heads, heads, d_k, d_v,
+                                         2.0, compute_dtype, f"delta_net {d_k}/{d_v}")
+    return out
+
+
+def _delta_net_layer(batch, seq_len, hidden, k_heads, v_heads, d_k, d_v,
+                     beta_scale, compute_dtype, what):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -355,7 +370,8 @@ def phase_delta_net(batch=1, seq_len=1280, hidden=2048, k_heads=16, v_heads=32,
     from deeplearning4j_tpu.nn.conf.layers.decoder import GatedDeltaNet
     from deeplearning4j_tpu.ops.helpers import helpers_enabled_ctx
     layer = GatedDeltaNet(n_in=hidden, n_out=hidden, n_k_heads=k_heads,
-                          n_v_heads=v_heads, d_k=width, d_v=width)
+                          n_v_heads=v_heads, d_k=d_k, d_v=d_v,
+                          beta_scale=beta_scale)
     dtype = jnp.dtype(compute_dtype)
     keys = jax.random.split(jax.random.PRNGKey(42), 3)
     params = layer.init_params(keys[0], None, dtype)
@@ -366,20 +382,20 @@ def phase_delta_net(batch=1, seq_len=1280, hidden=2048, k_heads=16, v_heads=32,
         out = layer.forward(p, {}, x_, train=True)[0]
         return jnp.mean(jnp.square(out.astype(jnp.float32) - weigh))
 
-    def side(policy, mosaic, what):
+    def side(policy, mosaic, which):
         before = _helper_counts("gated_delta_rule")
         with helpers_enabled_ctx(policy):
             step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))
-            _assert_mosaic(step.lower(params, x).as_text(), mosaic, what)
+            _assert_mosaic(step.lower(params, x).as_text(), mosaic, which)
             got = jax.block_until_ready(step(params, x))
         after = _helper_counts("gated_delta_rule")
         return got, {k: after[k] - before[k] for k in after}
-    kernel, seam = side(_kernel_policy(), True, "delta_net")
-    scan, seam_off = side(False, False, "delta_net (helpers off)")
+    kernel, seam = side(_kernel_policy(), True, what)
+    scan, seam_off = side(False, False, what + " (helpers off)")
     assert seam["kernel"] >= 1 and seam["fallback"] == 0, \
-        f"delta_net: the seam answered {seam} for one layer"
+        f"{what}: the seam answered {seam} for one layer"
     assert seam_off["kernel"] == 0 and seam_off["fallback"] >= 1, seam_off
-    _finite([kernel[0]], "delta_net")
+    _finite([kernel[0]], what)
     # bf16: both sides round every product's operands, in another order
     far = {}
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(kernel),
@@ -389,7 +405,7 @@ def phase_delta_net(batch=1, seq_len=1280, hidden=2048, k_heads=16, v_heads=32,
             np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
     worst = max(far, key=far.get)
     assert far[worst] <= (3e-2 if dtype.itemsize < 4 else 1e-4), \
-        f"delta_net: {worst} is {far[worst]:.3e} off the token scan's"
+        f"{what}: {worst} is {far[worst]:.3e} off the token scan's"
     return {"loss": float(kernel[0]), "loss_scan": float(scan[0]),
             "farthest": [worst, far[worst]], "seam": seam}
 

@@ -4,6 +4,7 @@ from deeplearning4j_tpu.models.facenet_nn4_small2 import FaceNetNN4Small2
 from deeplearning4j_tpu.models.googlenet import GoogLeNet
 from deeplearning4j_tpu.models.inception_resnet_v1 import InceptionResNetV1
 from deeplearning4j_tpu.models.lenet import LeNet
+from deeplearning4j_tpu.models.olmo_hybrid import OlmoHybrid
 from deeplearning4j_tpu.models.qwen3_next import Qwen3Next
 from deeplearning4j_tpu.models.resnet50 import ResNet50
 from deeplearning4j_tpu.models.simple_cnn import SimpleCNN, TextGenerationLSTM

@@ -7,9 +7,11 @@ dropless sigmoid top-k routed experts beside a shared one, the
 manifold-constrained hyper-connection around a sublayer (a wrapping layer
 config, as `Bidirectional` is), the multi-token-prediction module's input
 and a softmax cross-entropy head over integer labels; the plain pre-norm
-residual block round a sublayer, the Gated DeltaNet mixer (a recurrent layer
-whose state is a matrix a head, `ops/gated_delta_rule.py`) and the gated
-attention mixer on grouped k/v heads with rotary on part of each head.
+residual block round a sublayer (the norm on the sublayer's input, or on its
+output as the Olmo family has it), the Gated DeltaNet mixer (a recurrent layer
+whose state is a matrix a head, `ops/gated_delta_rule.py`) and the softmax
+attention mixer on grouped k/v heads (with an output gate and rotary on part
+of each head, or with neither).
 
 Layout: these layers pass `(batch, time, features)` between them (features
 last, as the matrix unit wants them), not DL4J's `(batch, features, time)`;
@@ -258,30 +260,41 @@ class LatentAttention(_TokenLayer):
 @register_layer
 @dataclass
 class GatedAttention(_TokenLayer):
-    """Causal softmax attention on grouped k/v heads with an output gate
-    (Qwen3-Next's full-attention layer): [q, gate] = x W_q a head, k = x W_k,
+    """Causal softmax attention on grouped k/v heads, with an output gate
+    (Qwen3-Next's full-attention layer) or without (`output_gate` False:
+    Olmo's): [q, gate] = x W_q a head (q = x W_q without the gate), k = x W_k,
     v = x W_v on `n_kv_heads` heads, each shared by n_heads / n_kv_heads
-    query heads; a zero-centred RMS norm a head on q and on k; rotary (the
-    half-split pairing) on the first `rotary_dim` entries of each head; y =
-    (attention * sigmoid(gate)) W_o. No bias. Past a tile's length the
-    attention is the flash kernel, which reads a query head's k/v at its
-    group's row and sums dk, dv over the group: no repeat is written."""
+    query heads; an RMS norm on q and on k, a head with one gain of
+    `head_dim` or, with `qk_norm_whole`, over the projection's whole width
+    with a gain of that width (Olmo 2's), the gains zero-centred or from 1;
+    rotary (the half-split pairing) on the first `rotary_dim` entries of
+    each head (0: none); y = (attention * sigmoid(gate)) W_o. No bias. Past a
+    tile's length the attention is the flash kernel, which reads a query
+    head's k/v at its group's row and sums dk, dv over the group: no repeat
+    is written. Where a chip holds a share of a model's heads, `n_heads` and
+    `n_kv_heads` are the held ones: the output is their part of the sum over
+    heads, and a norm over the whole width runs over the held columns."""
     n_heads: int = 16
     n_kv_heads: int = 2
     head_dim: int = 256
     rotary_dim: int = 64
     rope_theta: float = 1e7
     eps: float = 1e-6
+    output_gate: bool = True
+    qk_norm_whole: bool = False
+    zero_centred: bool = True
 
     def init_params(self, key, input_type, dtype=jnp.float32):
         d, h, hk, hd = self.n_in, self.n_heads, self.n_kv_heads, self.head_dim
-        shapes = {"w_q": (d, h * 2 * hd), "w_k": (d, hk * hd), "w_v": (d, hk * hd),
+        shapes = {"w_q": (d, h * (2 if self.output_gate else 1) * hd),
+                  "w_k": (d, hk * hd), "w_v": (d, hk * hd),
                   "w_o": (h * hd, self.n_out)}
         keys = jax.random.split(key, len(shapes))
         p = {name: self._winit(k, s, s[0], s[1], dtype)
              for k, (name, s) in zip(keys, shapes.items())}
-        p["q_norm_g"] = _gain((hd,), True, dtype)
-        p["k_norm_g"] = _gain((hd,), True, dtype)
+        whole = self.qk_norm_whole
+        p["q_norm_g"] = _gain((h * hd if whole else hd,), self.zero_centred, dtype)
+        p["k_norm_g"] = _gain((hk * hd if whole else hd,), self.zero_centred, dtype)
         return p
 
     def _attend(self, q, k, v):
@@ -292,27 +305,39 @@ class GatedAttention(_TokenLayer):
             if q.shape[2] > _DENSE_ATTENTION_MAX_T else None
         if flash is not None:
             return flash(q, k, v, None, True, scale)
-        group = self.n_heads // self.n_kv_heads
+        group = q.shape[1] // k.shape[1]
         return _dense_causal_attention(q, jnp.repeat(k, group, axis=1),
                                        jnp.repeat(v, group, axis=1), scale)
 
-    def _rotate(self, x, inv_freq):
+    def _normed(self, x, g, inv_freq):
+        """x (B, T, heads, hd): the norm, then the rotary part."""
+        if self.qk_norm_whole:
+            flat = x.reshape(x.shape[:2] + (-1,))
+            x = rms_norm(flat, g, self.eps, self.zero_centred).reshape(x.shape)
+        else:
+            x = rms_norm(x, g, self.eps, self.zero_centred)
         rd = self.rotary_dim
+        if not rd:
+            return x
         return jnp.concatenate([apply_rope(x[..., :rd], inv_freq), x[..., rd:]],
                                axis=-1)
 
     def forward(self, params, state, x, *, train, rng=None, mask=None):
         b, t, _ = x.shape
         h, hk, hd = self.n_heads, self.n_kv_heads, self.head_dim
-        q, gate = jnp.split((x @ params["w_q"]).reshape(b, t, h, 2 * hd), 2, axis=-1)
+        q = (x @ params["w_q"]).reshape(b, t, h, -1)
+        if self.output_gate:
+            q, gate = jnp.split(q, 2, axis=-1)
         k = (x @ params["w_k"]).reshape(b, t, hk, hd)
         v = (x @ params["w_v"]).reshape(b, t, hk, hd)
-        inv_freq = yarn_inv_freq(self.rotary_dim, self.rope_theta, None)
-        q = self._rotate(rms_norm(q, params["q_norm_g"], self.eps, True), inv_freq)
-        k = self._rotate(rms_norm(k, params["k_norm_g"], self.eps, True), inv_freq)
+        inv_freq = yarn_inv_freq(self.rotary_dim, self.rope_theta, None) \
+            if self.rotary_dim else None
+        q = self._normed(q, params["q_norm_g"], inv_freq)
+        k = self._normed(k, params["k_norm_g"], inv_freq)
         heads_first = lambda a: jnp.swapaxes(a, 1, 2)
         out = jnp.swapaxes(self._attend(*map(heads_first, (q, k, v))), 1, 2)
-        out = out * jax.nn.sigmoid(gate.astype(_F32)).astype(out.dtype)
+        if self.output_gate:
+            out = out * jax.nn.sigmoid(gate.astype(_F32)).astype(out.dtype)
         return out.reshape(b, t, h * hd) @ params["w_o"], state, mask
 
 
@@ -329,20 +354,27 @@ def _causal_depthwise_conv(x, w):
 class GatedDeltaNet(_TokenLayer):
     """Gated DeltaNet mixer (arXiv:2412.06464; Qwen3-Next's linear-attention
     layer): [q, k, v, z] = x W_qkvz, [b, a] = x W_ba; q, k, v through a causal
-    depthwise convolution of `conv_width` and silu; beta = sigmoid(b), g =
-    -exp(A_log) softplus(a + dt_bias) a value head (float32); q, k scaled to
+    depthwise convolution of `conv_width` and silu; beta = `beta_scale`
+    sigmoid(b) (2 where the state's transition may have negative eigenvalues,
+    `allow_neg_eigval`), g = -exp(A_log) softplus(a + dt_bias) a value head
+    (float32); q, k scaled to
     unit length a head, q by d_k^-1/2 more, each of the `n_k_heads` key heads
     serving n_v_heads / n_k_heads value heads; the gated delta rule
     (`ops/gated_delta_rule.py`: one (d_k, d_v) state a value head; its
     kernels where `heads_a_step` takes the heads, else the token scan); y =
     (RMSNorm(o) * silu(z)) W_out, the norm a head with its own gain. The
-    columns of W_qkvz are [q | k | v | z], of W_ba [b | a]."""
+    columns of W_qkvz are [q | k | v | z], of W_ba [b | a] (a model that
+    publishes them as separate projections, each with its convolution, has
+    the same products). Where a chip holds a share of a model's heads,
+    `n_k_heads` and `n_v_heads` are the held ones: the output is their part
+    of the sum over heads."""
     n_k_heads: int = 16
     n_v_heads: int = 32
     d_k: int = 128
     d_v: int = 128
     conv_width: int = 4
     eps: float = 1e-6
+    beta_scale: float = 1.0
 
     @property
     def _widths(self):
@@ -383,6 +415,8 @@ class GatedDeltaNet(_TokenLayer):
             norm = lax.rsqrt(jnp.sum(jnp.square(a32), axis=-1, keepdims=True) + 1e-6)
             return (a32 * norm * scale).astype(a.dtype)
         beta = jax.nn.sigmoid(ba[..., :nv])
+        if self.beta_scale != 1.0:
+            beta = self.beta_scale * beta
         g = -jnp.exp(params["a_log"].astype(_F32)) \
             * jax.nn.softplus(ba[..., nv:] + params["dt_bias"].astype(_F32))
         with jax.named_scope("delta_rule"):
@@ -818,14 +852,16 @@ class HyperConnection(BaseLayerConf):
 @register_layer
 @dataclass
 class PreNormResidual(BaseLayerConf):
-    """The plain pre-norm residual block round a sublayer F: x + F(norm(x)),
-    the norm an RMS norm with its own gain (`norm_g`; zero-centred by
-    default, as the models that use the block have it). A wrapping layer
-    config as `HyperConnection` is, so that norm, sublayer and add are one
-    recomputed block under one scope."""
+    """The plain residual block round a sublayer F: x + F(norm(x)), or with
+    `norm_output` x + norm(F(x)) (Olmo 2's order: the norm on the sublayer's
+    output); the norm an RMS norm with its own gain (`norm_g`; zero-centred
+    by default, as the models that first used the block have it). A wrapping
+    layer config as `HyperConnection` is, so that norm, sublayer and add are
+    one recomputed block under one scope."""
     layer: Optional[BaseLayerConf] = None
     eps: float = 1e-6
     zero_centred: bool = True
+    norm_output: bool = False
 
     def __post_init__(self):
         if isinstance(self.layer, dict):
@@ -847,10 +883,13 @@ class PreNormResidual(BaseLayerConf):
 
     def forward(self, params, state, x, *, train, rng=None, mask=None):
         inner = {k: v for k, v in params.items() if k != "norm_g"}
-        u = rms_norm(x, params["norm_g"], self.eps, self.zero_centred)
+        norm = lambda a: rms_norm(a, params["norm_g"], self.eps, self.zero_centred)
+        u = x if self.norm_output else norm(x)
         with layer_scope(self.layer, self.name):
             y, new_state, mask = self.layer.forward(
                 inner, state, u, train=train, rng=rng, mask=mask)
+        if self.norm_output:
+            y = norm(y)
         return x + y.astype(x.dtype), new_state, mask
 
     def state_gauges(self, state) -> dict:

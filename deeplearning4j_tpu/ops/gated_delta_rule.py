@@ -92,6 +92,7 @@ _BASE = 32
 # the chunk's operands of every head in flight, spilled by the compiler.
 _HEADROOM = 16 * 1024 * 1024
 _VMEM_MOST = 100 * 1024 * 1024
+_LANES = 128
 
 _NN = (((1,), (0,)), ((), ()))       # a @ b
 _NT = (((1,), (1,)), ((), ()))       # a @ b.T
@@ -303,71 +304,109 @@ def _heads_of(q_ref, v_ref, g_ref, group):
     return heads, q_ref.shape[-1] * group // heads, v_ref.shape[-1] // heads
 
 
-def _fwd_kernel(chunk, group, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref,
-                *rest):
+def _lanes(width: int) -> int:
+    """A head's width in whole lane tiles."""
+    return -(-width // _LANES) * _LANES
+
+
+def _head(ref, rows, h, width):
+    """Head h's (C, width) of a block's rows, its lanes filled up with zeros
+    to whole tiles: a width that is no whole tile (96, 192) crosses HBM as it
+    is and is widened here, in VMEM, which changes no sum."""
+    x = ref[rows, h * width:(h + 1) * width]
+    fill = _lanes(width) - width
+    if not fill:
+        return x
+    return jnp.concatenate([x, jnp.zeros((x.shape[0], fill), x.dtype)], axis=1)
+
+
+def _put(ref, rows, h, width, x):
+    """`_head` the other way: the first `width` lanes of x to head h's place."""
+    ref[rows, h * width:(h + 1) * width] = x[:, :width].astype(ref.dtype)
+
+
+def _present(keys, n_k):
+    """Runs a body for the grid step's key head `key` if it is one of the
+    `n_k`: where the step's heads do not divide them its last block reaches
+    past the end, and the heads there are skipped (what their lanes hold is
+    not read)."""
+    from jax.experimental import pallas as pl
+    if n_k % keys == 0:
+        return lambda key: lambda body: body()
+    first = pl.program_id(1) * keys
+    return lambda key: pl.when(first + key < n_k)
+
+
+def _fwd_kernel(chunk, group, n_k, q_ref, k_ref, v_ref, g_ref, beta_ref,
+                o_ref, *rest):
     """`rest`: the block of the chunks' starting states where the call keeps
     them, then the states' scratch."""
     from jax.experimental import pallas as pl
     *starts_ref, state_scr = rest
     heads, d_k, d_v = _heads_of(q_ref, v_ref, g_ref, group)
+    keys = heads // group
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    masks = _masks(chunk)
+    masks, present = _masks(chunk), _present(keys, n_k)
 
     def one_chunk(c, carry):
         rows = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
-        for key in range(heads // group):
-            q = q_ref[rows, key * d_k:(key + 1) * d_k]
-            k = k_ref[rows, key * d_k:(key + 1) * d_k]
-            kk, qk = _mm(k, k, _NT), _mm(q, k, _NT)
-            for h in range(key * group, (key + 1) * group):
-                state = state_scr[h]
-                for kept in starts_ref:
-                    kept[h, c] = state
-                out, state_scr[h] = _chunk_fwd(
-                    q, k, v_ref[rows, h * d_v:(h + 1) * d_v], kk, qk,
-                    g_ref[h, pl.ds(c, 1), :], beta_ref[h, pl.ds(c, 1), :],
-                    state, masks)
-                o_ref[rows, h * d_v:(h + 1) * d_v] = out.astype(o_ref.dtype)
+        for key in range(keys):
+            @present(key)
+            def _():
+                q, k = _head(q_ref, rows, key, d_k), _head(k_ref, rows, key, d_k)
+                kk, qk = _mm(k, k, _NT), _mm(q, k, _NT)
+                for h in range(key * group, (key + 1) * group):
+                    state = state_scr[h]
+                    for kept in starts_ref:
+                        kept[h, c] = state
+                    out, state_scr[h] = _chunk_fwd(
+                        q, k, _head(v_ref, rows, h, d_v), kk, qk,
+                        g_ref[h, pl.ds(c, 1), :], beta_ref[h, pl.ds(c, 1), :],
+                        state, masks)
+                    _put(o_ref, rows, h, d_v, out)
         return carry
     lax.fori_loop(0, g_ref.shape[1], one_chunk, 0)
 
 
-def _bwd_kernel(chunk, group, q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref,
-                do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, d_state_scr):
+def _bwd_kernel(chunk, group, n_k, q_ref, k_ref, v_ref, g_ref, beta_ref,
+                starts_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
+                d_state_scr):
     from jax.experimental import pallas as pl
     heads, d_k, d_v = _heads_of(q_ref, v_ref, g_ref, group)
+    keys = heads // group
     chunks = g_ref.shape[1]
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         d_state_scr[...] = jnp.zeros_like(d_state_scr)
 
-    masks = _masks(chunk)
+    masks, present = _masks(chunk), _present(keys, n_k)
 
     def one_chunk(step, carry):
         c = chunks - 1 - step
         rows = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
-        for key in range(heads // group):
-            wide = slice(key * d_k, (key + 1) * d_k)
-            q, k = q_ref[rows, wide], k_ref[rows, wide]
-            kk, qk = _mm(k, k, _NT), _mm(q, k, _NT)
-            dq = dk = 0.0
-            for h in range(key * group, (key + 1) * group):
-                cols = slice(h * d_v, (h + 1) * d_v)
-                dq_h, dk_h, dv, dg, dbeta, d_state_scr[h] = _chunk_bwd(
-                    q, k, v_ref[rows, cols], kk, qk, g_ref[h, pl.ds(c, 1), :],
-                    beta_ref[h, pl.ds(c, 1), :], starts_ref[h, c],
-                    do_ref[rows, cols], d_state_scr[h], masks)
-                dq, dk = dq + dq_h, dk + dk_h
-                dv_ref[rows, cols] = dv.astype(dv_ref.dtype)
-                dg_ref[h, pl.ds(c, 1), :] = dg
-                dbeta_ref[h, pl.ds(c, 1), :] = dbeta
-            dq_ref[rows, wide] = dq.astype(dq_ref.dtype)
-            dk_ref[rows, wide] = dk.astype(dk_ref.dtype)
+        for key in range(keys):
+            @present(key)
+            def _():
+                q, k = _head(q_ref, rows, key, d_k), _head(k_ref, rows, key, d_k)
+                kk, qk = _mm(k, k, _NT), _mm(q, k, _NT)
+                dq = dk = 0.0
+                for h in range(key * group, (key + 1) * group):
+                    dq_h, dk_h, dv, dg, dbeta, d_state_scr[h] = _chunk_bwd(
+                        q, k, _head(v_ref, rows, h, d_v), kk, qk,
+                        g_ref[h, pl.ds(c, 1), :], beta_ref[h, pl.ds(c, 1), :],
+                        starts_ref[h, c], _head(do_ref, rows, h, d_v),
+                        d_state_scr[h], masks)
+                    dq, dk = dq + dq_h, dk + dk_h
+                    _put(dv_ref, rows, h, d_v, dv)
+                    dg_ref[h, pl.ds(c, 1), :] = dg
+                    dbeta_ref[h, pl.ds(c, 1), :] = dbeta
+                _put(dq_ref, rows, key, d_k, dq)
+                _put(dk_ref, rows, key, d_k, dk)
         return carry
     lax.fori_loop(0, chunks, one_chunk, 0)
 
@@ -379,15 +418,27 @@ def heads_a_step(n_k: int, n_v: int, d_k: int, d_v: int, itemsize: int,
     """The value heads a grid step takes, or None where the kernels refuse
     the shape: the value heads have to be whole groups of the key heads, q,
     k, v no wider than the float32 the kernels reckon in, and compiled for
-    the chip the widths whole lane tiles and the step's blocks within VMEM."""
+    the chip a step's heads whole lane tiles together (a head's own width
+    need not be one: 4 heads of 96 are 384 lanes, and `_head` fills each up
+    in VMEM) and the step's blocks within VMEM. Up to `_HEADS` value heads a
+    step, in whole groups: the most that divide the key heads, or, where no
+    such count is whole tiles wide, the most that are, the last block then
+    reaching past the heads (`_present`)."""
     if n_v % n_k or itemsize > 4:
         return None
     group = n_v // n_k
-    keys = max(kb for kb in range(1, n_k + 1)
-               if n_k % kb == 0 and (kb == 1 or kb * group <= _HEADS))
-    if not helpers.interpret_mode() and (
-            d_k % 128 or d_v % 128 or _vmem_bytes(
-                keys, keys * group, d_k, d_v, itemsize, chunk) > _VMEM_MOST):
+    on_chip = not helpers.interpret_mode()
+    whole = lambda kb: not (kb * d_k % _LANES or kb * group * d_v % _LANES)
+    counts = [kb for kb in range(1, n_k + 1) if kb == 1 or kb * group <= _HEADS]
+    divide = [kb for kb in counts if n_k % kb == 0]
+    # interpreted, any widths do
+    fit = [kb for kb in divide if whole(kb)] or [kb for kb in counts if whole(kb)] \
+        or ([] if on_chip else divide)
+    if not fit:
+        return None
+    keys = fit[-1]
+    if on_chip and _vmem_bytes(keys, keys * group, d_k, d_v, itemsize,
+                               chunk) > _VMEM_MOST:
         return None
     return keys * group
 
@@ -398,7 +449,7 @@ def _vmem_bytes(keys, heads, d_k, d_v, itemsize, chunk):
     pipeline's two buffers), the states' cotangents, and `_HEADROOM`."""
     tile = _TILE_CHUNKS * chunk
     tokens = tile * (4 * keys * d_k + 3 * heads * d_v) * itemsize
-    states = heads * d_k * d_v * 4
+    states = heads * _lanes(d_k) * _lanes(d_v) * 4
     return 2 * (tokens + _TILE_CHUNKS * states) + states + _HEADROOM
 
 
@@ -427,9 +478,9 @@ def _laid_out(chunk, back, q, k, v, g, beta, *more):
         pl.BlockSpec((None, tile, heads * d_v), lambda i, j, n: (i, at(n), j)),
         pl.BlockSpec((None, heads, chunks, chunk),
                      lambda i, j, n: (i, j, at(n), 0)),
-        pl.BlockSpec((None, heads, chunks, d_k, d_v),
+        pl.BlockSpec((None, heads, chunks, _lanes(d_k), _lanes(d_v)),
                      lambda i, j, n: (i, j, at(n), 0, 0)))
-    grid = (b, n_k // keys, tiles)
+    grid = (b, -(-n_k // keys), tiles)
     vmem = _vmem_bytes(keys, heads, d_k, d_v, q.dtype.itemsize, chunk)
     return (tuple(map(flat, (q, k, v))) + (gate(g), gate(beta))
             + tuple(map(flat, more))), grid, specs, heads, vmem
@@ -460,7 +511,7 @@ def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch, vmem,
 @functools.partial(jax.jit, static_argnums=(0, 1, 2))
 def _fwd_call(chunk, interpret, keep, q, k, v, g, beta):
     """o, and with `keep` the state each chunk starts from (B, H_v, T /
-    chunk, d_k, d_v) float32, of the padded length."""
+    chunk, d_k, d_v) float32, of the padded length and of whole lane tiles."""
     from jax.experimental.pallas import tpu as pltpu
     b, t, n_k, d_k = q.shape
     n_v, d_v = v.shape[2:]
@@ -472,11 +523,12 @@ def _fwd_call(chunk, interpret, keep, q, k, v, g, beta):
     if keep:
         out_specs.append(state)
         out_shape.append(jax.ShapeDtypeStruct(
-            (b, n_v, length // chunk, d_k, d_v), _F32))
+            (b, n_v, length // chunk, _lanes(d_k), _lanes(d_v)), _F32))
     out = _call(
-        functools.partial(_fwd_kernel, chunk, n_v // n_k), "dl4j_gdr_fwd",
+        functools.partial(_fwd_kernel, chunk, n_v // n_k, n_k), "dl4j_gdr_fwd",
         grid, [key, key, value, gate, gate], out_specs, out_shape,
-        [pltpu.VMEM((heads, d_k, d_v), _F32)], vmem, interpret, args)
+        [pltpu.VMEM((heads, _lanes(d_k), _lanes(d_v)), _F32)], vmem, interpret,
+        args)
     o = out[0][:, :t].reshape(v.shape)
     return (o, out[1]) if keep else o
 
@@ -491,13 +543,14 @@ def _bwd_call(chunk, interpret, q, k, v, g, beta, starts, d_out):
     length = args[0].shape[1]
     gates = jax.ShapeDtypeStruct(args[3].shape, _F32)
     dq, dk, dv, dg, dbeta = _call(
-        functools.partial(_bwd_kernel, chunk, n_v // n_k), "dl4j_gdr_bwd", grid,
+        functools.partial(_bwd_kernel, chunk, n_v // n_k, n_k), "dl4j_gdr_bwd",
+        grid,
         [key, key, value, gate, gate, state, value],
         [key, key, value, gate, gate],
         [jax.ShapeDtypeStruct((b, length, n_k * d_k), q.dtype),
          jax.ShapeDtypeStruct((b, length, n_k * d_k), k.dtype),
          jax.ShapeDtypeStruct((b, length, n_v * d_v), v.dtype), gates, gates],
-        [pltpu.VMEM((heads, d_k, d_v), _F32)], vmem, interpret,
+        [pltpu.VMEM((heads, _lanes(d_k), _lanes(d_v)), _F32)], vmem, interpret,
         args[:5] + (starts, args[5]))
     tokens = lambda a, like: a[:, :t].reshape(like.shape)
     gate_back = lambda a, like: jnp.moveaxis(

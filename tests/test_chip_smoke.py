@@ -157,9 +157,19 @@ def test_phase_delta_net_tiny():
     # float32: the CPU's runtime has no bf16 x bf16 -> f32 product
     out = chip_smoke.phase_delta_net(batch=2, seq_len=40, hidden=16, k_heads=2,
                                      v_heads=4, width=8,
-                                     compute_dtype="float32")
+                                     compute_dtype="float32", second=None)
     assert out["seam"] == {"kernel": 1, "fallback": 0}
     assert out["farthest"][1] <= 1e-4
+
+
+def test_phase_delta_net_tiny_at_the_second_shape():
+    """The Olmo-Hybrid share's shape cut small: one value head a key head, 12
+    against 24 wide, the write strength up to 2."""
+    out = chip_smoke.phase_delta_net(batch=1, seq_len=40, hidden=16, k_heads=2,
+                                     v_heads=2, width=8, compute_dtype="float32",
+                                     second=(24, 3, 12, 24))
+    assert out["second"]["seam"] == {"kernel": 1, "fallback": 0}
+    assert out["second"]["farthest"][1] <= 1e-4
 
 
 def test_phase_serve_tiny():

@@ -195,6 +195,11 @@ def _hyper_grad_recomputed(*a):
 GQA_QKV = [((2, 16, 8192, 256), BF16)] + [((2, 2, 8192, 256), BF16)] * 2
 RULE = [((2, 8192, 16, 128), BF16)] * 2 + [((2, 8192, 32, 128), BF16)] \
     + [((2, 8192, 32), F32)] * 2
+# the Olmo-Hybrid share's: 15 heads, one value head a key head, 96-wide keys
+# and 192-wide values (no whole lane tiles), 4 heads a grid step and the last
+# block reaching past the fifteenth
+RULE_96_192 = [((1, 8192, 15, 96), BF16)] * 2 + [((1, 8192, 15, 192), BF16)] \
+    + [((1, 8192, 15), F32)] * 2
 
 
 def _delta_rule(*a):
@@ -228,6 +233,9 @@ CASES = {
     "gated_delta_rule": (_delta_rule, RULE),
     "gated_delta_rule bwd": (_grad(_delta_rule, 5), RULE),
     "gated_delta_rule bwd recomputed": (_delta_rule_grad_recomputed, RULE),
+    "gated_delta_rule 15 heads of 96 x 192": (_delta_rule, RULE_96_192),
+    "gated_delta_rule 15 heads of 96 x 192 bwd recomputed":
+        (_delta_rule_grad_recomputed, RULE_96_192),
     "grouped_matmul up": (grouped_matmul_kernel, GMM_UP),
     "grouped_matmul down": (grouped_matmul_kernel, GMM_DOWN),
     "grouped_matmul bwd": (_gmm_grad, GMM_UP),
@@ -270,6 +278,9 @@ KERNEL_NAMES = {
     "gated_delta_rule": ("dl4j_gdr_fwd",),
     "gated_delta_rule bwd": ("dl4j_gdr_fwd", "dl4j_gdr_bwd"),
     "gated_delta_rule bwd recomputed": ("dl4j_gdr_fwd", "dl4j_gdr_bwd"),
+    "gated_delta_rule 15 heads of 96 x 192": ("dl4j_gdr_fwd",),
+    "gated_delta_rule 15 heads of 96 x 192 bwd recomputed":
+        ("dl4j_gdr_fwd", "dl4j_gdr_bwd"),
 }
 
 # how often a kernel stays in the compiled program: the recomputed forward's
@@ -281,6 +292,8 @@ COMPILED_CALLS = {
         {"dl4j_hc_pre": 2, "dl4j_hc_post": 1, "dl4j_hc_post_bwd": 1,
          "dl4j_hc_pre_bwd": 1},
     "gated_delta_rule bwd recomputed": {"dl4j_gdr_fwd": 2, "dl4j_gdr_bwd": 1},
+    "gated_delta_rule 15 heads of 96 x 192 bwd recomputed":
+        {"dl4j_gdr_fwd": 2, "dl4j_gdr_bwd": 1},
 }
 
 
@@ -317,6 +330,27 @@ def test_kernel_lowers_and_compiles_for_tpu(name, v5e_sharding):
         named = [c for c in calls if re.fullmatch(
             rf"%(jvp_)?{kernel}_?(\.\d+)?", c.strip())]
         assert len(named) == times, (kernel, calls)
+
+
+@pytest.mark.parametrize("shape,heads", [
+    # what the rule's kernels take on the chip: (n_k, n_v, d_k, d_v, itemsize)
+    ((16, 32, 128, 128, 2), 4),      # the Qwen3-Next share's: two key heads
+    ((15, 15, 96, 192, 2), 4),       # the Olmo-Hybrid share's: 4 heads of 96
+                                     # and of 192 are 384 and 768 lanes, the
+                                     # last block reaching past the fifteenth
+    ((15, 15, 96, 192, 4), 4),
+    ((15, 15, 128, 256, 2), 3),      # whole tiles: the most that divide 15
+    ((2, 16, 128, 128, 2), 8),       # a group larger than a step's heads
+    # and what they still refuse
+    ((3, 3, 96, 192, 2), None),      # 4 heads make whole tiles, 3 are held
+    ((15, 15, 100, 192, 2), None),   # no count under five makes 100 whole
+    ((3, 3, 12, 24, 4), None),       # the tests' widths: interpreted only
+    ((4, 6, 128, 128, 2), None),     # value heads in no whole groups
+    ((15, 15, 96, 192, 8), None),    # wider than the float32 they reckon in
+])
+def test_the_rules_heads_a_step_on_the_chip(shape, heads):
+    from deeplearning4j_tpu.ops.gated_delta_rule import heads_a_step
+    assert heads_a_step(*shape) == heads
 
 
 @pytest.mark.parametrize("stream_dcs", [True, False])
