@@ -150,7 +150,7 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         `rnn_init_states` (tBPTT: per-LSTM (h0, c0) in layer-name order, None
         entries allowed) a 4th element — the final RNN states — is appended."""
         from deeplearning4j_tpu.nn.conf.layers.feedforward import EmbeddingLayer
-        from deeplearning4j_tpu.util.dtypes import cast_floats
+        from deeplearning4j_tpu.util.dtypes import cast_floats, cast_params
         cd = self.compute_dtype
         mixed = cd != self.dtype
         params_full = params_tree  # storage-dtype originals (score + regularization)
@@ -236,7 +236,7 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                     init = rnn_init_states[len(final_rnn)]
                     with _layer_scope(layer, name):
                         out, (h, c) = layer._scan(
-                            cast_floats(params_tree[pi], cd) if cast_inside
+                            cast_params(params_tree[pi], cd) if cast_inside
                             else params_tree[pi], cur, mask,
                             h0=None if init is None else init[0],
                             c0=None if init is None else init[1])
@@ -248,7 +248,7 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
 
                     def fwd(p, s, c, r, m, _layer=layer):
                         if cast_inside:
-                            p = cast_floats(p, cd)
+                            p = cast_params(p, cd)
                         return _layer.forward(p, s, c, train=train, rng=r, mask=m)
 
                     if remat:

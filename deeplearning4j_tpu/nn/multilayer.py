@@ -39,11 +39,11 @@ _telemetry.count_compiles()   # dl4j.compile.* counters, from here on
 def _cast_params(layers, names, params_tree, dtype):
     """The layers' parameters in the compute type, each layer's casts under
     its own name."""
-    from deeplearning4j_tpu.util.dtypes import cast_floats
+    from deeplearning4j_tpu.util.dtypes import cast_params
     out = []
     for layer, name, p in zip(layers, names, params_tree):
         with _layer_scope(layer, name):
-            out.append(cast_floats(p, dtype))
+            out.append(cast_params(p, dtype))
     return out
 
 
@@ -307,7 +307,7 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         if not out_layer.is_output_layer():
             raise ValueError("Last layer must be an output/loss layer for scoring")
         from deeplearning4j_tpu.nn.conf.layers.feedforward import EmbeddingLayer
-        from deeplearning4j_tpu.util.dtypes import cast_floats
+        from deeplearning4j_tpu.util.dtypes import cast_floats, cast_params
         cd = self.compute_dtype
         mixed = cd != self.dtype
         params_full = params_tree  # storage-dtype originals (score + regularization)
@@ -350,7 +350,7 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                 init = rnn_init_states[len(final_rnn)]
                 with _layer_scope(layer, i):
                     cur, (h, c) = layer._scan(
-                        cast_floats(params_tree[i], cd) if cast_inside
+                        cast_params(params_tree[i], cd) if cast_inside
                         else params_tree[i], cur, mask,
                         h0=None if init is None else init[0],
                         c0=None if init is None else init[1])
@@ -366,7 +366,7 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
 
                 def fwd(p, s, c, r, m, _layer=layer):
                     if cast_inside:
-                        p = cast_floats(p, cd)
+                        p = cast_params(p, cd)
                     return _layer.forward(p, s, c, train=train, rng=r, mask=m)
 
                 if self.conf.global_conf.remat:
