@@ -25,7 +25,7 @@ from deeplearning4j_tpu.nn.divergence import DivergenceSentinelMixin
 from deeplearning4j_tpu.nn.multilayer import (
     _abstract, _apply_updates, _cast_params, _compute_updates,
     _device_loop_args, _layer_scope, _normalize_gradients,
-    _register_fit_batch_costs, _subtract_updates, _train_step_args)
+    _subtract_updates, _train_step_args)
 from deeplearning4j_tpu.nn.updater.updaters import BaseUpdater
 from deeplearning4j_tpu import telemetry as _telemetry
 from deeplearning4j_tpu.telemetry import health as _health
@@ -380,7 +380,6 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
             if self._accumulator is None:
                 step_args = _train_step_args(self, sub, x, y, fmask, lmask,
                                              rnn_init_states)
-                _register_fit_batch_costs(self, step_args)
         if self._accumulator is not None:
             return self._fit_batch_accumulated(x, y, fmask, lmask, sub)
         with _telemetry.span("dl4j.fit_batch.dispatch", step=step):
@@ -431,14 +430,6 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                 run = self._get_device_loop(vary_batch)
                 self._rng, sub = jax.random.split(self._rng)
                 args = _device_loop_args(self, sub, x, y, fmask, lmask)
-                # profiler cost registry (ISSUE 6): register BEFORE the
-                # dispatch donates params/opt/state; see
-                # MultiLayerNetwork.fit_on_device
-                from deeplearning4j_tpu.telemetry import profiler as _profiler
-                warm = _profiler.register_train_loop(
-                    self, ("cg", vary_batch, self._health_key()), run, args,
-                    steps)
-            t_run = time.perf_counter()
             with _telemetry.span("dl4j.fit_on_device.dispatch", step=step):
                 (self.params_tree, self._opt_state, self.state_tree, _, _,
                  div), losses, health_out = run(*args, n=steps)
@@ -455,10 +446,6 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                     (losses, self._pending_div,
                      [self.state_tree[i] for i in self._gauge_layers]))  # ONE readback
             self._publish_state_gauges(gauge_states)
-            if warm:
-                # warm + sync only: compile excluded, readback already paid for
-                _profiler.observe("train_step", (time.perf_counter() - t_run)
-                                  * 1e3 / max(1, steps))
             self._score = float(losses[-1])
             self._resolve_divergence(int(div))
             return losses

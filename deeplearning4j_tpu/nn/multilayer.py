@@ -34,6 +34,7 @@ from deeplearning4j_tpu.util.flat_params import flatten_params, num_params, unfl
 
 
 _telemetry.count_compiles()   # dl4j.compile.* counters, from here on
+_telemetry.count_gc()         # dl4j.host.gc.* counters and full-collection spans
 
 
 def _cast_params(layers, names, params_tree, dtype):
@@ -121,20 +122,6 @@ def _train_step_args(net, rng, x, y, fmask, lmask, rnn_init_states):
     `lower_fit_batch`): the loop's, and the carried RNN states."""
     args = _device_loop_args(net, rng, x, y, fmask, lmask)
     return args[:-1] + (rnn_init_states, args[-1])
-
-
-def _register_fit_batch_costs(net, step_args):
-    """Profiler cost registry (ISSUE 6): file train_step costs once, BEFORE
-    the dispatch donates params/opt/state (AOT — no exec);
-    telemetry.training.mark_iteration feeds the measured ms side."""
-    from deeplearning4j_tpu.telemetry import profiler as _profiler
-    if _profiler.enabled() and not getattr(net, "_profiled_fit_batch", False):
-        net._profiled_fit_batch = True
-        try:
-            _profiler.register("train_step", net._train_step_fn, step_args,
-                               meta={"loop": "fit_batch"})
-        except Exception:
-            pass
 
 
 class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
@@ -486,7 +473,6 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
             if self._accumulator is None:
                 step_args = _train_step_args(
                     self, sub, x, y, fmask, lmask, rnn_init_states)
-                _register_fit_batch_costs(self, step_args)
         if self._accumulator is not None:
             return self._fit_batch_accumulated(x, y, fmask, lmask, rnn_init_states)
         with _telemetry.span("dl4j.fit_batch.dispatch", step=step):
@@ -573,15 +559,6 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                                             vary_batch)
                 self._rng, sub = jax.random.split(self._rng)
                 args = _device_loop_args(self, sub, x, y, fmask, lmask)
-                # profiler cost registry (ISSUE 6): file per-step train_step
-                # costs BEFORE the dispatch below donates params/opt/state;
-                # `warm` gates the wall-time observation so compile time never
-                # pollutes it
-                from deeplearning4j_tpu.telemetry import profiler as _profiler
-                warm = _profiler.register_train_loop(
-                    self, ("mln", per_step_data, has_fm, has_lm, vary_batch,
-                           self._health_key()), run, args, steps)
-            t_run = time.perf_counter()
             with _telemetry.span("dl4j.fit_on_device.dispatch", step=step):
                 (self.params_tree, self._opt_state, self.state_tree, _, _,
                  div), losses, health_out = run(*args, n=steps)
@@ -599,11 +576,6 @@ class MultiLayerNetwork(DivergenceSentinelMixin, _health.HealthMonitorMixin):
             with _telemetry.span("dl4j.fit_on_device.readback", step=step):
                 losses, div = jax.device_get(
                     (losses, self._pending_div))  # ONE readback
-            if warm:
-                # warm + sync: the wall spans the whole device loop plus its
-                # one readback — a host value the sync path already paid for
-                _profiler.observe("train_step", (time.perf_counter() - t_run)
-                                  * 1e3 / max(1, steps))
             self._score = float(losses[-1])
             self._resolve_divergence(int(div))
             return losses

@@ -373,6 +373,30 @@ class ParallelWrapper:
             raise ValueError(
                 "fit_on_device is unsupported in CUSTOM mode: the caller-provided "
                 "GradientsAccumulator is applied host-side between steps")
+        with telemetry.span("dl4j.pw.fit_on_device.prepare", step=step):
+            x, y, sub = self._prepare_device_loop(x, y)
+        with telemetry.span("dl4j.pw.fit_on_device.dispatch", step=step):
+            self._carry, losses = self._scan_fn(self._carry, sub, x, y,
+                                                n=int(steps))
+        self._host_step += int(steps)
+        if not sync:
+            # deferred readback (see MultiLayerNetwork.fit_on_device): the
+            # returned device array is the completion handle — timed callers
+            # block_until_ready on it rather than paying a host copy per call
+            self._score = losses[-1]
+        else:
+            # host transfer doubles as the synchronization point: callers must
+            # observe completed work, not queued dispatches
+            with telemetry.span("dl4j.pw.fit_on_device.readback", step=step):
+                losses = np.asarray(losses)
+            self._score = float(losses[-1])
+        with telemetry.span("dl4j.pw.fit_on_device.write_back", step=step):
+            self._write_back()
+        return losses
+
+    def _prepare_device_loop(self, x, y):
+        """The batch placed on the mesh, the scan built on first use and the
+        call's key: everything `fit_on_device` does before its dispatch."""
         self._ensure_setup()
         net = self.model
         if np.shape(x)[0] % self.workers != 0:
@@ -402,24 +426,7 @@ class ParallelWrapper:
 
             self._scan_fn = dl4j_pw_device_loop
         net._rng, sub = jax.random.split(net._rng)
-        with telemetry.span("dl4j.pw.fit_on_device.dispatch", step=step):
-            self._carry, losses = self._scan_fn(self._carry, sub, x, y,
-                                                n=int(steps))
-        self._host_step += int(steps)
-        if not sync:
-            # deferred readback (see MultiLayerNetwork.fit_on_device): the
-            # returned device array is the completion handle — timed callers
-            # block_until_ready on it rather than paying a host copy per call
-            self._score = losses[-1]
-        else:
-            # host transfer doubles as the synchronization point: callers must
-            # observe completed work, not queued dispatches
-            with telemetry.span("dl4j.pw.fit_on_device.readback", step=step):
-                losses = np.asarray(losses)
-            self._score = float(losses[-1])
-        with telemetry.span("dl4j.pw.fit_on_device.write_back", step=step):
-            self._write_back()
-        return losses
+        return x, y, sub
 
     def _average_partial_window(self):
         """AVERAGING mode, fit() epilogue: when averaging_frequency does not

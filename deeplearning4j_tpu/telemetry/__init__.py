@@ -12,6 +12,9 @@ Three pieces:
   tracer; `maybe_export_trace()` writes it to `$DL4J_TPU_TRACE_PATH`.
 - `count_compiles()`: JAX's monitoring events as `dl4j.compile.*` counters
   of the default registry (called where the nets' modules are imported).
+- `count_gc()`: Python's garbage collections as `dl4j.host.gc.*` counters
+  of the default registry, and each full collection as a `dl4j.host.gc`
+  span (called beside `count_compiles`).
 - Prometheus text exposition: `registry().prometheus_text()`, served by
   ui/server.py at GET /metrics, or mount `metrics_route()` on any
   util/http.JsonHttpServer.
@@ -27,9 +30,10 @@ Env toggles:
   (health.py, ISSUE 5). Unset means health is off unless a listener or the
   model opts in.
 - DL4J_TPU_PROFILE=1 (any value but 0/false/off) enables the
-  compiled-function cost registry + per-function MFU/roofline gauges
-  (profiler.py, ISSUE 6). Unset/0 keeps the profiling call sites inert
-  (default). A device trace needs no switch: a span is also a
+  compiled-function cost registry + per-function MFU/roofline gauges of
+  the serving engine's programs (profiler.py; the training programs'
+  costs are counted analytically by the benchmark). Unset/0 keeps the
+  profiling call sites inert (default). A device trace needs no switch: a span is also a
   `jax.profiler.TraceAnnotation`, so any recorded profile holds them.
 - DL4J_TPU_FLIGHT_RECORDER=1 attaches a default flight recorder
   (flight_recorder.py, ISSUE 8) to every new ServingEngine: it retains
@@ -69,7 +73,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "Tracer",
     "DEFAULT_MS_BUCKETS", "DEFAULT_S_BUCKETS", "registry", "tracer", "span",
     "instant", "enabled", "configure", "maybe_export_trace", "metrics_route",
-    "count_compiles",
+    "count_compiles", "count_gc",
     "PROMETHEUS_CONTENT_TYPE", "sanitize_component", "set_track", "health",
     "profiler", "memory", "slo", "flight_recorder", "kv_observatory",
     "blame", "timeseries", "alerts",
@@ -205,6 +209,68 @@ def count_compiles() -> None:
 
     monitoring.register_event_duration_secs_listener(on_duration)
     monitoring.register_event_listener(on_event)
+
+
+GC_SPAN = "dl4j.host.gc"
+_GC_COUNTED = False
+
+
+def count_gc() -> None:
+    """Count Python's garbage collections from now on: every collection
+    adds to `dl4j.host.gc.collections.gen<g>` and `dl4j.host.gc.seconds.gen<g>`
+    of the default registry, and every full (generation-2) collection is
+    also a `dl4j.host.gc` span with `generation`, `collected` and
+    `uncollectable` — in the ring on the collecting thread and, while a
+    profile records, a `jax.profiler.TraceAnnotation` inside the span it
+    interrupted. The young generations get counters only, so that their
+    hundreds in a set-up leave the ring to the calls. With span recording
+    off the counters stay.
+    A collection runs on whichever thread allocates, between two
+    bytecodes, and stalls that thread: on the training thread between two
+    calls, the device waits. Idempotent: one `gc.callbacks` hook, two
+    Python calls a collection."""
+    global _GC_COUNTED
+    if _GC_COUNTED:
+        return
+    _GC_COUNTED = True
+    import gc
+    import threading
+    import time
+
+    from deeplearning4j_tpu.telemetry.tracing import _annotation
+    counts = [_REGISTRY.counter(f"dl4j.host.gc.collections.gen{g}",
+                                f"Python's generation-{g} collections")
+              for g in range(3)]
+    seconds = [_REGISTRY.counter(f"dl4j.host.gc.seconds.gen{g}",
+                                 f"seconds in Python's generation-{g} "
+                                 "collections") for g in range(3)]
+    running = {}    # the collection in progress: CPython runs one at a time
+
+    def on_gc(phase, info):
+        full = info["generation"] == 2
+        if phase == "start":
+            ann = _annotation(GC_SPAN, {"generation": 2}) \
+                if full and _ENABLED else None
+            if ann is not None:
+                ann.__enter__()
+            running["ann"] = ann
+            running["t0"] = time.perf_counter()
+            return
+        t1 = time.perf_counter()
+        t0, ann = running["t0"], running["ann"]
+        g = info["generation"]
+        counts[g].inc()
+        seconds[g].inc(t1 - t0)
+        if ann is not None:
+            ann.set_metadata(collected=info["collected"],
+                             uncollectable=info["uncollectable"])
+            ann.__exit__(None, None, None)
+        if full and _ENABLED:
+            _TRACER._record("X", GC_SPAN, t0, t1 - t0, threading.get_ident(),
+                            {"generation": 2, "collected": info["collected"],
+                             "uncollectable": info["uncollectable"]})
+
+    gc.callbacks.append(on_gc)
 
 
 def metrics_route(reg: Optional[MetricsRegistry] = None):

@@ -2,9 +2,9 @@
 
 ISSUE 6 tentpole, part 1+3. Three jobs:
 
-1. **Compiled-function cost registry.** Every jit entry point (train_step,
-   the fit_on_device scan, prefill buckets, decode_chunk per K, helper
-   kernels) calls `register(name, jitted, args...)` at compile time — an
+1. **Compiled-function cost registry.** The serving engine's jit entry
+   points (prefill buckets, decode_chunk per K) call
+   `register(name, jitted, args...)` at compile time — an
    AOT `lower().compile().cost_analysis()` via util/costs, nothing
    executes, no buffer is donated — filing FLOPs/bytes under the function
    name and publishing `profiler.fn.<name>.{flops,bytes,mxu_floor_ms}`
@@ -178,8 +178,8 @@ def register(name: str, jitted=None, args=(), *,
     gauges (and `.mxu_floor_ms` on a TPU) and returns the cost record.
 
     Safe to call immediately before dispatching a donated-arg jit (AOT
-    lowering does not consume buffers) — and that ordering is REQUIRED for
-    train_step, whose params are donated by the real call."""
+    lowering does not consume buffers) — and that ordering is REQUIRED
+    where the real call donates its arguments."""
     plat = _detect_platform()
     meta = dict(meta or {})
     meta.setdefault("platform", plat)
@@ -240,39 +240,6 @@ def observe(name: str, ms: float,
     reg.gauge(f"profiler.fn.{n}.mfu",
               "model FLOPs utilization vs the chip's bf16 peak"
               ).set(floor / ms)
-
-
-def register_train_loop(owner, key, run, args, steps: int,
-                        name: str = "train_step") -> bool:
-    """fit_on_device hook: register per-step `train_step` costs for a
-    jitted scan loop, once per loop cache key, and report warmness.
-
-    MUST be called BEFORE the dispatch — the real call donates the
-    params/opt/state buffers in `args`, while the AOT cost analysis here
-    only lowers (nothing executes, nothing is donated). Costs are analyzed
-    at the loop's real signature (n=steps) and normalized to per-step so
-    the `train_step` entry is comparable across step counts.
-
-    Returns True when this key has dispatched before (WARM) — the caller
-    observes wall time only then, so the first call's jit compile never
-    pollutes the measured ms. No-op returning False when profiling is off."""
-    if not enabled():
-        return False
-    profiled = owner.__dict__.setdefault("_profiler_loop_keys", set())
-    warm = key in profiled
-    if warm:
-        return True
-    profiled.add(key)
-    try:
-        costs = _costs.lowered_costs(run, *args, n=int(steps))
-        register(name,
-                 flops=costs["flops"] / max(1, int(steps)),
-                 bytes_accessed=costs["bytes_accessed"] / max(1, int(steps)),
-                 meta={"normalized_per_step": True, "steps_analyzed":
-                       int(steps), "loop": str(key[0])})
-    except Exception:
-        pass
-    return False
 
 
 def observed(name: str) -> Optional[dict]:

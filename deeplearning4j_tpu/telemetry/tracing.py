@@ -39,8 +39,16 @@ iteration when the span began>`, so the spans of one step share it:
   (the underlying iterator's `next`), "dl4j.async.stage" (`np.asarray` +
   `jax.device_put`, args: bytes) and "dl4j.async.put_wait" (blocked on the
   full queue)
-- "dl4j.pw.fit_on_device" with ".dispatch", ".readback", ".write_back" —
-  `ParallelWrapper.fit_on_device`
+- "dl4j.pw.fit_on_device" with ".prepare", ".dispatch", ".readback",
+  ".write_back" — `ParallelWrapper.fit_on_device`
+- "dl4j.host.gc" (args: generation, collected, uncollectable) — one of
+  Python's full (generation-2) collections, on the thread that ran it
+  (`telemetry.count_gc`, which counts every generation's collections and
+  seconds as `dl4j.host.gc.*` counters)
+
+`Tracer.spans(prefix, t0, t1)` reads the ring back on `time.perf_counter`'s
+clock, so a caller that timed a stretch with that clock can put the
+program's own spans on it.
 """
 from __future__ import annotations
 
@@ -49,7 +57,7 @@ import json
 import sys
 import threading
 import time
-from typing import Deque, Dict, Optional
+from typing import Deque, Dict, List, Optional, Tuple
 
 _US = 1e6
 
@@ -204,6 +212,27 @@ class Tracer:
     @property
     def n_events(self) -> int:
         return len(self._events)
+
+    def spans(self, prefix: str, t0: Optional[float] = None,
+              t1: Optional[float] = None) -> List[Tuple]:
+        """The ring's complete spans whose name starts with `prefix` and
+        whose start lies in [t0, t1] (either bound may be None), in the
+        order they ended, as `(name, start_s, end_s, thread, args)` on
+        `time.perf_counter`'s clock; `thread` is the recording thread's
+        ident or its named track's tid."""
+        with self._lock:
+            events = list(self._events)
+        out = []
+        for ev in events:
+            if ev["ph"] != "X" or not ev["name"].startswith(prefix):
+                continue
+            start = self._epoch + ev["ts"] / _US
+            if (t0 is not None and start < t0) or (t1 is not None
+                                                   and start > t1):
+                continue
+            out.append((ev["name"], start, start + ev["dur"] / _US,
+                        ev["tid"], ev.get("args") or {}))
+        return out
 
     def chrome_trace(self) -> dict:
         """The exported document: Chrome trace 'JSON Object Format'."""

@@ -91,13 +91,6 @@ def mark_iteration(iteration: int, registry: Optional[MetricsRegistry] = None,
         reg.histogram("training.iteration_ms",
                       "wall time per training iteration (host clock)",
                       buckets=DEFAULT_MS_BUCKETS).observe(ms)
-        # roofline attribution (ISSUE 6): when train_step costs are on
-        # file (fit_batch registered them under DL4J_TPU_PROFILE), feed
-        # the SAME host wall to the profiler — one dict lookup when off
-        from deeplearning4j_tpu.util.costs import get_costs
-        if get_costs("train_step") is not None:
-            from deeplearning4j_tpu.telemetry import profiler
-            profiler.observe("train_step", ms, registry=reg)
     return record
 
 

@@ -60,7 +60,7 @@ def analyze_and_record(name: str, jitted, *args,
     """`lowered_costs` + `record_costs` in one step. AOT lower/compile —
     nothing executes and no buffer is donated, so it is safe to call
     immediately BEFORE dispatching a jit whose donated args are still alive
-    (the train_step case: register first, then step)."""
+    (register first, then dispatch)."""
     costs = lowered_costs(jitted, *args, **kwargs)
     return record_costs(name, costs["flops"], costs["bytes_accessed"],
                         meta=meta)
