@@ -22,6 +22,8 @@ from deeplearning4j_tpu.common.enums import Activation, ConvolutionMode, Pooling
 from deeplearning4j_tpu.nn.conf.input_type import InputType
 from deeplearning4j_tpu.nn.conf.layers.base import (
     BaseLayerConf, FeedForwardLayerConf, register_layer)
+from deeplearning4j_tpu.ops.helpers import helper_for
+from deeplearning4j_tpu.ops.max_pool import max_pool_grad_tiles
 
 
 def conv_output_size(in_size: int, k: int, s: int, p: int, mode: ConvolutionMode) -> int:
@@ -199,6 +201,11 @@ class SubsamplingLayer(BaseLayerConf):
     def forward(self, params, state, x, *, train, rng=None, mask=None):
         ph, pw = _pad_config(x.shape[2], x.shape[3], self.kernel_size, self.stride,
                              self.padding, self.convolution_mode)
+        if self.pooling_type == PoolingType.MAX and max_pool_grad_tiles(
+                x.shape, x.dtype, self.kernel_size, self.stride, (ph, pw)) is not None:
+            pool = helper_for("max_pool_grad", None)
+            if pool is not None:
+                return pool(x, self.kernel_size, self.stride, (ph, pw)), state, mask
         out = _pool(x, self.pooling_type, (1, 1) + tuple(self.kernel_size),
                     (1, 1) + tuple(self.stride), ((0, 0), (0, 0), ph, pw), self.pnorm)
         return out, state, mask

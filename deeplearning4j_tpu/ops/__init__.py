@@ -14,8 +14,9 @@ from deeplearning4j_tpu.ops import decode_attention  # registers the paged decod
 from deeplearning4j_tpu.ops import grouped_matmul  # registers grouped_matmul
 from deeplearning4j_tpu.ops import hyper_connection  # registers hyper_connection
 from deeplearning4j_tpu.ops import gated_delta_rule  # registers gated_delta_rule
+from deeplearning4j_tpu.ops import max_pool  # registers max_pool_grad
 
 __all__ = ["enable_helpers", "helper_for", "register_helper",
            "registered_helpers", "lstm_scan_fused", "flash_attention",
            "decode_attention", "grouped_matmul", "hyper_connection",
-           "gated_delta_rule"]
+           "gated_delta_rule", "max_pool"]

@@ -215,6 +215,23 @@ def _delta_rule_grad_recomputed(*a):
                               argnums=tuple(range(len(a))))(*a)
 
 
+# the ResNet50 cell's two max pools, 3x3 windows at stride 2: the stem's over
+# the ReLU of its first convolution, 512 x 64 maps of 112 x 112 pooled to
+# 55 x 55 (the batch on lanes), and the head's over 4 x 4 maps of 2048
+# channels (the channels on lanes); and a `Same` pool, padded with -inf
+STEM_POOL = [((512, 64, 112, 112), BF16), ((512, 64, 55, 55), BF16)]
+HEAD_POOL = [((512, 2048, 4, 4), BF16), ((512, 2048, 1, 1), BF16)]
+SAME_POOL = [((128, 32, 27, 27), BF16), ((128, 32, 14, 14), BF16)]
+
+
+def _max_pool_grad(padding=((0, 0), (0, 0))):
+    from deeplearning4j_tpu.ops.max_pool import max_pool
+
+    def fn(x, dy):
+        return jax.vjp(lambda a: max_pool(a, (3, 3), (2, 2), padding), x)[1](dy)
+    return fn
+
+
 CASES = {
     "hyper_connection": (_hyper, HYPER),
     "hyper_connection bwd recomputed": (_hyper_grad_recomputed, HYPER),
@@ -258,6 +275,9 @@ CASES = {
     "decode_attention_spec_paged Q=4 int8 window=256":
         (_decode(flash_decode_attention_spec_paged, 256),
          _paged((S, 4, NH, D), I8)),
+    "max_pool_grad stem 3x3/2": (_max_pool_grad(), STEM_POOL),
+    "max_pool_grad head 3x3/2": (_max_pool_grad(), HEAD_POOL),
+    "max_pool_grad Same 3x3/2": (_max_pool_grad(((1, 1), (1, 1))), SAME_POOL),
 }
 
 
@@ -281,6 +301,9 @@ KERNEL_NAMES = {
     "gated_delta_rule 15 heads of 96 x 192": ("dl4j_gdr_fwd",),
     "gated_delta_rule 15 heads of 96 x 192 bwd recomputed":
         ("dl4j_gdr_fwd", "dl4j_gdr_bwd"),
+    "max_pool_grad stem 3x3/2": ("dl4j_max_pool_bwd",),
+    "max_pool_grad head 3x3/2": ("dl4j_max_pool_bwd",),
+    "max_pool_grad Same 3x3/2": ("dl4j_max_pool_bwd",),
 }
 
 # how often a kernel stays in the compiled program: the recomputed forward's

@@ -16,7 +16,7 @@ from deeplearning4j_tpu.ops.helpers import (
 
 SHIPPED = {"graves_lstm_scan", "flash_attention", "grouped_matmul",
            "decode_attention_paged", "decode_attention_spec_paged",
-           "hyper_connection", "gated_delta_rule"}
+           "hyper_connection", "gated_delta_rule", "max_pool_grad"}
 
 
 @pytest.fixture(autouse=True)
@@ -284,6 +284,19 @@ def _site_gated_delta_net(monkeypatch):
     return layer.forward(params, {}, x, train=True)[0]
 
 
+def _site_max_pool(monkeypatch, stride=2):
+    """The layer's gradient: the kernel replaces the backward only."""
+    from deeplearning4j_tpu.common.enums import PoolingType
+    from deeplearning4j_tpu.nn.conf.layers.convolutional import (
+        SubsamplingLayer)
+    layer = SubsamplingLayer(pooling_type=PoolingType.MAX, kernel_size=(3, 3),
+                             stride=(stride, stride))
+    x = jnp.asarray(np.random.RandomState(17).randn(2, 3, 9, 8), jnp.float32)
+    out, vjp = jax.vjp(lambda a: layer.forward({}, {}, a, train=True)[0], x)
+    return out, vjp(jnp.cos(jnp.arange(out.size, dtype=out.dtype).reshape(
+        out.shape)))[0]
+
+
 SITES = {
     "LSTM._scan": ("graves_lstm_scan", _site_lstm_scan, 1e-10),
     "SelfAttentionLayer.forward": ("flash_attention", _site_self_attention,
@@ -299,6 +312,7 @@ SITES = {
     "HyperConnection.forward": ("hyper_connection", _site_hyper_connection,
                                 1e-5),
     "GatedDeltaNet.forward": ("gated_delta_rule", _site_gated_delta_net, 1e-5),
+    "SubsamplingLayer.forward": ("max_pool_grad", _site_max_pool, 1e-6),
 }
 
 
@@ -352,6 +366,8 @@ REFUSED = {
         ("hyper_connection", lambda m: _site_hyper_connection(m, t=96)),
     "a delta net whose heads are no whole lane tiles, compiled":
         ("gated_delta_rule", _refused_by_width),
+    "a max pool whose windows reach past the next one's rows":
+        ("max_pool_grad", lambda m: _site_max_pool(m, stride=1)),
 }
 
 
