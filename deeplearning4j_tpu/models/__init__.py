@@ -1,10 +1,20 @@
-"""Model zoo (ref deeplearning4j-zoo): instantiable architectures + ModelSelector."""
+"""Model zoo (ref deeplearning4j-zoo): instantiable architectures + ModelSelector.
+
+Beside the reference's zoo, decoders built from the keys of a published
+`config.json`, each trained through `ComputationGraph.fit_on_device`: `Xing4`
+(latent attention, routed experts, hyper-connections, an MTP module),
+`Qwen3Next` (Gated DeltaNet and gated attention, routed experts),
+`OlmoHybrid` (Gated DeltaNet and attention, dense SwiGLU) and `Ouro` (one
+stack of layers run several times with shared weights, an exit gate scoring
+every pass). They are not in `ZOO`: they take a config, not a label count.
+"""
 from deeplearning4j_tpu.models.alexnet import AlexNet
 from deeplearning4j_tpu.models.facenet_nn4_small2 import FaceNetNN4Small2
 from deeplearning4j_tpu.models.googlenet import GoogLeNet
 from deeplearning4j_tpu.models.inception_resnet_v1 import InceptionResNetV1
 from deeplearning4j_tpu.models.lenet import LeNet
 from deeplearning4j_tpu.models.olmo_hybrid import OlmoHybrid
+from deeplearning4j_tpu.models.ouro import Ouro
 from deeplearning4j_tpu.models.qwen3_next import Qwen3Next
 from deeplearning4j_tpu.models.resnet50 import ResNet50
 from deeplearning4j_tpu.models.simple_cnn import SimpleCNN, TextGenerationLSTM

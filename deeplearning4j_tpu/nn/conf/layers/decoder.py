@@ -7,11 +7,15 @@ dropless sigmoid top-k routed experts beside a shared one, the
 manifold-constrained hyper-connection around a sublayer (a wrapping layer
 config, as `Bidirectional` is), the multi-token-prediction module's input
 and a softmax cross-entropy head over integer labels; the plain pre-norm
-residual block round a sublayer (the norm on the sublayer's input, or on its
-output as the Olmo family has it), the Gated DeltaNet mixer (a recurrent layer
-whose state is a matrix a head, `ops/gated_delta_rule.py`) and the softmax
-attention mixer on grouped k/v heads (with an output gate and rotary on part
-of each head, or with neither).
+residual block round a sublayer (the norm on the sublayer's input, on its
+output as the Olmo family has it, or on both sides as Ouro has it), the Gated
+DeltaNet mixer (a recurrent layer whose state is a matrix a head,
+`ops/gated_delta_rule.py`) and the softmax attention mixer on grouped k/v
+heads (with an output gate and rotary on part of each head, or with neither;
+with or without a norm on q and k); the looped stack (`LoopedStack`: a stack
+of blocks run several times with one set of weights, one `lax.scan` over the
+passes) and its exit head (`LoopExitHead`: every pass scored by one head and
+weighted by an early-exit gate).
 
 Layout: these layers pass `(batch, time, features)` between them (features
 last, as the matrix unit wants them), not DL4J's `(batch, features, time)`;
@@ -267,6 +271,8 @@ class GatedAttention(_TokenLayer):
     query heads; an RMS norm on q and on k, a head with one gain of
     `head_dim` or, with `qk_norm_whole`, over the projection's whole width
     with a gain of that width (Olmo 2's), the gains zero-centred or from 1;
+    with `qk_norm` False no norm at all (Ouro's), and then the layer has no
+    `q_norm_g` or `k_norm_g` leaves (and `qk_norm_whole` is refused);
     rotary (the half-split pairing) on the first `rotary_dim` entries of
     each head (0: none); y = (attention * sigmoid(gate)) W_o. No bias. Past a
     tile's length the attention is the flash kernel, which reads a query
@@ -283,6 +289,12 @@ class GatedAttention(_TokenLayer):
     output_gate: bool = True
     qk_norm_whole: bool = False
     zero_centred: bool = True
+    qk_norm: bool = True
+
+    def __post_init__(self):
+        if self.qk_norm_whole and not self.qk_norm:
+            raise ValueError("qk_norm_whole says how q and k are normed; "
+                             "with qk_norm False they are not")
 
     def init_params(self, key, input_type, dtype=jnp.float32):
         d, h, hk, hd = self.n_in, self.n_heads, self.n_kv_heads, self.head_dim
@@ -292,6 +304,8 @@ class GatedAttention(_TokenLayer):
         keys = jax.random.split(key, len(shapes))
         p = {name: self._winit(k, s, s[0], s[1], dtype)
              for k, (name, s) in zip(keys, shapes.items())}
+        if not self.qk_norm:
+            return p
         whole = self.qk_norm_whole
         p["q_norm_g"] = _gain((h * hd if whole else hd,), self.zero_centred, dtype)
         p["k_norm_g"] = _gain((hk * hd if whole else hd,), self.zero_centred, dtype)
@@ -311,10 +325,10 @@ class GatedAttention(_TokenLayer):
 
     def _normed(self, x, g, inv_freq):
         """x (B, T, heads, hd): the norm, then the rotary part."""
-        if self.qk_norm_whole:
+        if self.qk_norm and self.qk_norm_whole:
             flat = x.reshape(x.shape[:2] + (-1,))
             x = rms_norm(flat, g, self.eps, self.zero_centred).reshape(x.shape)
-        else:
+        elif self.qk_norm:
             x = rms_norm(x, g, self.eps, self.zero_centred)
         rd = self.rotary_dim
         if not rd:
@@ -332,8 +346,8 @@ class GatedAttention(_TokenLayer):
         v = (x @ params["w_v"]).reshape(b, t, hk, hd)
         inv_freq = yarn_inv_freq(self.rotary_dim, self.rope_theta, None) \
             if self.rotary_dim else None
-        q = self._normed(q, params["q_norm_g"], inv_freq)
-        k = self._normed(k, params["k_norm_g"], inv_freq)
+        q = self._normed(q, params.get("q_norm_g"), inv_freq)
+        k = self._normed(k, params.get("k_norm_g"), inv_freq)
         heads_first = lambda a: jnp.swapaxes(a, 1, 2)
         out = jnp.swapaxes(self._attend(*map(heads_first, (q, k, v))), 1, 2)
         if self.output_gate:
@@ -852,20 +866,26 @@ class HyperConnection(BaseLayerConf):
 @register_layer
 @dataclass
 class PreNormResidual(BaseLayerConf):
-    """The plain residual block round a sublayer F: x + F(norm(x)), or with
-    `norm_output` x + norm(F(x)) (Olmo 2's order: the norm on the sublayer's
-    output); the norm an RMS norm with its own gain (`norm_g`; zero-centred
-    by default, as the models that first used the block have it). A wrapping
-    layer config as `HyperConnection` is, so that norm, sublayer and add are
+    """The plain residual block round a sublayer F, in one of three orders:
+    x + F(norm(x)) (the default: the norm on the sublayer's input); with
+    `norm_output` x + norm(F(x)) (Olmo 2's: the norm on its output); with
+    `sandwich` x + norm'(F(norm(x))) (Ouro's: a norm on both sides, each with
+    its own gain, `norm_g` before and `norm_out_g` after; both flags at once
+    are refused). Each norm is an RMS norm with its own gain (zero-centred by
+    default, as the models that first used the block have it). A wrapping
+    layer config as `HyperConnection` is, so that norms, sublayer and add are
     one recomputed block under one scope."""
     layer: Optional[BaseLayerConf] = None
     eps: float = 1e-6
     zero_centred: bool = True
     norm_output: bool = False
+    sandwich: bool = False
 
     def __post_init__(self):
         if isinstance(self.layer, dict):
             self.layer = BaseLayerConf.from_dict(self.layer)
+        if self.norm_output and self.sandwich:
+            raise ValueError("one order: norm_output or sandwich, not both")
 
     def set_n_in(self, input_type, override=False):
         self.layer.set_n_in(input_type, override)
@@ -876,20 +896,26 @@ class PreNormResidual(BaseLayerConf):
     def init_params(self, key, input_type, dtype=jnp.float32):
         p = dict(self.layer.init_params(key, input_type, dtype))
         p["norm_g"] = _gain((input_type.size,), self.zero_centred, dtype)
+        if self.sandwich:
+            p["norm_out_g"] = _gain((input_type.size,), self.zero_centred, dtype)
         return p
 
     def init_state(self, input_type, dtype=jnp.float32):
         return self.layer.init_state(input_type, dtype)
 
     def forward(self, params, state, x, *, train, rng=None, mask=None):
-        inner = {k: v for k, v in params.items() if k != "norm_g"}
-        norm = lambda a: rms_norm(a, params["norm_g"], self.eps, self.zero_centred)
+        inner = {k: v for k, v in params.items()
+                 if k not in ("norm_g", "norm_out_g")}
+        norm = lambda a, g="norm_g": rms_norm(a, params[g], self.eps,
+                                              self.zero_centred)
         u = x if self.norm_output else norm(x)
         with layer_scope(self.layer, self.name):
             y, new_state, mask = self.layer.forward(
                 inner, state, u, train=train, rng=rng, mask=mask)
         if self.norm_output:
             y = norm(y)
+        elif self.sandwich:
+            y = norm(y, "norm_out_g")
         return x + y.astype(x.dtype), new_state, mask
 
     def state_gauges(self, state) -> dict:
@@ -970,14 +996,155 @@ class TokenCrossEntropyHead(FeedForwardLayerConf):
             return self._score(params, x, labels, mask)
 
     def _score(self, params, x, labels, mask):
+        nll, mask = self._nll(params, x, labels, mask)
+        return _masked_mean(nll, mask)
+
+    def _nll(self, params, x, labels, mask):
+        """(-log softmax(x W)[label] at each scored position, their mask)."""
         labels = labels.astype(jnp.int32) - self.first_row
         if self.shift:
             x, labels = x[:, :-self.shift], labels[:, self.shift:]
             mask = None if mask is None else mask[:, self.shift:]
         logits = jnp.dot(x, params["W"], preferred_element_type=_F32)
         picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-        nll = jax.nn.logsumexp(logits, axis=-1) - picked
-        if mask is None:
-            return jnp.mean(nll)
-        m = mask.astype(nll.dtype)
-        return jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1.0)
+        return jax.nn.logsumexp(logits, axis=-1) - picked, mask
+
+
+def _masked_mean(a, mask):
+    if mask is None:
+        return jnp.mean(a)
+    m = mask.astype(a.dtype)
+    return jnp.sum(a * m) / jnp.maximum(jnp.sum(m), 1.0)
+
+
+def _count_traced(name: str, what: str, n: int = 1) -> None:
+    from deeplearning4j_tpu import telemetry
+    telemetry.registry().counter(
+        f"loop.{what}_traced.{telemetry.sanitize_component(name or '_')}",
+        f"{what} of a looped stack's scan traced (counted at trace time)").inc(n)
+
+
+@register_layer
+@dataclass
+class LoopedStack(BaseLayerConf):
+    """A stack of blocks run `passes` times with one set of weights (Ouro's
+    looped decoder, arXiv:2510.25741): pass t runs every block in order on
+    x_{t-1}, then an RMS norm with its own gain (`norm_g`, from 1), h_t =
+    norm(...), and h_t is pass t + 1's input. The output is every pass's
+    h_t, stacked on a leading axis: (passes, batch, time, features), for a
+    head that scores each (`LoopExitHead`). The passes are one `lax.scan`
+    whose body closes over the blocks' parameters, so the program holds one
+    pass whatever `passes` is, and autodiff sums the shared weights'
+    gradients over the passes. A block's leaves are `<its name>.<key>`.
+
+    The graph hands the layer its recomputation policy a block at a time
+    (`block`, where it recomputes at all: `recomputes_blocks`): each block
+    and the norm are recomputed in the backward pass, their parameters cast
+    to the compute type inside, so that only the blocks' inputs of every pass
+    live through the step. Counters `loop.passes_traced.<node>` and
+    `loop.bodies_traced.<node>` count at trace time."""
+    blocks: Optional[list] = None
+    passes: int = 4
+    eps: float = 1e-6
+    recomputes_blocks = True   # the walk leaves the recomputation to `block`
+
+    def __post_init__(self):
+        self.blocks = [BaseLayerConf.from_dict(b) if isinstance(b, dict) else b
+                       for b in self.blocks or []]
+        names = [b.name for b in self.blocks]
+        if None in names or len(set(names)) != len(names):
+            raise ValueError(f"a looped stack's blocks need names of their own: {names}")
+
+    def set_n_in(self, input_type, override=False):
+        for b in self.blocks:
+            b.set_n_in(input_type, override)
+
+    def get_output_type(self, input_type):
+        return input_type
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        p = {}
+        for b, k in zip(self.blocks, jax.random.split(key, len(self.blocks))):
+            p.update({f"{b.name}.{leaf}": v
+                      for leaf, v in b.init_params(k, input_type, dtype).items()})
+        p["norm_g"] = jnp.ones((input_type.size,), dtype)
+        return p
+
+    def leaves_of(self, params, block) -> dict:
+        """A block's own leaves, under their own keys."""
+        head = block.name + "."
+        return {k[len(head):]: v for k, v in params.items() if k.startswith(head)}
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None, block=None):
+        wrap = block or (lambda f: f)
+
+        def step(b):
+            def apply(p, u):
+                with layer_scope(b, b.name):
+                    return b.forward(p, {}, u, train=train, mask=mask)[0]
+            return wrap(apply), self.leaves_of(params, b)
+        steps = [step(b) for b in self.blocks]
+        final = wrap(lambda p, u: rms_norm(u, p["norm_g"], self.eps))
+
+        def one_pass(h, _):
+            _count_traced(self.name, "bodies")
+            for f, p in steps:
+                h = f(p, h)
+            h = final({"norm_g": params["norm_g"]}, h)
+            return h, h
+        _, states = lax.scan(one_pass, x, None, length=self.passes)
+        _count_traced(self.name, "passes", self.passes)
+        return states, state, mask
+
+
+@register_layer
+@dataclass
+class LoopExitHead(TokenCrossEntropyHead):
+    """The output head of a `LoopedStack` (Ouro's training objective): its
+    input is every pass's state h_t (passes, batch, time, features). Each
+    pass is scored by the same untied head, CE_t = -log softmax(h_t W)[label]
+    (`TokenCrossEntropyHead`'s cross-entropy), and gated, lambda_t =
+    sigmoid(h_t w_gate + b_gate) a token. The exit distribution is p_t =
+    lambda_t prod_{j<t} (1 - lambda_j) for t < P, p_P = prod_{j<P} (1 -
+    lambda_j) (the last pass's gate is not used), worked out in log space;
+    the loss is the mean over positions of sum_t p_t CE_t - beta H(p), H(p) =
+    -sum_t p_t log p_t, beta = `entropy_weight`. The passes are scored one
+    at a time, each recomputed in the backward pass (`lax.map` of a
+    checkpointed pass): one pass's float32 logits live at once. Everything
+    here is float32. `forward` gives the last pass's logits."""
+    entropy_weight: float = 0.05
+    recomputes_its_score = True   # each pass is its own recomputed block
+
+    def init_params(self, key, input_type, dtype=jnp.float32):
+        k_head, k_gate = jax.random.split(key)
+        p = super().init_params(k_head, input_type, dtype)
+        p["w_gate"] = self._winit(k_gate, (self.n_in, 1), self.n_in, 1, dtype)
+        p["b_gate"] = jnp.zeros((1,), dtype)
+        return p
+
+    def forward(self, params, state, x, *, train, rng=None, mask=None):
+        return x[-1] @ params["W"], state, mask
+
+    def _score(self, params, x, labels, mask):
+        def one_pass(h):
+            nll, _ = self._nll(params, h, labels, None)
+            z = jnp.dot(h[:, :nll.shape[1]], params["w_gate"],
+                        preferred_element_type=_F32)[..., 0]
+            return nll, z + params["b_gate"].astype(_F32)[0]
+        nll, z = lax.map(jax.checkpoint(one_pass), x)
+        exit_p, log_p = exit_distribution(z)
+        per_token = jnp.sum(exit_p * (nll + self.entropy_weight * log_p), axis=0)
+        if self.shift and mask is not None:
+            mask = mask[:, self.shift:]
+        return _masked_mean(per_token, mask)
+
+
+def exit_distribution(z):
+    """Gate logits z (passes, ...) -> (p, log p) over the passes, p_t =
+    sigmoid(z_t) prod_{j<t} sigmoid(-z_j) for t < P, p_P = prod_{j<P}
+    sigmoid(-z_j), in log space (no 0 log 0)."""
+    stay = jax.nn.log_sigmoid(-z)
+    before = jnp.cumsum(stay, axis=0) - stay
+    log_p = jnp.concatenate([jax.nn.log_sigmoid(z[:-1]) + before[:-1],
+                             before[-1:]], axis=0)
+    return jnp.exp(log_p), log_p

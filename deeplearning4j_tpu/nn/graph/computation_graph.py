@@ -158,6 +158,12 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
         # block: no second copy of every weight lives through the step
         remat = bool(self.conf.global_conf.remat) and train
         cast_inside = mixed and remat
+
+        def recomputed(block):
+            """A block recomputed in the backward pass, its parameters cast
+            to the compute type inside it."""
+            return jax.checkpoint(lambda p, *a: block(
+                cast_params(p, cd) if cast_inside else p, *a))
         if mixed and not cast_inside:
             params_tree = _cast_params(self.layers, self.layer_names,
                                        params_tree, cd)
@@ -211,9 +217,12 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                 lm = lmask_map.get(name)
                 if lm is None and mask is not None and cur.ndim == 3:
                     lm = mask
-                # output-layer matmul + loss in storage dtype for stability
-                score = jax.checkpoint(layer.compute_score) if remat \
-                    else layer.compute_score
+                # output-layer matmul + loss in storage dtype for stability;
+                # a head that recomputes its score in parts (`LoopExitHead`)
+                # is not recomputed whole besides
+                own = getattr(layer, "recomputes_its_score", False)
+                score = jax.checkpoint(layer.compute_score) \
+                    if remat and not own else layer.compute_score
                 with jax.named_scope("dl4j.loss"):
                     total_loss = total_loss \
                         + getattr(layer, "loss_weight", 1.0) * score(
@@ -251,7 +260,13 @@ class ComputationGraph(DivergenceSentinelMixin, _health.HealthMonitorMixin):
                             p = cast_params(p, cd)
                         return _layer.forward(p, s, c, train=train, rng=r, mask=m)
 
-                    if remat:
+                    if remat and getattr(layer, "recomputes_blocks", False):
+                        # a layer of blocks (`LoopedStack`) takes the policy
+                        # a block at a time (`recomputed`): the cast inside each
+                        def fwd(p, s, c, r, m, _layer=layer):
+                            return _layer.forward(p, s, c, train=train, rng=r,
+                                                  mask=m, block=recomputed)
+                    elif remat:
                         # the layer's activations are dropped and recomputed
                         # in the backward pass (MultiLayerNetwork._loss_fn)
                         fwd = jax.checkpoint(fwd)
